@@ -40,7 +40,9 @@ class AgentConfig:
     server_enabled: bool = True
     num_schedulers: int = 2
     #: persistent XLA compile cache dir (utils/compile_cache) — warm
-    #: restarts skip the multi-second solver recompiles; "" = off
+    #: restarts skip the multi-second solver recompiles; "" = the
+    #: default resolution (JAX_COMPILATION_CACHE_DIR, else the
+    #: checkout's .jax_cache)
     compile_cache_dir: str = ""
     #: serving-tier overrides (server/serving.py ServingTier.KNOBS:
     #: slo_budget_s, max_batch, max_pending, bypass_priority, brownout

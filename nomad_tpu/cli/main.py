@@ -70,8 +70,9 @@ def cmd_agent(args) -> int:
     # themselves
     from ..utils.monitor import parse_level
     logging.getLogger("nomad_tpu").setLevel(parse_level(cfg.log_level))
-    # warm restarts skip the solver's XLA recompiles when a persistent
-    # compile cache dir is configured (config or env opt-in)
+    # warm restarts skip the solver's XLA recompiles: the persistent
+    # compile cache is on by default (utils/compile_cache resolves the
+    # directory: environment, then this config, then the checkout)
     from ..utils.compile_cache import enable_compile_cache
     enable_compile_cache(cfg.compile_cache_dir or None)
     if cfg.tls_rpc:

@@ -8,8 +8,7 @@ node universe, usage tensors, and eval stream — but fuses the *solves*:
 every stream step carries one batch per region, vmapped over a leading
 region axis inside a single `lax.scan` device program.  One dispatch and
 one result fetch cover every region's whole workload, where R separate
-streams would pay R transport round trips (ruinous on tunneled
-transports, see solver/resident.py).
+streams would pay R dispatches and R fetches (see solver/resident.py).
 
 On a multi-chip mesh the region axis is the natural sharding axis: the
 same program with the vmap replaced by a `shard_map` over a
@@ -314,13 +313,8 @@ class FederatedResidentSolver:
         The jit keys on the stacked operand shapes — which carry the
         region count R and every padded dim — plus the static config,
         so adding a region (new [B, R, ...] shapes) costs exactly one
-        new entry and leaves every existing entry warm.  -1 when the
-        runtime doesn't expose the cache."""
-        try:
-            return int(_federated_stream_kernel._cache_size())
-        except (AttributeError, TypeError):
-            # jax version without the _cache_size probe
-            return -1
+        new entry and leaves every existing entry warm."""
+        return int(_federated_stream_kernel._cache_size())
 
     # ---------------- usage ----------------
     def usage(self) -> Tuple[np.ndarray, np.ndarray]:

@@ -23,7 +23,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..solver.kernel import solve_kernel
@@ -396,13 +395,13 @@ def _build_sharded_stream_kernel(mesh: Mesh):
             stack_commit=stack_commit, compact=compact,
             pallas_mode=pallas_mode, shortlist_c=shortlist_c,
             has_preempt=has_preempt)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(node2, node2, node1, node1, node2, node2,
                       node2, node2, stacked_specs, P(), P(),
                       ev3, ev2, gid1, P(), P()),
             out_specs=(node2, node2, P(), P(), P(), P()),
-            check_rep=False)(
+            check_vma=False)(
             avail, reserved, valid, node_dc, attr_rank, dev_cap,
             used0, dev_used0, stacked, n_places, seeds,
             ev_res, ev_prio, node_gid, owner_map, slot_map)
@@ -733,9 +732,10 @@ class ShardedResidentSolver(ResidentSolver):
                     return a_l.at[loc].set(rows_, mode="drop")
                 return a_l.at[loc].add(rows_, mode="drop")
 
-            fn = jax.jit(shard_map(body, mesh=self._mesh,
-                                   in_specs=(spec, P(), P()),
-                                   out_specs=spec, check_rep=False))
+            fn = jax.jit(jax.shard_map(body, mesh=self._mesh,
+                                       in_specs=(spec, P(), P()),
+                                       out_specs=spec,
+                                       check_vma=False))
             self._scatter_kerns[key] = fn
         return fn(arr, idx, rows)
 

@@ -16,6 +16,7 @@ to the single-eval path, which sees its stops.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time as _time
 from typing import Dict, List, Optional, Tuple
@@ -24,10 +25,17 @@ from ..structs import (EVAL_STATUS_COMPLETE, EVAL_STATUS_FAILED, Allocation,
                        Evaluation, JOB_TYPE_BATCH, JOB_TYPE_SERVICE)
 from .generic import GenericScheduler, _VALID_TRIGGERS
 
+_log = logging.getLogger(__name__)
+
 #: hard ceiling on evals fused into one coordinator round — beyond
 #: this the ask tensor gets big enough that solve wall grows past the
 #: SLO budget the BatchController sized the member batches for
 DEFAULT_MAX_FUSED = 128
+
+#: how long a submitter waits on its round before it looks at why: a
+#: batch no drain leader will ever take is withdrawn and handed back
+#: (TimeoutError); one the leader holds is waited out, with a warning
+SUBMIT_PATIENCE_S = 60.0
 
 #: per-round wall breakdown stages (ISSUE 19).  `dequeue` is recorded
 #: by the worker loop (the broker wait isn't visible here); the fleet
@@ -513,8 +521,25 @@ class SolveCoordinator:
         re-raises the drain error so the caller's nack path owns its
         own evals."""
         sub = self.submit_nowait(worker, batch)
-        if not sub.done.wait(60.0):
-            raise TimeoutError("fused solve coordinator timed out")
+        t0 = _time.monotonic()
+        while not sub.done.wait(SUBMIT_PATIENCE_S):
+            # a batch may be given up (the caller nacks it) only while
+            # it is still queued: once the drain leader has taken it
+            # into a round its evals ARE being solved and planned, and
+            # a nack then hands them to a second scheduler as well
+            # (nothing downstream checks eval tokens).  A slow round —
+            # the first at a new shape compiles for longer than this on
+            # a TPU — is waited out, not abandoned.
+            with self._lock:
+                queued = sub in self._queue
+                orphaned = queued and not self._draining
+                if orphaned:
+                    self._queue.remove(sub)
+            if orphaned:
+                raise TimeoutError("fused solve coordinator timed out")
+            _log.warning("fused round still %s after %.0fs (%d evals)",
+                         "queued" if queued else "solving",
+                         _time.monotonic() - t0, len(batch))
         if sub.error is not None:
             raise sub.error
 

@@ -153,6 +153,7 @@ class GenericScheduler:
         self.queued_allocs = {}
         self.followup_evals = []
         self._sticky_probes = []
+        self._unfinished = 0
         self.plan = ev.make_plan(self.job)
 
         if not self.batch:
@@ -178,8 +179,14 @@ class GenericScheduler:
             fev.previous_eval = ev.id
             self.planner.create_eval(fev)
 
+        # placements the solve's wave budget left undecided are not
+        # failures: submit what was decided, then go round again (a
+        # fused batch of a hundred evals aiming at the same best nodes
+        # routinely leaves some).  A blocked eval would wait for a
+        # capacity change that, with capacity plentiful, need not come.
+        done = self._unfinished == 0
         if self.plan.is_no_op() and not ev.annotate_plan:
-            return True, None
+            return done, None
 
         result, new_state = self.planner.submit_plan(self.plan)
         if result is None:
@@ -198,7 +205,7 @@ class GenericScheduler:
         full, _expected, _actual = result.full_commit(self.plan)
         if not full:
             return False, None
-        return True, None
+        return done, None
 
     def _compute_job_allocs(self, snapshot
                             ) -> Tuple[List["_Missing"], Optional[str]]:
@@ -483,6 +490,13 @@ class GenericScheduler:
             if want_rows:
                 place_rows.append(_placement_row(m, placement))
             if placement.node is None:
+                if placement.retryable:
+                    # undecided, not failed: no preemption, no blocked
+                    # eval — but a destructive update keeps its old
+                    # alloc until the replacement lands
+                    self._unfinished += 1
+                    failed.add(id(m))
+                    continue
                 if not (preempt_ok and self._try_preemption(
                         nodes, m, allocs_by_node)):
                     self._record_failure(m, placement)

@@ -62,6 +62,10 @@ class Server:
                  raft_config: Optional[RaftConfig] = None,
                  raft_transport=None,
                  serving_config: Optional[dict] = None):
+        # the solver's XLA programs persist across restarts by default;
+        # an agent config's directory, if any, was set before this
+        from ..utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
         self.store = StateStore()
         self.fsm = StateFSM(self.store)
         if raft_config is None:

@@ -5,16 +5,20 @@ host-evaluated ops) without the placement scan — used by the system
 scheduler, which forces placements onto specific nodes and only needs
 the mask (reference analog: feasible.go checks without rank/limit).
 
-Bitpacking: the solve's boolean planes (feasibility, penalty,
-distinct-blocking) are one int8 lane per (group, node) cell when they
-ride along the fused wave kernel, and one full bool per cell on the
-host/device fetch path.  `pack_bool_u32` folds 32 node columns into one
-uint32 lane — 8x fewer HBM bytes per wave re-read of the static planes
-(kernel.py feeds the pallas pass packed words) and 8x fewer transport
-bytes when a mask is fetched whole (`static_feasibility` below fetches
-words and unpacks host-side).  Bit j of word w is node column
-``w * 32 + j``; the node axis must be a multiple of 32, which every
-tensorize padding (pow2 >= 32, or 1024-multiples) guarantees.
+Bitpacking, two layouts:
+
+  * `pack_bool_u32` folds 32 NODE columns into one uint32 word — 8x
+    fewer bytes when a [G, N] mask crosses the host/device boundary
+    (the stacked ask planes going in, `static_feasibility` coming
+    out).  Bit j of word w is node column ``w * 32 + j``; the node
+    axis must be a multiple of 32, which every tensorize padding (pow2
+    >= 32, or 1024-multiples) guarantees.
+  * `pack_groups_i32` folds 32 GROUP rows into one int32 word,
+    [ceil(G/32), N] — the layout the pallas wave kernel re-reads every
+    full wave (feasibility, penalty, distinct-blocking).  The node
+    axis stays the lane axis, so a tile's words are a lane-aligned
+    block and unpack with a sublane broadcast; the node-axis layout
+    cannot be blocked or unpacked by Mosaic (pallas_kernel.py).
 """
 from __future__ import annotations
 
@@ -52,6 +56,21 @@ def unpack_bool_u32(words: jnp.ndarray, n: int) -> jnp.ndarray:
     bits = (words[..., None] >> shifts) & jnp.uint32(1)
     return bits.reshape(words.shape[:-1]
                         + (words.shape[-1] * PACK_LANES,))[..., :n] != 0
+
+
+def pack_groups_i32(mask: jnp.ndarray) -> jnp.ndarray:
+    """[G, N] bool mask -> [ceil(G/32), N] int32 words, bit ``g % 32``
+    of word row ``g // 32`` (jnp; traceable inside jit).  Trailing
+    bits of a short last word are zero."""
+    g, n = mask.shape
+    gw = -(-g // PACK_LANES)
+    if g % PACK_LANES:
+        mask = jnp.concatenate(
+            [mask, jnp.zeros((gw * PACK_LANES - g, n), mask.dtype)])
+    bits = mask.astype(jnp.uint32).reshape(gw, PACK_LANES, n)
+    shifts = jnp.arange(PACK_LANES, dtype=jnp.uint32)[None, :, None]
+    words = (bits << shifts).sum(axis=1, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
 def np_pack_bool_u32(mask: np.ndarray) -> np.ndarray:

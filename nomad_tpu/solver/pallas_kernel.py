@@ -3,10 +3,9 @@
 The wave kernel's per-iteration cost is pure HBM traffic: the jnp
 implementation (`kernel.group_scores`) walks the [Gp, Np] plane half a
 dozen times per wave (fit/after/binpack/anti/spread/normalize/select)
-and materializes [Gp, Np, R] broadcast intermediates between passes —
-`BENCH_DETAIL.json` device_ceiling puts the measured solve far above
-its own bytes/bandwidth floor.  This module fuses the whole scoring
-chain into ONE pass per node tile resident in VMEM:
+and materializes [Gp, Np, R] broadcast intermediates between passes.
+This module fuses the whole scoring chain into ONE pass per node tile
+resident in VMEM:
 
   for each tile of T nodes (grid axis):
       load the tile's static planes (feasibility, affinity+penalty
@@ -37,23 +36,35 @@ Mode selection is static (trace-time): "topk" when the candidate
 window is small enough for iterative in-VMEM extraction, "score"
 otherwise (merged throughput batches with 1024-wide windows keep
 `approx_max_k` on the fused score), "off" when shapes/features fall
-outside the fused universe.  On CPU the kernel runs in pallas
-interpreter mode — same semantics, no Mosaic — which is what tier-1
-exercises; on TPU `available()` compile-probes a representative kernel
-once and disables the path rather than let a Mosaic regression take
-the scheduler down.
+outside the fused universe.  On a `tpu` backend the kernel is compiled
+by Mosaic and a lowering failure RAISES with the compiler's message —
+nothing here downgrades to the unfused path behind the caller's back.
+On any other backend it runs in the pallas interpreter (same
+semantics, no Mosaic), which is what tier-1 exercises.
 
-Two shortlist-era (ISSUE 4) extensions:
+Layout contract (what Mosaic accepts, see `fused_wave`): every block's
+last dimension is the node tile (a multiple of 128 lanes) or a whole
+small axis, so
 
+  * node-side planes arrive TRANSPOSED, [R, Np] / [D, Np]: a resource
+    row is a lane-dense (1, T) slice that broadcasts down the group
+    sublanes — never a column-to-lane relayout;
   * the boolean planes (feasibility, penalty, distinct-blocking)
-    arrive BITPACKED — uint32 words of 32 node columns
-    (masks.pack_bool_u32) — and unpack per tile inside the kernel, so
-    the static masks cost 1/8th of their int8 bytes on every full
-    wave's HBM re-read;
-  * `n_extract` decouples the in-kernel extraction width from the
-    candidate window TK: the full wave extracts the top-C shortlist
-    (C >= TK) in one pass, the caller windows the first TK and carries
-    the rest for shortlist-resident contention waves (kernel.py).
+    arrive bitpacked along the GROUP axis (masks.pack_groups_i32): one
+    int32 word holds 32 groups of one node column, [ceil(Gp/32), Np],
+    and unpacks in-kernel with a sublane broadcast and a per-row
+    shift.  Packing along the node axis would need a (Gp, T/32) block
+    and a minor-dim reshape, both of which Mosaic refuses;
+  * everything is 32-bit inside the kernel (v5e has no 8/16-bit VPU
+    lanes for sub-(32,128) blocks);
+  * small per-tile results leave through lane-dense 128-wide blocks
+    (counters, per-tile top-K partials) built with iota selects, not
+    single-lane dynamic stores.
+
+`n_extract` decouples the in-kernel extraction width from the
+candidate window TK: the full wave extracts the top-C shortlist
+(C >= TK) in one pass, the caller windows the first TK and carries
+the rest for shortlist-resident contention waves (kernel.py).
 """
 from __future__ import annotations
 
@@ -65,11 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:                               # TPU memory spaces (absent on some
-    from jax.experimental.pallas import tpu as pltpu  # cpu-only builds)
-except ImportError:                # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 #: sentinel strictly below NEG_INF: masks already-extracted slots so
@@ -81,17 +88,25 @@ SCORE_BIN = 0.05
 #: largest candidate window the in-kernel iterative extraction serves;
 #: wider windows (merged throughput batches) use mode "score"
 TOPK_MAX = 256
-#: per-tile VMEM working-set budget, in [Gp, T] f32-plane elements
-_TILE_ELEMS = 1 << 18
+#: per-tile working-set budget, in [Gp, T] 32-bit plane elements: a
+#: tile keeps a few dozen such planes live (double-buffered inputs
+#: plus the scoring chain's intermediates), so 64K elements = 256 KiB
+#: per plane stays inside _VMEM_LIMIT with room to spare at every Gp
+_TILE_ELEMS = 1 << 16
+#: scoped-VMEM ceiling handed to Mosaic (its 16 MiB default is sized
+#: for matmul tiles; a v5e core has 128 MiB)
+_VMEM_LIMIT = 64 << 20
 #: spread value-vocabulary cap for the unrolled select-sum
 _V_MAX = 16
+#: lane width every small per-tile output is padded to
+_LANES = 128
 
 _R_CPU, _R_MEM = 0, 1
 
 
 def _env_mode() -> str:
-    """NOMAD_TPU_PALLAS: '1'/'interpret' force-enables (interpreted on
-    CPU), '0' disables, unset = auto (on only for TPU backends)."""
+    """NOMAD_TPU_PALLAS: '1'/'interpret' force-enables (interpreted off
+    TPU), '0' disables, unset = auto (on only for TPU backends)."""
     return os.environ.get("NOMAD_TPU_PALLAS", "").strip().lower()
 
 
@@ -106,48 +121,7 @@ def enabled() -> bool:
         return False
     if env in ("1", "on", "true", "interpret"):
         return True
-    return jax.default_backend() == "tpu" and available()
-
-
-@functools.lru_cache(maxsize=1)
-def available() -> bool:
-    """Compile-probe a representative fused kernel once: a Mosaic
-    lowering failure downgrades the solver to the unfused path instead
-    of crashing the scheduler."""
-    try:
-        import numpy as np
-        from .masks import pack_bool_u32
-        Gp, Np, R, S, V, D = 2, 256, 4, 1, 4, 2
-        out = fused_wave(
-            mode="topk",
-            feas=pack_bool_u32(jnp.ones((Gp, Np), bool)),
-            blocked=pack_bool_u32(jnp.zeros((Gp, Np), bool)),
-            aff=jnp.zeros((Gp, Np), jnp.float32),
-            pen=pack_bool_u32(jnp.zeros((Gp, Np), bool)),
-            jitter=jnp.zeros((Gp, Np), jnp.float32),
-            coll=jnp.zeros((Gp, Np), jnp.float32),
-            used=jnp.zeros((Np, R), jnp.float32),
-            avail=jnp.ones((Np, R), jnp.float32) * 100,
-            reserved=jnp.zeros((Np, R), jnp.float32),
-            ask_res=jnp.ones((Gp, R), jnp.float32),
-            ask_desired=jnp.ones((Gp,), jnp.float32),
-            dev=(jnp.zeros((Np, D), jnp.float32),
-                 jnp.ones((Np, D), jnp.float32),
-                 jnp.zeros((Gp, D), jnp.float32)),
-            spread=(jnp.zeros((S, Gp, Np), jnp.int32),
-                    jnp.ones((S, Gp, Np), jnp.float32),
-                    jnp.zeros((Gp, S, V), jnp.float32),
-                    jnp.ones((Gp, S), jnp.float32),
-                    jnp.zeros((Gp, S), jnp.bool_),
-                    jnp.zeros((Gp, S), jnp.int8),
-                    jnp.zeros((Gp, S), jnp.float32),
-                    jnp.zeros((Gp, S), jnp.float32),
-                    jnp.zeros((Gp, S), jnp.int8)),
-            seed=jnp.int32(1), TK=8, tables_v=V)
-        np.asarray(out["top_score"])
-        return True
-    except Exception:               # pragma: no cover - backend specific
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def pick_tile(Np: int, Gp: int) -> int:
@@ -179,15 +153,16 @@ def resolve_mode(Np: int, Gp: int, TK: int, V: int,
     return "score"
 
 
-def _specs(shape, tile_map, memory_space=None):
-    kw = {}
-    if pltpu is not None and not _interpret():
-        kw["memory_space"] = memory_space or pltpu.VMEM
-    return pl.BlockSpec(shape, tile_map, **kw)
+def _lane_pad(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _vmem(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
-               coll, used, avail, reserved, ask_res, ask_desired,
+               coll, used_t, avail_t, reserved_t, ask_res, ask_desired,
                dev=None, spread=None, seed=0, TK=4, n_extract=0,
                tables_v=0):
     """One fused pass over node tiles producing the wave's scoring
@@ -207,31 +182,33 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
     counters: n_feas [Gp] i32, n_exh [Gp] i32, grp_any [Gp] bool,
     dim_exh [Gp, R] i32 — the per-wave explainability reductions.
 
-    All tensors use the caller's (kernel.py) layouts.  `feas`, `pen`
-    and `blocked` arrive BITPACKED: uint32 words over the node axis
-    (masks.pack_bool_u32), unpacked per tile in-kernel.  `spread` packs
-    (sp_vnode [S,Gp,Np], sp_des [S,Gp,Np], sp_used [Gp,S,V],
-    sp_weight [Gp,S], sp_targeted [Gp,S], sp_has [Gp,S] i8,
-    minc [Gp,S], maxc [Gp,S], anyp [Gp,S] i8); `dev` packs
-    (dev_used [Np,D], dev_cap [Np,D], dev_ask [Gp,D]).
+    Layouts (module docstring): `feas`, `pen` and `blocked` are
+    GROUP-bitpacked int32 words [ceil(Gp/32), Np]
+    (masks.pack_groups_i32); `used_t`/`avail_t`/`reserved_t` are the
+    node planes transposed to [R, Np].  `spread` packs (sp_vnode
+    [S,Gp,Np] i32, sp_des [S,Gp,Np], sp_used [Gp,S,V], sp_weight
+    [Gp,S], sp_targeted [Gp,S], sp_has [Gp,S], minc [Gp,S], maxc
+    [Gp,S], anyp [Gp,S]); `dev` packs (dev_used_t [D,Np], dev_cap_t
+    [D,Np], dev_ask [Gp,D]).  The small [Gp, S] flag arrays may be any
+    dtype; they enter the kernel as int32.
     """
-    Gp = feas.shape[0]
-    Np, R = used.shape[0], used.shape[1]
+    Gp = aff.shape[0]
+    R, Np = used_t.shape
+    Gw = feas.shape[0]
     has_devices = dev is not None
     has_spread = spread is not None
     has_blocked = blocked is not None
     T = pick_tile(Np, Gp)
     n_tiles = Np // T
-    # packed boolean planes: words per tile (T is a multiple of 32 for
-    # every multi-tile layout; single-tile layouts take the whole —
-    # possibly padded — word row)
-    Tw = -(-T // 32) if n_tiles == 1 else T // 32
     NE = n_extract or TK
     TKt = min(NE, T)
     want_tables = mode == "topk" and tables_v > 0
     Vs = tables_v
     TKv = -(-TK // (Vs + 1)) if want_tables else 0
     TKvt = min(TKv, T) if want_tables else 0
+    # lane-dense widths of the per-tile partial outputs
+    TKp = _lane_pad(TKt)
+    TKvp = _lane_pad(TKvt) if want_tables else 0
     CNT = 3 + R
 
     if has_spread:
@@ -242,77 +219,69 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
     else:
         S = V = 0
     if has_devices:
-        dev_used, dev_cap, dev_ask = dev
-        D = dev_cap.shape[1]
+        dev_used_t, dev_cap_t, dev_ask = dev
+        D = dev_cap_t.shape[0]
     else:
         D = 0
 
     # ---- assemble inputs + block specs (order matters: the kernel
     # unpacks positionally) ----
-    gp_t = lambda i: (0, i)              # [Gp, Np] planes  # noqa: E731
-    np_r = lambda i: (i, 0)              # [Np, X] planes   # noqa: E731
+    tile = lambda i: (0, i)              # [X, Np] planes     # noqa: E731
     full = lambda i: (0, 0)              # whole small arrays # noqa: E731
+    i32 = jnp.int32
     inputs = [feas, pen, aff, jitter, coll]
-    in_specs = [_specs((Gp, Tw), gp_t)] * 2 \
-        + [_specs((Gp, T), gp_t)] * 3
+    in_specs = [_vmem((Gw, T), tile)] * 2 + [_vmem((Gp, T), tile)] * 3
     if has_blocked:
         inputs.append(blocked)
-        in_specs.append(_specs((Gp, Tw), gp_t))
-    inputs += [used, avail, reserved, ask_res,
+        in_specs.append(_vmem((Gw, T), tile))
+    inputs += [used_t, avail_t, reserved_t, ask_res,
                ask_desired.reshape(Gp, 1),
-               jnp.asarray(seed, jnp.int32).reshape(1, 1)]
-    in_specs += [_specs((T, R), np_r), _specs((T, R), np_r),
-                 _specs((T, R), np_r), _specs((Gp, R), full),
-                 _specs((Gp, 1), full),
-                 _specs((1, 1), full,
-                        memory_space=(pltpu.SMEM if pltpu is not None
-                                      else None))]
+               jnp.asarray(seed, i32).reshape(1, 1)]
+    in_specs += [_vmem((R, T), tile)] * 3 + [
+        _vmem((Gp, R), full), _vmem((Gp, 1), full),
+        pl.BlockSpec((1, 1), full, memory_space=pltpu.SMEM)]
     if has_devices:
-        inputs += [dev_used, dev_cap, dev_ask]
-        in_specs += [_specs((T, D), np_r), _specs((T, D), np_r),
-                     _specs((Gp, D), full)]
+        inputs += [dev_used_t, dev_cap_t, dev_ask]
+        in_specs += [_vmem((D, T), tile), _vmem((D, T), tile),
+                     _vmem((Gp, D), full)]
     if has_spread:
-        s_gp_t = lambda i: (0, 0, i)     # noqa: E731
-        inputs += [sp_vnode, sp_des, sp_used, sp_weight,
-                   sp_targeted.astype(jnp.int8), sp_has, minc, maxc,
-                   anyp]
-        in_specs += [_specs((S, Gp, T), s_gp_t),
-                     _specs((S, Gp, T), s_gp_t),
-                     _specs((Gp, S, V), lambda i: (0, 0, 0)),
-                     _specs((Gp, S), full), _specs((Gp, S), full),
-                     _specs((Gp, S), full), _specs((Gp, S), full),
-                     _specs((Gp, S), full), _specs((Gp, S), full)]
+        s_tile = lambda i: (0, 0, i)     # noqa: E731
+        inputs += [sp_vnode.astype(i32), sp_des,
+                   sp_used.reshape(Gp, S * V), sp_weight,
+                   sp_targeted.astype(i32), sp_has.astype(i32), minc,
+                   maxc, anyp.astype(i32)]
+        in_specs += [_vmem((S, Gp, T), s_tile),
+                     _vmem((S, Gp, T), s_tile),
+                     _vmem((Gp, S * V), full)] \
+            + [_vmem((Gp, S), full)] * 6
 
     # ---- outputs ----
     out_shapes = []
     out_specs = []
     if mode == "score":
         out_shapes.append(jax.ShapeDtypeStruct((Gp, Np), jnp.float32))
-        out_specs.append(_specs((Gp, T), gp_t))
+        out_specs.append(_vmem((Gp, T), tile))
     else:
         out_shapes += [
-            jax.ShapeDtypeStruct((Gp, n_tiles * TKt), jnp.float32),
-            jax.ShapeDtypeStruct((Gp, n_tiles * TKt), jnp.int32)]
-        out_specs += [_specs((Gp, TKt), gp_t),
-                      _specs((Gp, TKt), gp_t)]
+            jax.ShapeDtypeStruct((Gp, n_tiles * TKp), jnp.float32),
+            jax.ShapeDtypeStruct((Gp, n_tiles * TKp), i32)]
+        out_specs += [_vmem((Gp, TKp), tile)] * 2
         if want_tables:
             out_shapes += [
-                jax.ShapeDtypeStruct((Vs + 1, Gp, n_tiles * TKvt),
+                jax.ShapeDtypeStruct((Vs + 1, Gp, n_tiles * TKvp),
                                      jnp.float32),
-                jax.ShapeDtypeStruct((Vs + 1, Gp, n_tiles * TKvt),
-                                     jnp.int32)]
-            vmap3 = lambda i: (0, 0, i)  # noqa: E731
-            out_specs += [_specs((Vs + 1, Gp, TKvt), vmap3),
-                          _specs((Vs + 1, Gp, TKvt), vmap3)]
-    out_shapes.append(jax.ShapeDtypeStruct((n_tiles, Gp, CNT),
+                jax.ShapeDtypeStruct((Vs + 1, Gp, n_tiles * TKvp), i32)]
+            vtile = lambda i: (0, 0, i)  # noqa: E731
+            out_specs += [_vmem((Vs + 1, Gp, TKvp), vtile)] * 2
+    out_shapes.append(jax.ShapeDtypeStruct((n_tiles, Gp, _LANES),
                                            jnp.float32))
-    out_specs.append(_specs((1, Gp, CNT), lambda i: (i, 0, 0)))
+    out_specs.append(_vmem((1, Gp, _LANES), lambda i: (i, 0, 0)))
 
     kernel = functools.partial(
         _wave_tile_kernel, mode=mode, Gp=Gp, T=T, R=R, D=D, S=S, V=V,
-        TKt=TKt, Vs=Vs, TKvt=TKvt, has_devices=has_devices,
-        has_spread=has_spread, has_blocked=has_blocked,
-        want_tables=want_tables)
+        TKt=TKt, TKp=TKp, Vs=Vs, TKvt=TKvt, TKvp=TKvp,
+        has_devices=has_devices, has_spread=has_spread,
+        has_blocked=has_blocked, want_tables=want_tables)
 
     outs = pl.pallas_call(
         kernel,
@@ -320,17 +289,29 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
         in_specs=in_specs,
         out_shape=tuple(out_shapes),
         out_specs=tuple(out_specs),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
+        name=f"nomad_wave_{mode}",
     )(*inputs)
 
     # ---- merge per-tile partials (the "small reduction") ----
+    def _strip(x, w, wp):
+        """[..., n_tiles * wp] lane-padded partials -> [..., n_tiles * w]"""
+        if w == wp:
+            return x
+        lead = x.shape[:-1]
+        return x.reshape(lead + (n_tiles, wp))[..., :w].reshape(
+            lead + (n_tiles * w,))
+
     res = {}
     oi = 0
     if mode == "score":
         res["score"] = outs[oi]
         oi += 1
     else:
-        ts_all, ti_all = outs[oi], outs[oi + 1]
+        ts_all = _strip(outs[oi], TKt, TKp)
+        ti_all = _strip(outs[oi + 1], TKt, TKp)
         oi += 2
         mTK = min(NE, n_tiles * TKt)
         ms, pos = lax.top_k(ts_all, mTK)
@@ -343,7 +324,8 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
                 [mi, jnp.zeros((Gp, pad), jnp.int32)], axis=1)
         res["top_score"], res["top_idx"] = ms, mi
         if want_tables:
-            vts, vti = outs[oi], outs[oi + 1]
+            vts = _strip(outs[oi], TKvt, TKvp)
+            vti = _strip(outs[oi + 1], TKvt, TKvp)
             oi += 2
             mv = min(TKv, n_tiles * TKvt)
             tab_s, vpos = lax.top_k(
@@ -359,7 +341,7 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
                     [tab_i, jnp.zeros((Gp, Vs + 1, padv), jnp.int32)],
                     axis=2)
             res["tab_s"], res["tab_i"] = tab_s, tab_i
-    cnt = outs[oi].sum(axis=0)                        # [Gp, CNT]
+    cnt = outs[oi][:, :, :CNT].sum(axis=0)            # [Gp, CNT]
     res["n_feas"] = cnt[:, 0].astype(jnp.int32)
     res["n_exh"] = cnt[:, 1].astype(jnp.int32)
     res["grp_any"] = cnt[:, 2] > 0
@@ -367,34 +349,57 @@ def fused_wave(*, mode, feas, blocked, aff, pen, jitter,
     return res
 
 
-def _extract_topk(sc, col_ids, n_out, write):
-    """Iteratively pop the row-wise max `n_out` times, ties broken by
-    LOWER column (lax.top_k's order).  `write(j, vals, cols)` stores
-    slot j.  Runs entirely on VMEM-resident values."""
+def _unpack_groups(words, Gp, T):
+    """[ceil(Gp/32), T] group-packed int32 words -> [Gp, T] bool: row g
+    reads bit g % 32 of word row g // 32 (a sublane broadcast and a
+    per-row shift — no lane movement)."""
+    rows = [jnp.broadcast_to(words[k:k + 1, :], (min(32, Gp - 32 * k), T))
+            for k in range(words.shape[0])]
+    w = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+    bit = lax.broadcasted_iota(jnp.int32, (Gp, T), 0) & 31
+    return (lax.shift_right_logical(w, bit) & 1) != 0
 
-    def body(j, sc):
+
+def _extract_topk(sc, col_ids, n_out, width, base):
+    """Iteratively pop the row-wise max `n_out` times, ties broken by
+    LOWER column (lax.top_k's order).  Returns ([Gp, width] scores,
+    [Gp, width] node ids = column + base); slot j holds the j-th pop,
+    slots >= n_out stay at the _EXTRACTED filler.  The outputs are
+    carried as lane-dense values and filled by iota select."""
+    Gp = sc.shape[0]
+    slot = lax.broadcasted_iota(jnp.int32, (Gp, width), 1)
+
+    def body(j, carry):
+        sc, out_s, out_i = carry
         m = jnp.max(sc, axis=1, keepdims=True)             # [Gp, 1]
         am = jnp.min(jnp.where(sc == m, col_ids, jnp.int32(1 << 30)),
                      axis=1, keepdims=True)                # [Gp, 1]
-        write(j, m, am)
-        return jnp.where(col_ids == am, jnp.float32(_EXTRACTED), sc)
+        hit = slot == j
+        out_s = jnp.where(hit, m, out_s)
+        out_i = jnp.where(hit, am + base, out_i)
+        sc = jnp.where(col_ids == am, jnp.float32(_EXTRACTED), sc)
+        return sc, out_s, out_i
 
-    lax.fori_loop(0, n_out, body, sc)
+    _, out_s, out_i = lax.fori_loop(
+        0, n_out, body,
+        (sc, jnp.full((Gp, width), _EXTRACTED, jnp.float32),
+         jnp.zeros((Gp, width), jnp.int32)))
+    return out_s, out_i
 
 
-def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
-                      has_devices, has_spread, has_blocked,
+def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, TKp, Vs,
+                      TKvt, TKvp, has_devices, has_spread, has_blocked,
                       want_tables):
     """The fused per-tile pass.  Positional refs mirror fused_wave's
     input/output assembly exactly."""
     it = iter(refs)
-    feas_ref = next(it)          # packed u32 words
-    pen_ref = next(it)           # packed u32 words
+    feas_ref = next(it)          # group-packed i32 words
+    pen_ref = next(it)           # group-packed i32 words
     aff_ref = next(it)
     jitter_ref = next(it)
     coll_ref = next(it)
-    blocked_ref = next(it) if has_blocked else None   # packed u32
-    used_ref = next(it)
+    blocked_ref = next(it) if has_blocked else None   # packed i32
+    used_ref = next(it)          # [R, T]
     avail_ref = next(it)
     reserved_ref = next(it)
     ask_res_ref = next(it)
@@ -421,39 +426,36 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
     i = pl.program_id(0)
     f32 = jnp.float32
 
-    from .masks import unpack_bool_u32
-    feas_b = unpack_bool_u32(feas_ref[...], T)         # [Gp, T]
+    feas_b = _unpack_groups(feas_ref[...], Gp, T)      # [Gp, T]
     if has_blocked:
-        feas_b &= ~unpack_bool_u32(blocked_ref[...], T)
+        feas_b &= ~_unpack_groups(blocked_ref[...], Gp, T)
 
     # ---- resource fit + bin-pack, one static unroll over R ----
-    ask_res = ask_res_ref[...]                         # [Gp, R]
+    # node rows are (1, T) lane-dense slices, ask columns (Gp, 1)
     fit = jnp.ones((Gp, T), bool)
     dim_fail = []
     util_cpu = util_mem = None
     denom_cpu = denom_mem = None
     for r in range(R):
-        after_r = (used_ref[:, r][None, :]
-                   + ask_res[:, r][:, None])           # [Gp, T]
-        fit_r = after_r <= avail_ref[:, r][None, :]
+        avail_r = avail_ref[r:r + 1, :]
+        after_r = used_ref[r:r + 1, :] + ask_res_ref[:, r:r + 1]
+        fit_r = after_r <= avail_r
         fit &= fit_r
-        dim_fail.append(jnp.sum((feas_b & ~fit_r).astype(f32), axis=1))
+        dim_fail.append(jnp.sum((feas_b & ~fit_r).astype(f32), axis=1,
+                                keepdims=True))
         if r == _R_CPU:
-            util_cpu = after_r + reserved_ref[:, r][None, :]
-            denom_cpu = avail_ref[:, r][None, :]
+            util_cpu = after_r + reserved_ref[r:r + 1, :]
+            denom_cpu = avail_r
         elif r == _R_MEM:
-            util_mem = after_r + reserved_ref[:, r][None, :]
-            denom_mem = avail_ref[:, r][None, :]
+            util_mem = after_r + reserved_ref[r:r + 1, :]
+            denom_mem = avail_r
 
+    dev_fit = jnp.ones((Gp, T), bool)
     if has_devices:
-        dev_fit = jnp.ones((Gp, T), bool)
-        dev_ask = dev_ask_ref[...]
         for d in range(D):
-            dev_fit &= ((dev_used_ref[:, d][None, :]
-                         + dev_ask[:, d][:, None])
-                        <= dev_cap_ref[:, d][None, :])
-    else:
-        dev_fit = jnp.ones((Gp, T), bool)
+            dev_fit &= ((dev_used_ref[d:d + 1, :]
+                         + dev_ask_ref[:, d:d + 1])
+                        <= dev_cap_ref[d:d + 1, :])
 
     placeable = feas_b & fit & dev_fit
 
@@ -475,26 +477,26 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
     # ---- spread (targeted + even), select-sum over the value vocab ----
     if has_spread:
         spread_total = jnp.zeros((Gp, T), f32)
-        sp_used = sp_used_ref[...]                     # [Gp, S, V]
         for s in range(S):
-            has = sp_has_ref[:, s][:, None] != 0       # [Gp, 1]
+            has = sp_has_ref[:, s:s + 1] != 0          # [Gp, 1]
             v = sp_vnode_ref[s]                        # [Gp, T]
             has_v = v >= 0
             cur = jnp.zeros((Gp, T), f32)
             for val in range(V):
+                k = s * V + val
                 cur = cur + jnp.where(v == val,
-                                      sp_used[:, s, val][:, None],
+                                      sp_used_ref[:, k:k + 1],
                                       f32(0.0))
             desired = sp_des_ref[s]                    # [Gp, T]
             boost = ((desired - (cur + f32(1.0)))
                      / jnp.maximum(desired, f32(1e-9))
-                     ) * sp_w_ref[:, s][:, None]
+                     ) * sp_w_ref[:, s:s + 1]
             targeted = jnp.where(~has_v, f32(-1.0),
                                  jnp.where(desired <= 0, f32(-1.0),
                                            boost))
-            minc = minc_ref[:, s][:, None]
-            maxc = maxc_ref[:, s][:, None]
-            anyp = anyp_ref[:, s][:, None] != 0
+            minc = minc_ref[:, s:s + 1]
+            maxc = maxc_ref[:, s:s + 1]
+            anyp = anyp_ref[:, s:s + 1] != 0
             delta_boost = (minc - cur) / jnp.maximum(minc, f32(1e-9))
             even = jnp.where(cur != minc, delta_boost,
                              jnp.where(minc == maxc, f32(-1.0),
@@ -502,7 +504,7 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
                                        / jnp.maximum(minc, f32(1e-9))))
             even = jnp.where(~has_v, f32(-1.0), even)
             even = jnp.where(anyp, even, f32(0.0))
-            contrib = jnp.where(sp_t_ref[:, s][:, None] != 0, targeted,
+            contrib = jnp.where(sp_t_ref[:, s:s + 1] != 0, targeted,
                                 even)
             spread_total = spread_total + jnp.where(has, contrib,
                                                     f32(0.0))
@@ -515,7 +517,7 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
     # EXACT float summation order of kernel.group_scores: f32 addition
     # is not associative, and the pallas path must be bitwise the
     # kernel/host twin's score for placement-identity to hold
-    pen_counts = unpack_bool_u32(pen_ref[...], T)
+    pen_counts = _unpack_groups(pen_ref[...], Gp, T)
     pen_score = jnp.where(pen_counts, f32(-1.0), f32(0.0))
     aff_sc = aff_ref[...]
     aff_counts = aff_sc != 0.0
@@ -529,12 +531,17 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
     total = total + jitter_ref[...]
     score = jnp.where(placeable, total, f32(NEG_INF))
 
-    # ---- explainability counters for this tile (one 2-D store) ----
-    n_feas_t = jnp.sum(feas_b.astype(f32), axis=1)
-    n_exh_t = jnp.sum((feas_b & ~(fit & dev_fit)).astype(f32), axis=1)
-    any_t = jnp.max(placeable.astype(f32), axis=1)
-    cnt_ref[0] = jnp.stack([n_feas_t, n_exh_t, any_t] + dim_fail,
-                           axis=1)                     # [Gp, 3 + R]
+    # ---- explainability counters for this tile: (Gp, 1) reductions
+    # laid into one lane-dense (Gp, 128) block by iota select ----
+    n_feas_t = jnp.sum(feas_b.astype(f32), axis=1, keepdims=True)
+    n_exh_t = jnp.sum((feas_b & ~(fit & dev_fit)).astype(f32), axis=1,
+                      keepdims=True)
+    any_t = jnp.max(placeable.astype(f32), axis=1, keepdims=True)
+    lane = lax.broadcasted_iota(jnp.int32, (Gp, _LANES), 1)
+    cnt = jnp.zeros((Gp, _LANES), f32)
+    for k, col in enumerate([n_feas_t, n_exh_t, any_t] + dim_fail):
+        cnt = jnp.where(lane == k, col, cnt)
+    cnt_ref[0] = cnt
 
     if mode == "score":
         score_ref[...] = score
@@ -543,21 +550,13 @@ def _wave_tile_kernel(*refs, mode, Gp, T, R, D, S, V, TKt, Vs, TKvt,
     # ---- in-kernel per-tile top-K extraction ----
     local_cols = lax.broadcasted_iota(jnp.int32, (Gp, T), 1)
     base = i * T
-
-    def write_main(j, vals, cols):
-        ts_ref[:, pl.ds(j, 1)] = vals
-        ti_ref[:, pl.ds(j, 1)] = cols + base
-
-    _extract_topk(score, local_cols, TKt, write_main)
+    ts_ref[...], ti_ref[...] = _extract_topk(score, local_cols, TKt,
+                                             TKp, base)
 
     if want_tables:
         vnode0 = sp_vnode_ref[0]                       # [Gp, T]
         for vv in range(Vs + 1):
             vmask = (vnode0 == vv) if vv < Vs else (vnode0 < 0)
             sv = jnp.where(vmask, score, f32(NEG_INF))
-
-            def write_v(j, vals, cols, vv=vv):
-                vts_ref[vv, :, pl.ds(j, 1)] = vals
-                vti_ref[vv, :, pl.ds(j, 1)] = cols + base
-
-            _extract_topk(sv, local_cols, TKvt, write_v)
+            vts_ref[vv], vti_ref[vv] = _extract_topk(
+                sv, local_cols, TKvt, TKvp, base)

@@ -17,6 +17,7 @@ import numpy as np
 from ..structs import (AllocatedDeviceResource, AllocatedResources,
                        AllocatedSharedResources, AllocatedTaskResources,
                        AllocMetric, DeviceAccounter, NetworkIndex, Node)
+from ..utils.metrics import global_metrics as _m
 from .kernel import TOP_K, solve_kernel
 from .tensorize import (NUM_R, ClusterDelta, PackedBatch, PlacementAsk,
                         Tensorizer, alloc_device_usage,
@@ -157,7 +158,6 @@ class _ResidentWorld:
         return added
 
     def rebuild(self, snapshot) -> None:
-        from ..utils.metrics import global_metrics as _m
         _m.incr_counter("solver.resident.rebuild")
         self.nodes = list(snapshot.nodes())          # join order
         by_node: Dict[str, list] = {}
@@ -241,7 +241,6 @@ class _ResidentWorld:
                         delta.stop.append(tracked)
                         delta.place.append((a.node_id, a))
                     self.live[key] = (a.node_id, a)
-        from ..utils.metrics import global_metrics as _m
         self.counters["delta_syncs"] += 1
         _m.incr_counter("solver.resident.delta_sync")
         if delta.empty():
@@ -317,6 +316,11 @@ class Placement:
     metrics: AllocMetric
     resources: Optional[AllocatedResources] = None
     failed_reason: str = ""
+    #: the solve's wave budget ran out before this placement was decided
+    #: (kernel `unfinished`): nothing says capacity is missing, so the
+    #: scheduler re-solves it at once instead of blocking the eval on a
+    #: capacity change that need not come
+    retryable: bool = False
     #: alloc ids the in-kernel preemption pass selected as victims for
     #: this placement (empty for normal placements) — the scheduler
     #: turns these into plan.node_preemptions
@@ -626,6 +630,8 @@ class Solver:
                 pb = self._tensorizer.pack(nodes, asks, allocs_by_node)
         from .watchdog import global_watchdog
         _t_pack_done = _t.perf_counter()
+        if self._degraded:
+                _m.incr_counter("solver.degraded")
         res = _run_kernel(pb, host_mode=self._host,
                           max_waves=BROWNOUT_MAX_WAVES
                           if self._degraded else 0,
@@ -653,6 +659,15 @@ class Solver:
         trace_attrs["kernel_wall_s"] = round(
             _t.perf_counter() - _solve_t0, 6)
         trace_attrs["resident"] = used_resident
+        # where solves answer from, as counters: an operator (and
+        # chip_smoke.py) can see a solve leave the device — prefer_host,
+        # a watchdog failover, pallas resolving to "off" — without
+        # reading traces
+        _m.incr_counter(f"solver.solve.{trace_attrs['platform']}")
+        _m.incr_counter(f"solver.pallas.{trace_attrs['pallas_mode']}")
+        _m.incr_counter("solver.waves", trace_attrs["waves"])
+        _m.incr_counter("solver.rescore_waves",
+                        trace_attrs["rescore_waves"])
         if used_resident:
             world = self._world
             if world is not None:
@@ -771,14 +786,15 @@ class Solver:
             if placed is None:
                 if unfinished[p]:
                     # the wave budget ran out before this placement was
-                    # decided; the blocked-eval path will retry it
+                    # decided; the scheduler's retry loop re-solves it
                     reason = "solve wave budget exhausted (retryable)"
                 elif n_feasible[p] > 0:
                     reason = "resources exhausted"
                 else:
                     reason = "no feasible nodes"
                 placed = Placement(ask_index=g, node=None, score=0.0,
-                                   metrics=m, failed_reason=reason)
+                                   metrics=m, failed_reason=reason,
+                                   retryable=bool(unfinished[p]))
             by_p[p] = placed
         # emit in ask order regardless of replay order: the scheduler
         # maps placements back to its per-ask missing queues by
@@ -1003,14 +1019,41 @@ def solve_trace_attrs(pb: PackedBatch, res,
                if res.n_rescore is not None else waves)
     evicted = (int(_np.asarray(res.evict).any(axis=1).sum())
                if res.evict is not None else 0)
-    backend = ("host" if type(res.choice).__module__
-               .startswith("numpy") else "device")
+    # where the result arrays live: numpy means a host twin answered
+    # (prefer_host, a watchdog failover); a jax array names its
+    # device's platform, so a CPU-backend run can never pass for a chip
+    if isinstance(res.choice, _np.ndarray):
+        backend, platform = "host", "numpy"
+    else:
+        backend = "device"
+        platform = next(iter(res.choice.devices())).platform
+    from . import pallas_kernel as _pk
+    from .kernel import resolve_shortlist_c, window_tk
+    from .resident import model_wave_bytes
+    Np, R = pb.avail.shape
+    Gp = pb.ask_res.shape[0]
+    K = pb.p_ask.shape[0]
+    S, V = pb.sp_desired.shape[1:3]
+    has_spread = bool((_np.asarray(pb.sp_col[:, 0]) >= 0).any())
+    # the one-shot path passes no group_count_hint, so this is the
+    # window — and with it the pallas mode — solve_kernel resolved
+    TK = window_tk(Gp, K, Np)
+    mode = ("off" if backend == "host"
+            else _pk.resolve_mode(Np, Gp, TK, V, has_spread))
+    C = (0 if bool((_np.asarray(pb.distinct) >= 0).any())
+         else resolve_shortlist_c(Np, TK, 0))
+    b1, brw, _passes = model_wave_bytes(Np, Gp, K, S, R, has_spread,
+                                        mode, TK, C)
     attrs = {"n_asks": int(pb.n_asks), "n_place": int(pb.n_place),
              "n_nodes": int(pb.n_real), "backend": backend,
+             "platform": platform, "pallas_mode": mode,
              "waves": waves, "rescore_waves": rescore,
              "shortlist_waves": waves - rescore,
              "evict_commits": evicted,
-             "unfinished": int(_np.asarray(res.unfinished).sum())}
+             "unfinished": int(_np.asarray(res.unfinished).sum()),
+             "bytes_wave1": int(b1), "bytes_rewave": int(brw),
+             "modeled_bytes_total": int(
+                 b1 * rescore + brw * (waves - rescore))}
     if lane_counters is not None:
         attrs["lanes"] = int(lane_counters.get("lanes", 1))
         attrs["lane_chunks"] = int(lane_counters.get("chunks", 0))
@@ -1018,33 +1061,6 @@ def solve_trace_attrs(pb: PackedBatch, res,
         attrs["lane_committed"] = int(lane_counters.get("committed", 0))
         attrs["lane_bounce_rate"] = float(
             lane_counters.get("bounce_rate", 0.0))
-    try:
-        # modeled bytes mirror ResidentSolver.wave_traffic's resolution
-        # (best effort: a model failure must never fail a solve)
-        from . import pallas_kernel as _pk
-        from .kernel import (MERGED_GP_MAX, TOP_K as _TK, WAVE_K,
-                             _MERGED_W_CAP, _WIDE_W_CAP,
-                             resolve_shortlist_c)
-        from .resident import model_wave_bytes
-        Np, R = pb.avail.shape
-        Gp = pb.ask_res.shape[0]
-        K = pb.p_ask.shape[0]
-        S = pb.sp_desired.shape[1]
-        has_spread = bool((_np.asarray(pb.sp_col[:, 0]) >= 0).any())
-        w_cap = (_MERGED_W_CAP if Gp <= MERGED_GP_MAX else _WIDE_W_CAP)
-        TKw = min(max(WAVE_K, w_cap) + _TK, Np)
-        C = (0 if bool((_np.asarray(pb.distinct) >= 0).any())
-             else resolve_shortlist_c(Np, TKw, 0))
-        V = pb.sp_desired.shape[2]
-        mode = _pk.resolve_mode(Np, Gp, TKw, V, has_spread)
-        b1, brw, _passes = model_wave_bytes(Np, Gp, K, S, R,
-                                            has_spread, mode, TKw, C)
-        attrs["bytes_wave1"] = int(b1)
-        attrs["bytes_rewave"] = int(brw)
-        attrs["modeled_bytes_total"] = int(
-            b1 * rescore + brw * (waves - rescore))
-    except Exception:
-        pass
     return attrs
 
 

@@ -255,6 +255,70 @@ class StateStore(StateSnapshot):
         with self._lock:
             return self.changelog.since(min_index, max_index)
 
+    # -- live reads --
+    # The inherited snapshot readers walk plain dict tables.  On the
+    # LIVE store a concurrent plan apply resizes those tables mid-walk
+    # ("dictionary changed size during iteration"), so every inherited
+    # reader that iterates is re-issued here under the store lock and
+    # hands back a copy.  Point lookups (one dict.get) stay inherited.
+    # A reader that needs several consistent reads takes snapshot().
+    def nodes(self) -> List[Node]:
+        with self._lock:
+            return list(super().nodes())
+
+    def ready_nodes_in_dcs(self, datacenters: List[str]
+                           ) -> Tuple[List[Node], Dict[str, int]]:
+        with self._lock:
+            return super().ready_nodes_in_dcs(datacenters)
+
+    def jobs(self) -> List[Job]:
+        with self._lock:
+            return list(super().jobs())
+
+    def jobs_by_namespace(self, namespace: str) -> List[Job]:
+        with self._lock:
+            return super().jobs_by_namespace(namespace)
+
+    def evals(self) -> List[Evaluation]:
+        with self._lock:
+            return list(super().evals())
+
+    def evals_by_job(self, namespace: str, job_id: str
+                     ) -> List[Evaluation]:
+        with self._lock:
+            return super().evals_by_job(namespace, job_id)
+
+    def allocs(self) -> List[Allocation]:
+        with self._lock:
+            return list(super().allocs())
+
+    def allocs_by_node(self, node_id: str) -> List[Allocation]:
+        with self._lock:
+            return super().allocs_by_node(node_id)
+
+    def allocs_by_job(self, namespace: str, job_id: str,
+                      anyCreateIndex: bool = True) -> List[Allocation]:
+        with self._lock:
+            return super().allocs_by_job(namespace, job_id,
+                                         anyCreateIndex)
+
+    def allocs_by_eval(self, eval_id: str) -> List[Allocation]:
+        with self._lock:
+            return super().allocs_by_eval(eval_id)
+
+    def allocs_by_deployment(self, dep_id: str) -> List[Allocation]:
+        with self._lock:
+            return super().allocs_by_deployment(dep_id)
+
+    def deployments(self) -> List[Deployment]:
+        with self._lock:
+            return list(super().deployments())
+
+    def deployments_by_job(self, namespace: str, job_id: str
+                           ) -> List[Deployment]:
+        with self._lock:
+            return super().deployments_by_job(namespace, job_id)
+
     # -- snapshot & watch --
     def snapshot(self) -> StateSnapshot:
         with self._lock:

@@ -9,6 +9,7 @@ runtime consequences).  The SHARD/ALIAS fixtures include seeded
 reproductions of the three shipped historical bugs (PR-5 zero-copy
 device_put aliasing, GSPMD double-applied scatter, PR-4 donated-carry
 read) so the passes provably catch what we actually shipped."""
+import functools
 import os
 import textwrap
 
@@ -1277,11 +1278,18 @@ def test_robust_error_tier():
     assert pass_of("ROBUST701") == "robust"
 
 
+@functools.lru_cache(maxsize=1)
+def _repo_report():
+    """analyze() over the real package, once per session: four gates
+    below read the same read-only report (a full pass is ~12 s)."""
+    return analyze()
+
+
 def test_repo_robust_zero_unsuppressed():
     """The recovery-critical planes carry zero unsuppressed swallowed
     exceptions; deliberate probe/trace fallbacks are baselined with
     justifications."""
-    rep = analyze()
+    rep = _repo_report()
     bad = [f for f in rep.findings if f.rule.startswith("ROBUST")]
     assert not bad, "\n".join(f.render() for f in bad)
 
@@ -1328,7 +1336,7 @@ def test_repo_obs_zero_unsuppressed():
     """Every metric/series name in the real package is a registered
     lowercase dotted literal; the bounded dynamic sites carry baseline
     justifications naming the bound."""
-    rep = analyze()
+    rep = _repo_report()
     bad = [f for f in rep.findings if f.rule.startswith("OBS")]
     assert not bad, "\n".join(f.render() for f in bad)
 
@@ -1368,7 +1376,7 @@ def test_repo_baseline_is_valid_and_fresh():
 def test_repo_has_zero_unsuppressed_findings():
     """The tier-1 gate: any new unsuppressed finding fails the suite.
     Fix the code or add a JUSTIFIED baseline entry."""
-    rep = analyze()
+    rep = _repo_report()
     assert rep.ok, "unsuppressed nomadlint findings:\n" + "\n".join(
         f.render() for f in rep.findings)
     # and the baseline itself must not rot
@@ -1449,7 +1457,7 @@ def test_repo_new_passes_have_no_unsuppressed_findings():
     """Zero-unsuppressed gate extension for SHARD4xx/ALIAS5xx/SCORE6xx
     specifically (the combined gate above covers everything; this one
     localizes a regression to the new passes)."""
-    rep = analyze()
+    rep = _repo_report()
     new = [f for f in rep.findings
            if f.rule.startswith(("SHARD", "ALIAS", "SCORE"))]
     assert not new, "\n".join(f.render() for f in new)

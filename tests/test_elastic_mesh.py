@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from nomad_tpu.parallel.sharded import (_ARG_SPECS,
@@ -71,8 +70,8 @@ def mesh_solve_two_tier(args, n_hosts, n_chips, **kw):
     out_specs = out_specs._replace(feas=P(None, AX2),
                                    used_final=P(AX2, None),
                                    dev_used_final=P(AX2, None))
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False))
     return f(*args)
 
 
@@ -208,9 +207,9 @@ def test_elastic_remap_kernel_matches_host(mode, two_tier):
     out_specs = out_specs._replace(feas=P(None, nspec),
                                    used_final=P(nspec, None),
                                    dev_used_final=P(nspec, None))
-    f = jax.jit(shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=in_specs + (gid_spec, P(), P()),
-                          out_specs=out_specs, check_rep=False))
+                          out_specs=out_specs, check_vma=False))
     res = f(*ek_args, gid, om, sm)
 
     # scalar/per-ask outputs compare directly; plane outputs compare

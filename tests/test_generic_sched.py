@@ -293,3 +293,42 @@ def test_spread_across_datacenters():
     nodes_by_id = {n.id: n for n in h.store.nodes()}
     dcs = [nodes_by_id[a.node_id].datacenter for a in allocs]
     assert dcs.count("dc1") == 2 and dcs.count("dc2") == 2
+
+
+def test_wave_budget_leftovers_retry_instead_of_blocking():
+    """Placements the solve's wave budget left undecided are not
+    capacity failures: the eval submits what was decided and goes round
+    again.  Recording them as failures parked the eval behind a blocked
+    eval that only a capacity change wakes — at deployment size, with
+    capacity plentiful, a third of a fused batch hung there for good
+    (ISSUE 21)."""
+    from nomad_tpu.solver.solve import Placement, Solver
+
+    class BudgetStarved(Solver):
+        calls = 0
+
+        def solve(self, *a, **kw):
+            out = super().solve(*a, **kw)
+            type(self).calls += 1
+            if self.calls == 1:         # first round: 3 left undecided
+                for i in (-1, -2, -3):
+                    p = out.placements[i]
+                    out.placements[i] = Placement(
+                        ask_index=p.ask_index, node=None, score=0.0,
+                        metrics=p.metrics, retryable=True,
+                        failed_reason="solve wave budget exhausted "
+                                      "(retryable)")
+            return out
+
+    h = Harness()
+    h.solver = BudgetStarved(host="always")
+    setup_cluster(h)
+    job = mock.job()           # count=10
+    ev = register_job(h, job)
+    h.process("service", ev)
+
+    assert BudgetStarved.calls == 2 and len(h.plans) == 2
+    assert len(h.store.allocs_by_job("default", job.id)) == 10
+    assert h.create_evals == []              # nothing blocked
+    assert h.evals[-1].status == EVAL_STATUS_COMPLETE
+    assert not h.evals[-1].failed_tg_allocs

@@ -29,7 +29,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from nomad_tpu.parallel.federated import (CrossRegionResidentSolver,
@@ -80,8 +79,8 @@ def mesh_solve_three_tier(args, n_regions, n_hosts, n_chips, **kw):
     out_specs = out_specs._replace(feas=P(None, AX3),
                                    used_final=P(AX3, None),
                                    dev_used_final=P(AX3, None))
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False))
     return f(*args)
 
 

@@ -187,9 +187,8 @@ def mesh_solve_preempt(pb, n_shards, **kw):
     out_specs = out_specs._replace(feas=P(None, "nodes"),
                                    used_final=P("nodes", None),
                                    dev_used_final=P("nodes", None))
-    from jax.experimental.shard_map import shard_map
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False))
     return f(*(args + extra))
 
 

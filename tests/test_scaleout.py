@@ -293,6 +293,52 @@ def test_coordinator_relays_solve_error_to_every_submitter():
         server.stop()
 
 
+def test_submitter_waits_out_a_slow_round_and_abandons_only_orphans(
+        monkeypatch):
+    """A round slower than the submit patience (a cold TPU compile) must
+    not make its submitters nack evals the drain leader still holds —
+    that hands them to a second scheduler.  Only a batch no leader will
+    ever take (paused coordinator) is withdrawn and handed back."""
+    from nomad_tpu.scheduler import fleet
+    monkeypatch.setattr(fleet, "SUBMIT_PATIENCE_S", 0.05)
+    release = threading.Event()
+    solved = []
+
+    def slow_solve(_server, _worker, combined):
+        release.wait(10.0)
+        solved.append(len(combined))
+
+    coord = SolveCoordinator(None, max_fused=1, solve_fn=slow_solve)
+    errors = []
+
+    def submit(tag):
+        try:
+            coord.submit(None, [(mock.eval_(job_id=tag), "tok")])
+        except Exception as exc:
+            errors.append(exc)
+
+    # one becomes the leader and sits in the slow round, the other is
+    # queued behind it; both outlast many patience slices
+    threads = [threading.Thread(target=submit, args=(f"j{i}",))
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    assert wait_until(lambda: coord.pending() == 1, timeout=5.0)
+    time.sleep(0.3)
+    assert not errors and all(t.is_alive() for t in threads)
+    release.set()
+    for t in threads:
+        t.join(timeout=10.0)
+    assert not errors and solved == [1, 1]
+
+    # no leader will come for this one: withdrawn, raised
+    coord.pause()
+    submit("orphan")
+    assert len(errors) == 1 and isinstance(errors[0], TimeoutError)
+    assert coord.pending() == 0
+    coord.resume()
+
+
 # ------------------------------------------------------------------
 # Group-commit plan applies
 # ------------------------------------------------------------------

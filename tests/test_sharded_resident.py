@@ -10,7 +10,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from nomad_tpu import mock
@@ -45,8 +44,8 @@ def mesh_solve(args, n_shards, **kw):
     out_specs = out_specs._replace(feas=P(None, "nodes"),
                                    used_final=P("nodes", None),
                                    dev_used_final=P("nodes", None))
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False))
     return f(*args)
 
 

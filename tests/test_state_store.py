@@ -163,3 +163,65 @@ def test_deployment_lifecycle():
     s.upsert_plan_results(4, result)
     assert (s.deployment_by_id(d.id).status
             == structs.DEPLOYMENT_STATUS_SUCCESSFUL)
+
+
+def test_live_readers_survive_concurrent_writes():
+    """A reader polling the LIVE store while plans apply (an HTTP
+    handler, chip_smoke.py) must never die with "dictionary changed
+    size during iteration": every reader that walks a table copies it
+    under the store lock (ISSUE 21).  More threads than cores, a
+    shortened switch interval, time-bounded."""
+    import sys
+
+    s = StateStore()
+    job = mock.job()
+    s.upsert_job(1, job)
+    n = mock.node()
+    s.upsert_node(2, n)
+    stop = threading.Event()
+    errors = []
+
+    def writer(base):
+        ix = base
+        while not stop.is_set():
+            ix += 2
+            ev = mock.eval_(job_id=job.id)
+            s.upsert_evals(ix, [ev])
+            a = mock.alloc(job=job, node_id=n.id, eval_id=ev.id)
+            s.upsert_allocs(ix + 1, [a])
+            if ix % 7 == 0:
+                s.delete_eval(ix + 1, [ev.id], [a.id])
+
+    def reader():
+        try:
+            while not stop.is_set():
+                s.evals_by_job(job.namespace, job.id)
+                list(s.evals())
+                list(s.allocs())
+                s.allocs_by_node(n.id)
+                s.allocs_by_job(job.namespace, job.id)
+                s.allocs_by_eval("nope")
+                list(s.jobs())
+                list(s.nodes())
+                s.ready_nodes_in_dcs(["dc1"])
+        except Exception as e:       # relayed to the asserting thread
+            errors.append(e)
+            stop.set()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = ([threading.Thread(target=writer, args=(10 + 1_000_000 * i,))
+                    for i in range(4)]
+                   + [threading.Thread(target=reader) for _ in range(12)])
+        for t in threads:
+            t.start()
+        time.sleep(1.5)
+        stop.set()
+        for t in threads:
+            t.join(timeout=10.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert errors == []
+    assert len(s.evals_by_job(job.namespace, job.id)) > 0
