@@ -191,9 +191,12 @@ class AnalysisConfig:
         "rpc", "scheduler", "serving", "slo", "solver", "telemetry",
         "watchdog", "worker",
     )
-    # the sinks themselves (name arrives as a parameter there)
+    # the sinks themselves (name arrives as a parameter there; the
+    # tracer's layer spans write the sample `span.<name>`, or the key
+    # their call site gives, and every call site passes literals)
     obs_exclude_modules: Tuple[str, ...] = (
         "nomad_tpu.utils.metrics", "nomad_tpu.telemetry.series",
+        "nomad_tpu.utils.tracing",
     )
     # RACE9xx / LOCK305 scope: the planes whose thread-shared classes
     # get Eraser-style guarded-by inference and blocking-under-lock

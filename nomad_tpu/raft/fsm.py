@@ -20,6 +20,7 @@ from ..structs import (Allocation, DeploymentStatusUpdate,
                        DesiredTransition, Deployment, Evaluation, Job, Node,
                        PlanResult)
 from ..utils.codec import from_wire, to_wire
+from ..utils.tracing import global_tracer
 
 # entry type -> (payload struct fields needing decode)
 NOOP = "noop"
@@ -41,7 +42,14 @@ class StateFSM:
         handler = getattr(self, "_ap_" + etype, None)
         if handler is None:
             raise ValueError(f"unknown raft entry type {etype!r}")
-        handler(index, p)
+        if etype in ("plan_result", "plan_results_batch"):
+            # the Plan-apply layer's FSM half (from_wire + the store
+            # upsert): a child of `plan.raft_apply` where the single-
+            # voter raft applies inside the dispatch
+            with global_tracer.layer("fsm.apply", etype=etype):
+                handler(index, p)
+        else:
+            handler(index, p)
 
     def _ap_node_upsert(self, index, p):
         self.store.upsert_node(index, from_wire(Node, p["node"]))

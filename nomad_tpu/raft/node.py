@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..utils.tracing import global_tracer
 from .fsm import NOOP, StateFSM
 from .log import LogEntry, RaftLog
 
@@ -583,16 +584,19 @@ class RaftNode:
 
     # --------------------------------------------------------- snapshots
     def _compact_locked(self) -> None:
-        data = self.fsm.snapshot()
-        self.snapshot_term = self.log.term_at(self.last_applied)
-        self.snapshot_index = self.last_applied
-        if self._snap_path:
-            tmp = self._snap_path + ".tmp"
-            with open(tmp, "wb") as f:
-                f.write(data)
-            os.replace(tmp, self._snap_path)
-        self.log.compact_to(self.snapshot_index)
-        self._save_meta_locked()
+        # runs inside whichever propose crossed the threshold: the span
+        # names the stall in that caller's trace
+        with global_tracer.layer("raft.compact", index=self.last_applied):
+            data = self.fsm.snapshot()
+            self.snapshot_term = self.log.term_at(self.last_applied)
+            self.snapshot_index = self.last_applied
+            if self._snap_path:
+                tmp = self._snap_path + ".tmp"
+                with open(tmp, "wb") as f:
+                    f.write(data)
+                os.replace(tmp, self._snap_path)
+            self.log.compact_to(self.snapshot_index)
+            self._save_meta_locked()
 
     def _read_snapshot(self) -> bytes:
         if self._snap_path and os.path.exists(self._snap_path):

@@ -23,6 +23,8 @@ from typing import Dict, List, Optional, Tuple
 
 from ..structs import (EVAL_STATUS_COMPLETE, EVAL_STATUS_FAILED, Allocation,
                        Evaluation, JOB_TYPE_BATCH, JOB_TYPE_SERVICE)
+from ..utils.metrics import global_metrics as _m
+from ..utils.tracing import global_tracer as _tr
 from .generic import GenericScheduler, _VALID_TRIGGERS
 
 _log = logging.getLogger(__name__)
@@ -51,7 +53,6 @@ def record_stage_metrics(stages: Dict[str, float],
     """Publish one round's stage breakdown as metrics histograms
     (explicit latency buckets, surfaced at /v1/metrics and consumed by
     bench.py --scaleout)."""
-    from ..utils.metrics import global_metrics as _m
     for name, v in stages.items():
         _m.observe_hist(f"{prefix}.{name}_s", float(v))
 
@@ -218,74 +219,74 @@ def fleet_begin(server, worker, batch: List[Tuple[Evaluation, str]]
     path can't carry (single-eval processed inline), build the shared
     world ONCE, and run every member's reconcile + ask assembly against
     it.  Returns None when nothing is left to fuse."""
-    t0 = _time.perf_counter()
-    # the fused pass can outlive the nack timeout for tail-of-batch
-    # evals; hold the timers while we own the batch (explicit ack/nack
-    # follows) — one lock hold per touched shard, not per eval
-    server.broker.pause_nack_batch([(ev.id, tok) for ev, tok in batch])
+    with _tr.layer("fleet.reconcile") as reconcile:
+        # the fused pass can outlive the nack timeout for tail-of-batch
+        # evals; hold the timers while we own the batch (explicit ack/nack
+        # follows) — one lock hold per touched shard, not per eval
+        server.broker.pause_nack_batch([(ev.id, tok) for ev, tok in batch])
 
-    fused: List[_Entry] = []
-    for ev, token in batch:
-        if ev.type not in (JOB_TYPE_SERVICE, JOB_TYPE_BATCH) \
-                or ev.triggered_by not in _VALID_TRIGGERS:
-            worker._process(ev, token)
-            continue
-        fused.append(_Entry(ev, token, GenericScheduler(
-            server.store, worker, batch=(ev.type == JOB_TYPE_BATCH),
-            solver=worker.fleet_solver())))
-    if not fused:
-        return None
+        fused: List[_Entry] = []
+        for ev, token in batch:
+            if ev.type not in (JOB_TYPE_SERVICE, JOB_TYPE_BATCH) \
+                    or ev.triggered_by not in _VALID_TRIGGERS:
+                worker._process(ev, token)
+                continue
+            fused.append(_Entry(ev, token, GenericScheduler(
+                server.store, worker, batch=(ev.type == JOB_TYPE_BATCH),
+                solver=worker.fleet_solver())))
+        if not fused:
+            return None
 
-    rnd = _FleetRound()
-    rnd.fused = fused
-    wait_index = max(max(e.ev.modify_index, e.ev.snapshot_index)
-                     for e in fused)
-    server.store.wait_for_index(wait_index, timeout=5.0)
-    snapshot = server.store.snapshot()
-    rnd.snapshot = snapshot
+        rnd = _FleetRound()
+        rnd.fused = fused
+        wait_index = max(max(e.ev.modify_index, e.ev.snapshot_index)
+                         for e in fused)
+        server.store.wait_for_index(wait_index, timeout=5.0)
+        snapshot = server.store.snapshot()
+        rnd.snapshot = snapshot
 
-    # one shared world for the whole batch — including the node-id map
-    # and dc counts every member's prepare pass reads (the per-eval
-    # rebuild of node_by_id over a 2k-node list was pure burn)
-    nodes = [n for n in snapshot.nodes() if n.ready()]
-    rnd.nodes = nodes
-    node_by_id = {n.id: n for n in nodes}
-    by_dc: Dict[str, int] = {}
-    for n in nodes:
-        by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
-    rnd.by_dc = by_dc
-    allocs_by_node: Dict[str, List[Allocation]] = {}
-    for n in nodes:
-        live = [a for a in snapshot.allocs_by_node(n.id)
-                if not a.terminal_status()]
-        if live:
-            allocs_by_node[n.id] = live
-    rnd.allocs_by_node = allocs_by_node
+        # one shared world for the whole batch — including the node-id map
+        # and dc counts every member's prepare pass reads (the per-eval
+        # rebuild of node_by_id over a 2k-node list was pure burn)
+        nodes = [n for n in snapshot.nodes() if n.ready()]
+        rnd.nodes = nodes
+        node_by_id = {n.id: n for n in nodes}
+        by_dc: Dict[str, int] = {}
+        for n in nodes:
+            by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
+        rnd.by_dc = by_dc
+        allocs_by_node: Dict[str, List[Allocation]] = {}
+        for n in nodes:
+            live = [a for a in snapshot.allocs_by_node(n.id)
+                    if not a.terminal_status()]
+            if live:
+                allocs_by_node[n.id] = live
+        rnd.allocs_by_node = allocs_by_node
 
-    all_asks: List = []
-    for e in fused:
-        try:
-            missing, err = e.sched._begin(e.ev, snapshot)
-        except Exception as exc:
-            e.err = f"scheduler error: {exc}"
-            continue
-        if err is not None:
-            e.err = err
-            continue
-        if missing:
-            # restrict to this job's datacenters via the ask's dc mask —
-            # the shared node list spans all DCs
-            prep = e.sched._prepare_placements(
-                snapshot, missing, nodes=nodes, by_dc=by_dc,
-                allocs_by_node=allocs_by_node, node_by_id=node_by_id)
-            if prep is not None:
-                _nodes, _by_dc, _abn, asks, ask_missing = prep
-                e.prep = (missing, ask_missing)
-                e.ask_base = len(all_asks)
-                all_asks.extend(asks)
-                rnd.solvable.append(e)
-    rnd.all_asks = all_asks
-    rnd.stages["reconcile"] = _time.perf_counter() - t0
+        all_asks: List = []
+        for e in fused:
+            try:
+                missing, err = e.sched._begin(e.ev, snapshot)
+            except Exception as exc:
+                e.err = f"scheduler error: {exc}"
+                continue
+            if err is not None:
+                e.err = err
+                continue
+            if missing:
+                # restrict to this job's datacenters via the ask's dc mask —
+                # the shared node list spans all DCs
+                prep = e.sched._prepare_placements(
+                    snapshot, missing, nodes=nodes, by_dc=by_dc,
+                    allocs_by_node=allocs_by_node, node_by_id=node_by_id)
+                if prep is not None:
+                    _nodes, _by_dc, _abn, asks, ask_missing = prep
+                    e.prep = (missing, ask_missing)
+                    e.ask_base = len(all_asks)
+                    all_asks.extend(asks)
+                    rnd.solvable.append(e)
+        rnd.all_asks = all_asks
+    rnd.stages["reconcile"] = reconcile.dur_s
     return rnd
 
 
@@ -314,7 +315,6 @@ def fleet_dispatch(server, worker, rnd: _FleetRound) -> None:
     # one fused device solve, one solve span PER member trace: each
     # eval's timeline stays self-contained, the shared counters
     # (and fused_batch size) tie the members back together
-    from ..utils.tracing import global_tracer as _tr
     for e in solvable:
         rnd.spans[e.ev.id] = _tr.stage(
             e.ev.id, "solve", job_id=e.ev.job_id, fused=True,
@@ -322,7 +322,7 @@ def fleet_dispatch(server, worker, rnd: _FleetRound) -> None:
     rnd.pending = worker.fleet_solver().solve_async(
         rnd.nodes, rnd.all_asks, rnd.allocs_by_node, rnd.by_dc,
         snapshot=snapshot, proposed_delta=([], probes),
-        preempt=preempt_ok)
+        preempt=preempt_ok, spans="fleet")
     rnd.t_dispatched = rnd.pending.t_dispatched
     rnd.stages["pack"] = rnd.pending.pack_wall_s
     rnd.stages["dispatch"] = rnd.pending.dispatch_wall_s
@@ -355,58 +355,61 @@ def fleet_finish(server, worker, rnd: _FleetRound,
 
     snapshot = rnd.snapshot
     if out is not None and rnd.solvable:
-        t0 = _time.perf_counter()
-        # single-pass fan-back: each placement belongs to exactly one
-        # member (ask ranges partition the fused ask list), so rebase
-        # ask_index in place and bucket by owner — the old O(E*P) scan
-        # with a copy per match dominated plan build at batch 128
-        owner: List[int] = []
-        for i, e in enumerate(rnd.solvable):
-            owner.extend([i] * len(e.prep[1]))
-        local: List[List] = [[] for _ in rnd.solvable]
-        for p in out.placements:
-            i = owner[p.ask_index]
-            p.ask_index -= rnd.solvable[i].ask_base
-            local[i].append(p)
-        stage_attrs = {f"stage_{k}_s": round(v, 6)
-                       for k, v in rnd.stages.items()}
-        for i, e in enumerate(rnd.solvable):
-            missing, ask_missing = e.prep
-            base, n_local = e.ask_base, len(e.prep[1])
-            view = _SolveView(
-                local[i], out.class_eligibility[base:base + n_local])
-            view.trace = dict(out.trace)
-            view.trace.update(stage_attrs)
-            e.sched._consume_solve(snapshot, view, rnd.nodes,
-                                   rnd.allocs_by_node, missing,
-                                   ask_missing,
-                                   span=rnd.spans.get(e.ev.id))
-        rnd.stages["plan_build"] = _time.perf_counter() - t0
+        with _tr.layer("fleet.plan_build") as plan_build:
+            # single-pass fan-back: each placement belongs to exactly
+            # one member (ask ranges partition the fused ask list), so
+            # rebase ask_index in place and bucket by owner — the old
+            # O(E*P) scan with a copy per match dominated plan build at
+            # batch 128
+            owner: List[int] = []
+            for i, e in enumerate(rnd.solvable):
+                owner.extend([i] * len(e.prep[1]))
+            local: List[List] = [[] for _ in rnd.solvable]
+            for p in out.placements:
+                i = owner[p.ask_index]
+                p.ask_index -= rnd.solvable[i].ask_base
+                local[i].append(p)
+            for i, e in enumerate(rnd.solvable):
+                missing, ask_missing = e.prep
+                base, n_local = e.ask_base, len(e.prep[1])
+                view = _SolveView(
+                    local[i],
+                    out.class_eligibility[base:base + n_local])
+                view.trace = dict(out.trace)
+                e.sched._consume_solve(snapshot, view, rnd.nodes,
+                                       rnd.allocs_by_node, missing,
+                                       ask_missing,
+                                       span=rnd.spans.get(e.ev.id))
+        rnd.stages["plan_build"] = plan_build.dur_s
 
     # finalize each eval; anything incomplete replays on the single path
-    t0 = _time.perf_counter()
-    acks: List[Tuple[str, str]] = []
-    for e in rnd.fused:
-        if e.err is not None:
-            e.sched._set_status(EVAL_STATUS_FAILED, str(e.err))
-            server.broker.nack(e.ev.id, e.token)
-            continue
-        try:
-            done, err = e.sched._finalize({"made": False})
-        except Exception as exc:
-            done, err = False, f"finalize error: {exc}"
-        if err is not None:
-            e.sched._set_status(EVAL_STATUS_FAILED, str(err))
-            server.broker.nack(e.ev.id, e.token)
-        elif done:
-            e.sched._set_status(EVAL_STATUS_COMPLETE, "")
-            acks.append((e.ev.id, e.token))
-        else:
-            # partial commit / refresh: the single-eval retry loop owns it
-            worker._process(e.ev, e.token)
-    if acks:
-        server.broker.ack_batch(acks)
-    rnd.stages["apply"] = _time.perf_counter() - t0
+    with _tr.layer("fleet.apply") as apply:
+        acks: List[Tuple[str, str]] = []
+        for e in rnd.fused:
+            if e.err is not None:
+                e.sched._set_status(EVAL_STATUS_FAILED, str(e.err))
+                server.broker.nack(e.ev.id, e.token)
+                continue
+            try:
+                done, err = e.sched._finalize({"made": False})
+            except Exception as exc:
+                done, err = False, f"finalize error: {exc}"
+            if err is not None:
+                e.sched._set_status(EVAL_STATUS_FAILED, str(err))
+                server.broker.nack(e.ev.id, e.token)
+            elif done:
+                e.sched._set_status(EVAL_STATUS_COMPLETE, "")
+                acks.append((e.ev.id, e.token))
+            else:
+                # partial commit / refresh: the single-eval retry loop
+                # owns it; its time is part of this stage, and
+                # `fleet.replay` says how much
+                _m.incr_counter("coordinator.replays")
+                with _tr.layer("fleet.replay", e.ev.id):
+                    worker._process(e.ev, e.token)
+        if acks:
+            server.broker.ack_batch(acks)
+    rnd.stages["apply"] = apply.dur_s
     record_stage_metrics(rnd.stages)
 
 
@@ -601,7 +604,6 @@ class SolveCoordinator:
         its neighbor.  The leader never returns with a round in flight,
         and a submitter's `done` fires only after its round's finish
         phase (no eval is released between dispatch and fetch)."""
-        from ..utils.metrics import global_metrics as _m
         # (submitters, round handle) of the dispatched-not-fetched round
         inflight: Optional[Tuple[List[_FusedSubmission], object]] = None
         prev_fetch_done = 0.0

@@ -9,6 +9,7 @@ the core of the TPU recast (SURVEY §7.1).
 from __future__ import annotations
 
 import copy
+import functools
 import time as _time
 from typing import Dict, List, Optional, Tuple
 
@@ -30,6 +31,7 @@ from ..structs import (ALLOC_CLIENT_PENDING, ALLOC_DESIRED_RUN,
                        Plan, RescheduleEvent, RescheduleTracker, TaskGroup,
                        resolve_node_target)
 from ..utils.ids import generate_uuid
+from ..utils.tracing import NULL_SPAN, global_tracer as _tr
 from . import feasible as hostfeas
 from .reconcile import (AllocDestructiveResult, AllocPlaceResult, Reconciler)
 from .util import (adjust_queued_allocations, generic_alloc_update_fn,
@@ -51,6 +53,17 @@ _VALID_TRIGGERS = {
     EVAL_TRIGGER_RETRY_FAILED_ALLOC, EVAL_TRIGGER_FAILED_FOLLOW_UP,
     EVAL_TRIGGER_PREEMPTION, EVAL_TRIGGER_SCALING,
 }
+
+
+def _layer(name: str):
+    """Mark a scheduler phase as one layer span of its eval's trace."""
+    def mark(fn):
+        @functools.wraps(fn)
+        def phase(self, *args, **kw):
+            with _tr.layer(name, self.eval.id):
+                return fn(self, *args, **kw)
+        return phase
+    return mark
 
 
 class _Missing:
@@ -131,8 +144,9 @@ class GenericScheduler:
 
     # ------------------------------------------------------------ internals
     def _process(self, progress) -> Tuple[bool, Optional[str]]:
-        snapshot = (self.state.snapshot()
-                    if hasattr(self.state, "snapshot") else self.state)
+        with _tr.layer("sched.snapshot", self.eval.id):
+            snapshot = (self.state.snapshot()
+                        if hasattr(self.state, "snapshot") else self.state)
         missing, err = self._begin(self.eval, snapshot)
         if err is not None:
             return False, err
@@ -147,23 +161,25 @@ class GenericScheduler:
         """Everything before the device solve: reconcile and assemble the
         plan skeleton. Returns the pending placements."""
         self.eval = ev
-        self.snapshot = snapshot
-        self.job = snapshot.job_by_id(ev.namespace, ev.job_id)
-        self.failed_tg_allocs = {}
-        self.queued_allocs = {}
-        self.followup_evals = []
-        self._sticky_probes = []
-        self._unfinished = 0
-        self.plan = ev.make_plan(self.job)
+        with _tr.layer("sched.reconcile", ev.id):
+            self.snapshot = snapshot
+            self.job = snapshot.job_by_id(ev.namespace, ev.job_id)
+            self.failed_tg_allocs = {}
+            self.queued_allocs = {}
+            self.followup_evals = []
+            self._sticky_probes = []
+            self._unfinished = 0
+            self.plan = ev.make_plan(self.job)
 
-        if not self.batch:
-            self.deployment = snapshot.latest_deployment_by_job(
-                ev.namespace, ev.job_id)
-            if self.deployment is not None and not self.deployment.active():
+            if not self.batch:
+                self.deployment = snapshot.latest_deployment_by_job(
+                    ev.namespace, ev.job_id)
+                if self.deployment is not None \
+                        and not self.deployment.active():
+                    self.deployment = None
+            else:
                 self.deployment = None
-        else:
-            self.deployment = None
-        return self._compute_job_allocs(snapshot)
+            return self._compute_job_allocs(snapshot)
 
     def _finalize(self, progress) -> Tuple[bool, Optional[str]]:
         """Everything after the solve: blocked/follow-up evals and plan
@@ -257,7 +273,6 @@ class GenericScheduler:
         for d in results.destructive_update:
             self.queued_allocs[d.place_task_group.name] = \
                 self.queued_allocs.get(d.place_task_group.name, 0) + 1
-        from ..utils.tracing import global_tracer as _tr
         _tr.event(ev.id, "schedule.reconcile",
                   n_place=len(results.place),
                   n_destructive=len(results.destructive_update),
@@ -291,7 +306,6 @@ class GenericScheduler:
         stops = [a for lst in self.plan.node_update.values()
                  for a in lst]
         from .preemption import preemption_enabled
-        from ..utils.tracing import global_tracer as _tr
         preempt_ok = preemption_enabled(
             snapshot.scheduler_config(),
             "batch" if self.batch else "service")
@@ -305,6 +319,7 @@ class GenericScheduler:
                             ask_missing, span=span)
         return None
 
+    @_layer("sched.prepare")
     def _prepare_placements(self, snapshot, missing: List[_Missing],
                             nodes=None, by_dc=None, allocs_by_node=None,
                             node_by_id=None):
@@ -457,6 +472,7 @@ class GenericScheduler:
                     blocked.add(n.id)
         return None, frozenset(blocked)
 
+    @_layer("sched.plan_build")
     def _consume_solve(self, snapshot, out, nodes, allocs_by_node,
                        missing: List[_Missing],
                        ask_missing: List[List[_Missing]],
@@ -470,7 +486,6 @@ class GenericScheduler:
         scorer training substrate)."""
         # map solver placements (contiguous per ask) back to missing
         from .preemption import preemption_enabled
-        from ..utils.tracing import NULL_SPAN
         preempt_ok = preemption_enabled(
             snapshot.scheduler_config(), "batch" if self.batch else "service")
         # per-ask consume cursors instead of pop(0) list churn

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..structs import EVAL_STATUS_PENDING, Evaluation
 from ..utils.ids import generate_uuid
+from ..utils.metrics import global_metrics as _m
 from ..utils.tracing import global_tracer as _tr
 
 FAILED_QUEUE = "_failed"
@@ -224,6 +225,7 @@ class _Shard:
                     self._deliveries.get(ev.id, 0) + 1
                 self._dequeues += 1
                 self._arm_nack_locked(u)
+                _m.add_sample("broker.wait", age)
                 _tr.event(ev.id, "broker.dequeue",
                           queue_age_s=round(age, 6),
                           delivery=self._deliveries[ev.id],
@@ -350,7 +352,6 @@ class _Shard:
         del self._unack[eval_id]
         self._requeue.pop(eval_id, None)
         self._nacks += 1
-        from ..utils.metrics import global_metrics as _m
         _m.incr_counter("broker.nack")
         ev = u.eval
         # keep the per-job serialization slot held by the nacked eval
@@ -563,7 +564,6 @@ class EvalBroker:
         per-dequeue loops can't turn the gauge walk into lock traffic —
         the leader's 1s export beat passes the default 0 and always
         publishes."""
-        from ..utils.metrics import global_metrics as _m
         if min_interval_s > 0.0:
             now = _time.monotonic()
             with self._export_lock:
@@ -716,7 +716,6 @@ class EvalBroker:
                                            max_batch - len(out)))
         # dequeue-batch size histogram (p50/p99 via the metrics
         # reservoir) — the observability face of the BatchController
-        from ..utils.metrics import global_metrics as _m
         _m.add_sample("broker.dequeue_batch_size", float(len(out)))
         return out
 

@@ -260,6 +260,7 @@ class PlanApplier:
                    out: Optional[_Outstanding]
                    ) -> Optional[_Outstanding]:
         from ..utils.metrics import global_metrics as _m
+        from ..utils.tracing import global_tracer as _tr
         _m.set_gauge("plan.queue_depth", self.queue.depth()
                      if hasattr(self.queue, "depth") else 0)
         # group commit: opportunistically drain up to K-1 more queued
@@ -272,7 +273,10 @@ class PlanApplier:
                 if extra is None:
                     break
                 group.append(extra)
-        snapshot = self.store.snapshot()
+        for p in group:
+            _tr.waited("plan.queue_wait", p.t_enqueued, p.plan.eval_id)
+        with _tr.layer("plan.snapshot", pending.plan.eval_id):
+            snapshot = self.store.snapshot()
         if out is not None:
             # evaluate against base + the in-flight plans' known results
             # (the overlay is idempotent if the apply already landed)
@@ -281,7 +285,8 @@ class PlanApplier:
         items = []
         for p in group:
             try:
-                with _m.timed("plan.evaluate"):
+                with _tr.layer("plan.evaluate", p.plan.eval_id,
+                               key="plan.evaluate"):
                     result = evaluate_plan(snapshot, p.plan)
             except Exception as e:
                 # a poisoned group member must not strand the others
@@ -299,8 +304,10 @@ class PlanApplier:
             return out
         if len(items) > 1 and self.apply_batch_async_fn is not None:
             try:
-                index, finish = self.apply_batch_async_fn(
-                    [(pl, res) for _p, pl, res in items])
+                # one raft entry for the group: a span of no one eval
+                with _tr.layer("plan.raft_apply", group=len(items)):
+                    index, finish = self.apply_batch_async_fn(
+                        [(pl, res) for _p, pl, res in items])
             except Exception as e:
                 for p, _pl, _res in items:
                     p.future.respond(None, f"plan apply error: {e}")
@@ -316,7 +323,8 @@ class PlanApplier:
             return new_out
         if self.apply_async_fn is not None and len(items) == 1:
             p, plan, result = items[0]
-            index, finish = self.apply_async_fn(plan, result)
+            with _tr.layer("plan.raft_apply", plan.eval_id):
+                index, finish = self.apply_async_fn(plan, result)
             _m.incr_counter("plan.raft_applies")
             new_out = _Outstanding(items, finish)
             if out is not None:
@@ -328,7 +336,7 @@ class PlanApplier:
         if out is not None:
             self._finalize(out)
         for p, plan, result in items:
-            with _m.timed("plan.apply"):
+            with _tr.layer("plan.raft_apply", plan.eval_id):
                 index = self.apply_fn(plan, result)
             result.alloc_index = index
             self._account_and_respond(p, plan, result)
@@ -340,9 +348,12 @@ class PlanApplier:
         instead (PlanFuture.respond is first-wins, so a partial
         _account_and_respond that already delivered the result cannot
         be overwritten by the trailing error)."""
-        from ..utils.metrics import global_metrics as _m
+        from ..utils.tracing import global_tracer as _tr
         try:
-            with _m.timed("plan.apply"):
+            # the wait for the dispatched entry: over before it starts
+            # on a single-voter raft, which applies inside the dispatch
+            # (`plan.raft_apply` is the layer's work)
+            with _tr.layer("plan.commit_wait", key="plan.apply"):
                 index = out.finish(10.0)
         except Exception as e:
             for pending, _plan, _result in out.items:

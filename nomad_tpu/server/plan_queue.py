@@ -8,6 +8,7 @@ from __future__ import annotations
 import heapq
 import itertools
 import threading
+import time
 from typing import List, Optional, Tuple
 
 from ..structs import Plan, PlanResult
@@ -41,6 +42,8 @@ class PendingPlan:
     def __init__(self, plan: Plan):
         self.plan = plan
         self.future = PlanFuture()
+        #: enqueue stamp: the applier records `plan.queue_wait` from it
+        self.t_enqueued = time.monotonic()
 
 
 class PlanQueue:
@@ -75,7 +78,6 @@ class PlanQueue:
             return pending
 
     def dequeue(self, timeout: float) -> Optional[PendingPlan]:
-        import time
         deadline = time.monotonic() + timeout
         with self._lock:
             while True:

@@ -157,6 +157,8 @@ class Server:
         become leader synchronously (existing callers see the same
         behavior as before); multi-node members run the election and
         leader services follow leadership transitions."""
+        from ..utils.tracing import global_tracer
+        global_tracer.watch_gc()
         if self._multi:
             self.raft.start()
         else:
@@ -310,16 +312,20 @@ class Server:
 
     def _export_metrics_loop(self) -> None:
         beats = 0
+        from ..utils.tracing import global_tracer as _tr
         while not self._stop_reapers.wait(self.METRICS_EXPORT_INTERVAL_S):
-            self.broker.export_metrics()
-            beats += 1
-            try:
-                self._telemetry_tick(beats)
-            except Exception:
-                # telemetry must never kill the export beat — the
-                # broker gauges above are load-bearing for operators
-                from ..utils.metrics import global_metrics as _m
-                _m.incr_counter("telemetry.tick_error")
+            # one span a beat: what this thread takes from the workers
+            # (it walks the broker, and every fifth beat the node planes)
+            with _tr.layer("telemetry.tick"):
+                self.broker.export_metrics()
+                beats += 1
+                try:
+                    self._telemetry_tick(beats)
+                except Exception:
+                    # telemetry must never kill the export beat — the
+                    # broker gauges above are load-bearing for operators
+                    from ..utils.metrics import global_metrics as _m
+                    _m.incr_counter("telemetry.tick_error")
 
     def _telemetry_tick(self, beats: int) -> None:
         """Feed the multi-resolution series store on the export beat
@@ -535,6 +541,15 @@ class Server:
 
     def register_job(self, job: Job, enforce_index: bool = False,
                      check_index: int = 0) -> Optional[Evaluation]:
+        from ..utils.tracing import global_tracer as _tr
+        # the Ingress layer's span: validate, the raft write of the job
+        # and of its eval, the broker enqueue (no eval id exists yet, so
+        # the recorder gets no row; the eval's `create` event follows)
+        with _tr.layer("job.register"):
+            return self._register_job(job, enforce_index, check_index)
+
+    def _register_job(self, job: Job, enforce_index: bool,
+                      check_index: int) -> Optional[Evaluation]:
         job.canonicalize()
         # validate server-side so every path (HTTP, RPC, direct) is
         # covered (reference: job_endpoint.go Job.Register → Validate

@@ -13,6 +13,8 @@ from typing import List, Optional, Tuple
 
 from ..scheduler.base import new_scheduler
 from ..structs import Evaluation, Plan, PlanResult
+from ..utils.metrics import global_metrics as _m
+from ..utils.tracing import global_tracer as _tr
 
 DEQUEUE_TIMEOUT_S = 0.2
 
@@ -56,8 +58,6 @@ class Worker(threading.Thread):
 
     def run(self) -> None:
         import time as _t
-
-        from ..utils.metrics import global_metrics as _m
         while not self._shutdown.is_set():
             broker = self.server.broker
             serving = getattr(self.server, "serving", None)
@@ -71,9 +71,10 @@ class Worker(threading.Thread):
                 self._shutdown.wait(0.05)
                 continue
             target = self._target_batch(serving, broker)
-            batch = broker.dequeue_batch(
-                self.sched_types, target, DEQUEUE_TIMEOUT_S,
-                home=self.index)
+            with _tr.layer("worker.dequeue_wait"):
+                batch = broker.dequeue_batch(
+                    self.sched_types, target, DEQUEUE_TIMEOUT_S,
+                    home=self.index)
             if not batch:
                 # idle tick: readmit shed work once the queue drains
                 self._readmit_tick(serving)
@@ -150,7 +151,6 @@ class Worker(threading.Thread):
         """Run one dequeued batch; returns True when the fused
         (coordinator / process_fleet) path handled the bulk lane, i.e.
         the sizing model was already fed device time by fleet_finish."""
-        from ..utils.tracing import global_tracer as _tr
         if len(batch) == 1:
             _tr.event(batch[0][0].id, "worker.batch", batch_size=1,
                       lane="single")
@@ -205,9 +205,6 @@ class Worker(threading.Thread):
             self.server.broker.enqueue(ev)
 
     def _process(self, ev: Evaluation, token: str) -> None:
-        import time as _t
-
-        from ..utils.metrics import global_metrics as _m
         server = self.server
         _m.incr_counter("worker.dequeue_eval")
         # the raft catch-up + solve + plan wait can exceed the nack
@@ -215,12 +212,10 @@ class Worker(threading.Thread):
         server.broker.pause_nack_timeout(ev.id, token)
         # wait for local state to reach the eval's creation point
         # (reference metric: nomad.worker.wait_for_index)
-        from ..utils.tracing import global_tracer as _tr
         wait_index = max(ev.modify_index, ev.snapshot_index)
-        t0 = _t.monotonic()
-        with _tr.stage(ev.id, "worker.wait_index", index=wait_index):
+        with _tr.layer("worker.wait_index", ev.id,
+                       key="worker.wait_for_index", index=wait_index):
             server.store.wait_for_index(wait_index, timeout=5.0)
-        _m.measure_since("worker.wait_for_index", t0)
         if self.mesh_supervisor is not None and ev.node_id:
             from ..structs import EVAL_TRIGGER_NODE_UPDATE
             if ev.triggered_by == EVAL_TRIGGER_NODE_UPDATE:
@@ -232,34 +227,35 @@ class Worker(threading.Thread):
                 if node is not None:
                     self.mesh_supervisor.note_node_event(ev.node_id,
                                                          node.status)
-        _invoke_t0 = _t.monotonic()
-        try:
-            from ..structs import JOB_TYPE_CORE
-            if ev.type == JOB_TYPE_CORE:
-                # administrative GC runs against a snapshot and reaps
-                # through the server (worker.go:258, core_sched.go:46)
-                from ..scheduler.core import CoreScheduler
-                CoreScheduler(server, server.store.snapshot()).process(ev)
-                err = None
-            else:
-                sched = new_scheduler(ev.type, server.store, self,
-                                      solver=self.fleet_solver())
-                err = sched.process(ev)
-        except Exception as e:
-            # record the failure on the eval so a parked (delivery-limited)
-            # eval isn't restored as pending after a leader restart
-            import copy
-            from ..structs import EVAL_STATUS_FAILED
-            failed = copy.copy(ev)
-            failed.status = EVAL_STATUS_FAILED
-            failed.status_description = f"scheduler error: {e}"
-            server.upsert_evals([failed])
-            server.broker.nack(ev.id, token)
-            return
-        finally:
-            # reference metric: nomad.worker.invoke_scheduler_<type>
-            _m.measure_since(f"worker.invoke_scheduler_{ev.type}",
-                             _invoke_t0)
+        # reference metric: nomad.worker.invoke_scheduler_<type>
+        with _tr.layer("worker.invoke_scheduler", ev.id,
+                       key=f"worker.invoke_scheduler_{ev.type}"):
+            try:
+                from ..structs import JOB_TYPE_CORE
+                if ev.type == JOB_TYPE_CORE:
+                    # administrative GC runs against a snapshot and
+                    # reaps through the server (worker.go:258,
+                    # core_sched.go:46)
+                    from ..scheduler.core import CoreScheduler
+                    CoreScheduler(server,
+                                  server.store.snapshot()).process(ev)
+                    err = None
+                else:
+                    sched = new_scheduler(ev.type, server.store, self,
+                                          solver=self.fleet_solver())
+                    err = sched.process(ev)
+            except Exception as e:
+                # record the failure on the eval so a parked (delivery-
+                # limited) eval isn't restored as pending after a
+                # leader restart
+                import copy
+                from ..structs import EVAL_STATUS_FAILED
+                failed = copy.copy(ev)
+                failed.status = EVAL_STATUS_FAILED
+                failed.status_description = f"scheduler error: {e}"
+                server.upsert_evals([failed])
+                server.broker.nack(ev.id, token)
+                return
         if err is not None:
             server.broker.nack(ev.id, token)
         else:
@@ -268,47 +264,50 @@ class Worker(threading.Thread):
     # ---------------------------------------------------- Planner interface
     def submit_plan(self, plan: Plan
                     ) -> Tuple[Optional[PlanResult], Optional[object]]:
-        import time as _t
-
-        from ..utils.metrics import global_metrics as _m
-        from ..utils.tracing import global_tracer as _tr
-        t0 = _t.monotonic()
-        sp = _tr.stage(plan.eval_id, "plan.submit",
+        # reference metric: nomad.worker.submit_plan (p50/p99 plan-submit
+        # latency — the BASELINE.md headline latency metric)
+        with _tr.layer("plan.submit", plan.eval_id,
+                       key="worker.submit_plan",
                        n_alloc=sum(len(v) for v in
                                    plan.node_allocation.values()),
                        n_stop=sum(len(v) for v in
-                                  plan.node_update.values()))
-        pending = self.server.plan_queue.enqueue(plan)
-        if pending is None:
-            sp.end(outcome="queue_disabled")
-            return None, None
-        result, err = pending.future.wait(30.0)
-        # reference metric: nomad.worker.submit_plan (p50/p99 plan-submit
-        # latency — the BASELINE.md headline latency metric)
-        _m.measure_since("worker.submit_plan", t0)
-        if err is not None or result is None:
-            sp.end(outcome=f"error: {err}" if err else "no result")
-            return None, None
-        sp.end(outcome="applied", alloc_index=result.alloc_index,
-               refresh_index=result.refresh_index)
-        # feed the applied changeset into the solver's resident world:
-        # the next eval's solve starts from already-advanced tensors
-        # (the change-log sync then dedups these same writes)
-        if self._solver is not None:
-            self._solver.note_plan_result(plan, result)
-        if result.refresh_index:
-            # partial commit: catch up past the conflicting writes and hand
-            # the scheduler a fresh snapshot to retry against
-            self.server.store.wait_for_index(result.refresh_index,
-                                             timeout=5.0)
-            return result, self.server.store.snapshot()
-        return result, None
+                                  plan.node_update.values())) as sp:
+            pending = self.server.plan_queue.enqueue(plan)
+            if pending is None:
+                sp.set(outcome="queue_disabled")
+                return None, None
+            with _tr.layer("plan.result_wait"):
+                result, err = pending.future.wait(30.0)
+            if err is not None or result is None:
+                sp.set(outcome=f"error: {err}" if err else "no result")
+                return None, None
+            sp.set(outcome="applied", alloc_index=result.alloc_index,
+                   refresh_index=result.refresh_index)
+            with _tr.layer("plan.refresh"):
+                # feed the applied changeset into the solver's resident
+                # world: the next eval's solve starts from already-
+                # advanced tensors (the change-log sync then dedups
+                # these same writes)
+                if self._solver is not None:
+                    self._solver.note_plan_result(plan, result)
+                if result.refresh_index:
+                    # partial commit: catch up past the conflicting
+                    # writes and hand the scheduler a fresh snapshot to
+                    # retry against
+                    self.server.store.wait_for_index(
+                        result.refresh_index, timeout=5.0)
+                    return result, self.server.store.snapshot()
+            return result, None
 
     def update_eval(self, ev: Evaluation) -> None:
-        self.server.upsert_evals([ev])
+        with _tr.layer("eval.update", ev.id):
+            self.server.upsert_evals([ev])
 
     def create_eval(self, ev: Evaluation) -> None:
-        self.server.upsert_evals([ev])
+        # a blocked or follow-up eval: the span joins the trace of the
+        # eval being processed (the enclosing layer span's)
+        with _tr.layer("eval.update"):
+            self.server.upsert_evals([ev])
 
     def reblock_eval(self, ev: Evaluation) -> None:
         self.server.blocked_evals.block(ev)

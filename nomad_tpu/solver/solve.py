@@ -18,6 +18,7 @@ from ..structs import (AllocatedDeviceResource, AllocatedResources,
                        AllocatedSharedResources, AllocatedTaskResources,
                        AllocMetric, DeviceAccounter, NetworkIndex, Node)
 from ..utils.metrics import global_metrics as _m
+from ..utils.tracing import global_tracer as _tr
 from .kernel import TOP_K, solve_kernel
 from .tensorize import (NUM_R, ClusterDelta, PackedBatch, PlacementAsk,
                         Tensorizer, alloc_device_usage,
@@ -346,24 +347,23 @@ class PendingSolve:
     returns the SolveOutput; idempotent, single-owner (the pipelined
     coordinator's drain leader).
 
-    Timing stamps (perf_counter domain) let the caller account device
-    time as interval unions under pipelining:
-
-      t_dispatched     stamp right after the kernel launch returned
-      dispatch_wall_s  pack + launch wall (host-side dispatch cost)
-      fetch_wall_s     wall blocked inside wait() on the device result
-      finish_wall_s    host fixup walk wall
+    `t_dispatched` (perf_counter domain) is the stamp right after the
+    kernel launch returned, and `pack_wall_s` / `dispatch_wall_s` /
+    `fetch_wall_s` the walls of the pack, launch and fetch layer spans:
+    the fused round accounts its stages, and device time as interval
+    unions under pipelining, from them.  `spans` prefixes the four
+    layer spans (`solve.pack` ...; the fused round passes `fleet`).
     """
 
     __slots__ = ("_solver", "_pb", "_sol_nodes", "_asks",
                  "_allocs_by_node", "_by_dc", "_used_resident", "_res",
-                 "_t0", "_out", "t_dispatched", "pack_wall_s",
-                 "dispatch_wall_s", "fetch_wall_s", "finish_wall_s")
+                 "_out", "_spans", "t_dispatched", "pack_wall_s",
+                 "dispatch_wall_s", "fetch_wall_s")
 
     def __init__(self, solver, pb=None, sol_nodes=None, asks=None,
                  allocs_by_node=None, by_dc=None,
-                 used_resident: bool = False, res=None, t0: float = 0.0,
-                 out: Optional[SolveOutput] = None):
+                 used_resident: bool = False, res=None,
+                 out: Optional[SolveOutput] = None, spans: str = "solve"):
         self._solver = solver
         self._pb = pb
         self._sol_nodes = sol_nodes
@@ -372,13 +372,12 @@ class PendingSolve:
         self._by_dc = by_dc
         self._used_resident = used_resident
         self._res = res
-        self._t0 = t0
         self._out = out
-        self.t_dispatched = t0
+        self._spans = spans
+        self.t_dispatched = 0.0
         self.pack_wall_s = 0.0
         self.dispatch_wall_s = 0.0
         self.fetch_wall_s = 0.0
-        self.finish_wall_s = 0.0
 
     def wait(self) -> SolveOutput:
         """Block until the device result lands, then run the host fixup.
@@ -386,18 +385,14 @@ class PendingSolve:
         NOT safe to call concurrently from two threads."""
         if self._out is not None:
             return self._out
-        import time as _t
-        t0 = _t.perf_counter()
-        np.asarray(self._res.choice)   # blocks until the kernel is done
-        t1 = _t.perf_counter()
-        self.fetch_wall_s = t1 - t0
-        out = self._solver._finish_solve(
-            self._pb, self._sol_nodes, self._asks, self._res,
-            self._used_resident, self._allocs_by_node, self._by_dc,
-            self._t0)
-        self.finish_wall_s = _t.perf_counter() - t1
-        out.trace["dispatch_wall_s"] = round(self.dispatch_wall_s, 6)
-        out.trace["fetch_wall_s"] = round(self.fetch_wall_s, 6)
+        with _tr.layer(self._spans + ".fetch") as fetch:
+            np.asarray(self._res.choice)  # blocks until the kernel is done
+        self.fetch_wall_s = fetch.dur_s
+        with _tr.layer(self._spans + ".fixup"):
+            out = self._solver._finish_solve(
+                self._pb, self._sol_nodes, self._asks, self._res,
+                self._used_resident, self._allocs_by_node, self._by_dc,
+                self._spans)
         self._out = out
         # drop the packed batch + device refs so a long-lived pending
         # handle doesn't pin buffers
@@ -595,7 +590,8 @@ class Solver:
                     by_dc: Optional[Dict[str, int]] = None, *,
                     snapshot=None, proposed_delta=None,
                     preempt: bool = False,
-                    _overlay_only: bool = False) -> "PendingSolve":
+                    _overlay_only: bool = False,
+                    spans: str = "solve") -> "PendingSolve":
         """Dispatch phase of `solve`: pack and LAUNCH the kernel without
         fetching the result.  Returns a PendingSolve whose `wait()`
         blocks on the device fetch, runs the host fixup walk and yields
@@ -609,55 +605,59 @@ class Solver:
         the watchdog is armed the solve also degrades to eager, because
         the watchdog deadline must cover dispatch AND fetch as one
         window — a device wedge surfacing only at the fetch would
-        escape a dispatch-only deadline."""
+        escape a dispatch-only deadline.
+
+        `spans` names the layer spans of pack, dispatch, fetch and
+        fixup: `solve.*` on the single-eval path, `fleet.*` from the
+        fused round, so that neither's samples count the other's."""
         import time as _t
-        _solve_t0 = _t.perf_counter()
         if not asks:
-            return PendingSolve(self, out=SolveOutput(placements=[]),
-                                t0=_solve_t0)
-        pb = None
-        sol_nodes = nodes
-        if snapshot is not None and self.resident_active(snapshot):
-            packed = self._resident_pack(snapshot, asks, proposed_delta,
-                                         overlay_only=_overlay_only)
-            if packed is not None:
-                pb, sol_nodes = packed
-        used_resident = pb is not None
-        if pb is None:
-            with self._world_lock:
-                # the tensorizer's interners are shared with concurrent
-                # plan-view solves — serialize every pack through it
-                pb = self._tensorizer.pack(nodes, asks, allocs_by_node)
+            return PendingSolve(self, out=SolveOutput(placements=[]))
+        with _tr.layer(spans + ".pack") as pack:
+            pb = None
+            sol_nodes = nodes
+            if snapshot is not None and self.resident_active(snapshot):
+                packed = self._resident_pack(
+                    snapshot, asks, proposed_delta,
+                    overlay_only=_overlay_only)
+                if packed is not None:
+                    pb, sol_nodes = packed
+            used_resident = pb is not None
+            if pb is None:
+                with self._world_lock:
+                    # the tensorizer's interners are shared with
+                    # concurrent plan-view solves — serialize every pack
+                    # through it
+                    pb = self._tensorizer.pack(nodes, asks, allocs_by_node)
         from .watchdog import global_watchdog
-        _t_pack_done = _t.perf_counter()
         if self._degraded:
-                _m.incr_counter("solver.degraded")
-        res = _run_kernel(pb, host_mode=self._host,
-                          max_waves=BROWNOUT_MAX_WAVES
-                          if self._degraded else 0,
-                          preempt=preempt,
-                          materialize=global_watchdog.enabled)
+            _m.incr_counter("solver.degraded")
+        with _tr.layer(spans + ".dispatch") as dispatch:
+            res = _run_kernel(pb, host_mode=self._host,
+                              max_waves=BROWNOUT_MAX_WAVES
+                              if self._degraded else 0,
+                              preempt=preempt,
+                              materialize=global_watchdog.enabled)
         pending = PendingSolve(self, pb=pb, sol_nodes=sol_nodes,
                                asks=list(asks),
                                allocs_by_node=allocs_by_node,
                                by_dc=by_dc,
                                used_resident=used_resident, res=res,
-                               t0=_solve_t0)
+                               spans=spans)
         pending.t_dispatched = _t.perf_counter()
-        pending.pack_wall_s = _t_pack_done - _solve_t0
-        pending.dispatch_wall_s = pending.t_dispatched - _t_pack_done
+        pending.pack_wall_s = pack.dur_s
+        pending.dispatch_wall_s = dispatch.dur_s
         return pending
 
     def _finish_solve(self, pb: PackedBatch, sol_nodes, asks, res,
-                      used_resident: bool, allocs_by_node, by_dc,
-                      _solve_t0: float) -> SolveOutput:
+                      used_resident: bool, allocs_by_node,
+                      by_dc, spans: str = "solve") -> SolveOutput:
         """Fetch-side half of `solve`: result materialization happened
         in PendingSolve.wait(); this walks the host fixup and builds
-        the SolveOutput."""
-        import time as _t
+        the SolveOutput.  Runs inside the caller's `<spans>.fixup` layer
+        span; its two bulk passes are child spans, the per-placement
+        walk is what is left."""
         trace_attrs = solve_trace_attrs(pb, res)
-        trace_attrs["kernel_wall_s"] = round(
-            _t.perf_counter() - _solve_t0, 6)
         trace_attrs["resident"] = used_resident
         # where solves answer from, as counters: an operator (and
         # chip_smoke.py) can see a solve leave the device — prefer_host,
@@ -673,17 +673,18 @@ class Solver:
             if world is not None:
                 trace_attrs["world"] = dict(world.counters)
 
-        choice = np.asarray(res.choice)
-        choice_ok = np.asarray(res.choice_ok)
-        score = np.asarray(res.score)
-        n_feasible = np.asarray(res.n_feasible)
-        n_exhausted = np.asarray(res.n_exhausted)
-        dim_exhausted = np.asarray(res.dim_exhausted)
-        feas = np.asarray(res.feas)
-        cons_filtered = np.asarray(res.cons_filtered)
-        unfinished = np.asarray(res.unfinished)
-        evict = (np.asarray(res.evict) if res.evict is not None
-                 else None)
+        with _tr.layer(spans + ".d2h"):
+            choice = np.asarray(res.choice)
+            choice_ok = np.asarray(res.choice_ok)
+            score = np.asarray(res.score)
+            n_feasible = np.asarray(res.n_feasible)
+            n_exhausted = np.asarray(res.n_exhausted)
+            dim_exhausted = np.asarray(res.dim_exhausted)
+            feas = np.asarray(res.feas)
+            cons_filtered = np.asarray(res.cons_filtered)
+            unfinished = np.asarray(res.unfinished)
+            evict = (np.asarray(res.evict) if res.evict is not None
+                     else None)
 
         # host fixup state: per-node port/device accounting incl. in-batch.
         # host_used is the AUTHORITATIVE usage: when a placement falls through
@@ -804,16 +805,17 @@ class Solver:
 
         # class eligibility for blocked-eval optimization
         class_elig: List[Dict[str, bool]] = []
-        node_class = pb.node_class[:pb.n_real]
-        inv_class = {v: k for k, v in pb.class_ids.items()}
-        for g in range(pb.n_asks):
-            fg = feas[g, :pb.n_real]
-            elig: Dict[str, bool] = {}
-            for cid, cname in inv_class.items():
-                members = node_class == cid
-                if members.any():
-                    elig[cname] = bool(fg[members].any())
-            class_elig.append(elig)
+        with _tr.layer(spans + ".class_elig"):
+            node_class = pb.node_class[:pb.n_real]
+            inv_class = {v: k for k, v in pb.class_ids.items()}
+            for g in range(pb.n_asks):
+                fg = feas[g, :pb.n_real]
+                elig: Dict[str, bool] = {}
+                for cid, cname in inv_class.items():
+                    members = node_class == cid
+                    if members.any():
+                        elig[cname] = bool(fg[members].any())
+                class_elig.append(elig)
 
         return SolveOutput(placements=placements,
                            class_eligibility=class_elig,
@@ -1116,7 +1118,13 @@ def _run_kernel(pb: PackedBatch, host_mode: str = "auto",
         # the chunked scan-of-vmap stream — a one-shot solve under a
         # lane axis would trade its carried-window cond for a
         # collective for no reason (ISSUE 20)
-        res = solve_kernel(*_kernel_args(pb), has_spread=has_spread,
+        args = _kernel_args(pb)
+        # what this solve hands the device from the host: a numpy
+        # argument crosses on every call, one already on the device is
+        # a jax array and is not counted
+        _m.incr_counter("solver.h2d_bytes", float(sum(
+            a.nbytes for a in args if isinstance(a, _np.ndarray))))
+        res = solve_kernel(*args, has_spread=has_spread,
                            pallas_mode=pallas, max_waves=max_waves,
                            lane_axis=None, **ev_kw)
         # materialize under the watchdog deadline: an async dispatch

@@ -26,6 +26,17 @@ Design constraints (ISSUE 10):
     call returns immediately when off (no allocation, no lock); cheap
     when on — one dict append per stage under a leaf lock.
 
+Layer spans (ISSUE 25): `FlightRecorder.layer(name, trace_id)` is the
+ONE call site of a layer boundary.  It feeds three sinks at once: the
+recorder row (parented on the enclosing layer span of the same thread),
+a `jax.profiler.TraceAnnotation("nomad.<name>")` — so the span is an
+event of the profiler's own trace, on the clock of the device's ops —
+and the metrics registry's sample `span.<name>`, which is written
+whatever the recorder's sampling.  `waited()` is the same for a wait
+whose start was stamped on another thread, and `watch_gc()` marks the
+collector's pauses (`gc.pause`), which otherwise sit unseen inside
+whatever span was open.
+
 Knobs (env):
   NOMAD_TPU_TRACE        "0" disables recording (default on)
   NOMAD_TPU_TRACE_DEPTH  ring depth in traces (default 512)
@@ -40,8 +51,10 @@ Knobs (env):
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time as _time
 import zlib
@@ -49,6 +62,7 @@ from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
 from .ids import generate_uuid
+from .metrics import global_metrics
 
 DEFAULT_TRACE_DEPTH = 512
 DEFAULT_MESH_EVENTS = 4096
@@ -130,6 +144,68 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class LayerSpan:
+    """One layer boundary, opened by `FlightRecorder.layer` (see there).
+    After the `with` block `dur_s` holds the wall the block took."""
+
+    __slots__ = ("_rec", "name", "trace_id", "_key", "_attrs", "_span",
+                 "_ann", "_t0", "dur_s")
+
+    def __init__(self, rec: "FlightRecorder", name: str, trace_id: str,
+                 key: Optional[str], attrs: Dict):
+        self._rec = rec
+        self.name = name
+        self.trace_id = trace_id
+        self._key = key or "span." + name
+        self._attrs = attrs
+        self._span = NULL_SPAN
+        self._ann = None
+        self.dur_s = 0.0
+
+    @property
+    def span_id(self) -> str:
+        return self._span.span_id
+
+    def set(self, **attrs) -> "LayerSpan":
+        self._span.set(**attrs)
+        return self
+
+    def __enter__(self) -> "LayerSpan":
+        rec = self._rec
+        stack = rec._layer_stack()
+        outer = stack[-1] if stack else None
+        if not self.trace_id and outer is not None:
+            self.trace_id = outer.trace_id
+        if outer is not None and outer.span_id \
+                and outer.trace_id == self.trace_id:
+            self._span = rec.span(self.trace_id, self.name,
+                                  parent=outer.span_id, **self._attrs)
+        else:
+            self._span = rec.stage(self.trace_id, self.name,
+                                   **self._attrs)
+        stack.append(self)
+        # only where jax is already loaded: client and CLI processes
+        # must not import it for the sake of a span
+        prof = sys.modules.get("jax.profiler")
+        if prof is not None:
+            self._ann = prof.TraceAnnotation("nomad." + self.name,
+                                             eval=self.trace_id)
+            self._ann.__enter__()
+        self._t0 = _time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.dur_s = _time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._rec._layer_stack().pop()
+        self._span.__exit__(exc_type, exc, tb)
+        global_metrics.add_sample(self._key, self.dur_s)
+        if self._rec._gc_done:
+            self._rec._drain_gc()
+
+
+
 class FlightRecorder:
     """Bounded in-memory trace store + optional JSONL sink.
 
@@ -193,6 +269,13 @@ class FlightRecorder:
         self._tail_lock = threading.Lock()
         self._spill_event = threading.Event()
         self._drainer: Optional[threading.Thread] = None
+        # per-thread stack of the open layer spans (`layer`)
+        self._layers = threading.local()
+        # collector pauses (`watch_gc`): the running one's profiler
+        # event and start, and the finished ones not yet sampled
+        self._gc_watched = False
+        self._gc_open = None
+        self._gc_done: deque = deque(maxlen=DEFAULT_TRACE_SPILL)
 
     # ------------------------------------------------------------- record
     def sampled(self, trace_id: str) -> bool:
@@ -236,6 +319,84 @@ class FlightRecorder:
               if parent is not None else self.stage(trace_id, name,
                                                     **attrs))
         sp.end()
+
+    def _layer_stack(self) -> List["LayerSpan"]:
+        try:
+            return self._layers.stack
+        except AttributeError:
+            stack = self._layers.stack = []
+            return stack
+
+    def layer(self, name: str, trace_id: str = "",
+              key: Optional[str] = None, **attrs) -> "LayerSpan":
+        """Mark one layer boundary: `with tracer.layer(name, eval_id):`
+        around the layer's work.  The block becomes a recorder span
+        whose parent is the enclosing layer span of this thread (the
+        trace's tail, as `stage` chains, where there is none), a
+        `nomad.<name>` event of a running `jax.profiler` trace, and a
+        sample `span.<name>` of the metrics registry — `key` keeps an
+        older sample name (`worker.submit_plan`) where one is read.
+        An empty `trace_id` takes the enclosing layer span's; with
+        none to take, or the recorder off or not sampling the trace,
+        no row is written and the other two sinks still are."""
+        return LayerSpan(self, name, trace_id, key, attrs)
+
+    def waited(self, name: str, t_start: float, trace_id: str = "",
+               key: Optional[str] = None, **attrs) -> None:
+        """Record a wait that began at `t_start` (`time.monotonic`,
+        stamped where the work was queued, on whatever thread) and
+        ends now: a recorder span and the sample, as `layer` writes
+        them; no profiler event, a wait occupies no thread."""
+        now = _time.monotonic()
+        sp = self.stage(trace_id, name, **attrs)
+        if sp is not NULL_SPAN:
+            sp.t_start = min(t_start, now)
+            sp.end()
+        global_metrics.add_sample(key or "span." + name,
+                                  max(now - t_start, 0.0))
+
+    def watch_gc(self) -> None:
+        """Mark every collection of CPython's garbage collector above
+        the youngest generation as `gc.pause`: a `nomad.gc.pause` event
+        of a running profiler trace, on the thread the collection
+        stopped, and a sample `span.gc.pause`.  With a hundred thousand
+        allocs in the store a full collection stops every thread for
+        about a second, inside whatever span happened to be open.
+        Idempotent and process-wide (the collector is); a server calls
+        it when it starts."""
+        if not self._gc_watched:
+            self._gc_watched = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # runs wherever an allocation tipped the collector, possibly
+        # under the recorder's or the registry's lock: so it takes no
+        # lock (the profiler event, a clock read, a deque append), and
+        # `_drain_gc` writes the sample from the next layer span's end
+        generation = info["generation"]
+        if generation == 0:
+            return
+        if phase == "start":
+            prof = sys.modules.get("jax.profiler")
+            ann = None
+            if prof is not None:
+                ann = prof.TraceAnnotation("nomad.gc.pause",
+                                           generation=generation)
+                ann.__enter__()
+            self._gc_open = (ann, _time.monotonic())
+        elif self._gc_open is not None:
+            (ann, t0), self._gc_open = self._gc_open, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._gc_done.append(_time.monotonic() - t0)
+
+    def _drain_gc(self) -> None:
+        while True:
+            try:
+                dur_s = self._gc_done.popleft()
+            except IndexError:
+                return
+            global_metrics.add_sample("span.gc.pause", dur_s)
 
     def _record(self, sp: Span) -> None:
         row = {
