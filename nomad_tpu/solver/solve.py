@@ -49,11 +49,25 @@ class LazyAllocsView(dict):
     (live minus `excluded` alloc ids).  The steady-state scheduler only
     touches a handful of nodes per eval (chosen candidates' port/device
     fixups, sticky preferences), so the O(cluster) walk the eager dict
-    pays per eval collapses to O(touched); anything that genuinely
-    needs the whole world (full-pack fallback iterating items()) just
-    materializes.  Once a key is filled it is a plain dict entry, so
-    in-place mutation (sticky probes, preemption rewrites) behaves
-    exactly like the eager dict."""
+    pays per eval collapses to O(touched).
+
+    Reads one node and never walks the cluster: `get`, `[]`, `in`,
+    `setdefault`, and the truth test (`bool(view)`, `if view:`), which
+    says whether the snapshot holds any alloc at all: the eager dict it
+    stands for is empty only when the cluster is (a cluster whose every
+    alloc is terminal or excluded reads true and every `get` then comes
+    back empty, which no caller can tell from the eager dict).
+    Materializes, walking every alloc of the snapshot once: `items()`,
+    `keys()`, `values()`, iteration, `len()` and `dict(view)`: the
+    full-pack fallback and the host preemption walk need the whole
+    world and say so by iterating.
+
+    Both ways keep an alloc by the one test `_live`, and a node filled
+    before a materialize keeps its list, so a filled key is a plain
+    dict entry and in-place mutation (sticky probes, preemption
+    rewrites) behaves exactly like the eager dict.  Counters:
+    `solver.allocs_view.nodes` (nodes whose allocs were read from the
+    snapshot) and `solver.allocs_view.materialized` (full walks)."""
 
     def __init__(self, snapshot, excluded=frozenset()):
         super().__init__()
@@ -62,12 +76,16 @@ class LazyAllocsView(dict):
         self._filled = set()
         self._all = False
 
+    def _live(self, a) -> bool:
+        return not a.terminal_status() and a.id not in self.excluded
+
     def _fill(self, nid) -> None:
         if self._all or nid in self._filled:
             return
         self._filled.add(nid)
+        _m.incr_counter("solver.allocs_view.nodes")
         live = [a for a in self._snap.allocs_by_node(nid)
-                if not a.terminal_status() and a.id not in self.excluded]
+                if self._live(a)]
         if live:                 # eager dict only has non-empty keys
             dict.__setitem__(self, nid, live)
 
@@ -75,14 +93,19 @@ class LazyAllocsView(dict):
         if not self._all:
             pending: Dict[str, list] = {}
             for a in self._snap.allocs():
-                if (a.terminal_status() or a.id in self.excluded
-                        or a.node_id in self._filled):
-                    continue
-                pending.setdefault(a.node_id, []).append(a)
+                if self._live(a) and a.node_id not in self._filled:
+                    pending.setdefault(a.node_id, []).append(a)
             for nid, lst in pending.items():
                 dict.__setitem__(self, nid, lst)
             self._all = True
+            _m.incr_counter("solver.allocs_view.materialized")
+            _m.incr_counter("solver.allocs_view.nodes", len(pending))
         return self
+
+    def __bool__(self):
+        if self._all or dict.__len__(self):
+            return dict.__len__(self) > 0
+        return any(True for _ in self._snap.allocs())
 
     def get(self, nid, default=None):
         self._fill(nid)
@@ -101,21 +124,19 @@ class LazyAllocsView(dict):
         return dict.setdefault(self, nid, default)
 
     def items(self):
-        return self.materialize() and dict.items(self)
+        return dict.items(self.materialize())
 
     def keys(self):
-        return self.materialize() and dict.keys(self)
+        return dict.keys(self.materialize())
 
     def values(self):
-        return self.materialize() and dict.values(self)
+        return dict.values(self.materialize())
 
     def __iter__(self):
-        self.materialize()
-        return dict.__iter__(self)
+        return dict.__iter__(self.materialize())
 
     def __len__(self):
-        self.materialize()
-        return dict.__len__(self)
+        return dict.__len__(self.materialize())
 
 
 class _ResidentWorld:
@@ -884,13 +905,13 @@ class Solver:
         if idx is None:
             idx = NetworkIndex()
             idx.set_node(node)
-            if allocs_by_node:
+            if allocs_by_node is not None:
                 idx.add_allocs(allocs_by_node.get(node.id, ()))
             net_cache[node_ix] = idx
         acct = dev_cache.get(node_ix)
         if acct is None:
             acct = DeviceAccounter(node)
-            if allocs_by_node:
+            if allocs_by_node is not None:
                 acct.add_allocs(allocs_by_node.get(node.id, ()))
             dev_cache[node_ix] = acct
 

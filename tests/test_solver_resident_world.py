@@ -8,9 +8,11 @@ import pytest
 
 from nomad_tpu import mock, structs
 from nomad_tpu.scheduler.harness import Harness
+from nomad_tpu.solver.kernel import TOP_K
 from nomad_tpu.solver.solve import LazyAllocsView, Solver
 from nomad_tpu.solver.tensorize import PlacementAsk
 from nomad_tpu.state.store import StateStore
+from nomad_tpu.utils.metrics import global_metrics
 
 
 def _mk_node(i, store, index):
@@ -195,6 +197,182 @@ def test_lazy_allocs_view_matches_eager():
     assert {k: {a.id for a in v} for k, v in view.items()} == {
         k: {a.id for a in v} for k, v in list(eager.items())
         + [(nodes[3].id, [allocs[0]])]}
+
+
+def _view_counters():
+    c = global_metrics.dump()["counters"]
+    return (c.get("solver.allocs_view.nodes", 0.0),
+            c.get("solver.allocs_view.materialized", 0.0))
+
+
+def test_lazy_allocs_view_truth_test_does_not_materialize():
+    store = StateStore()
+    nodes = [_mk_node(i, store, 100 + i) for i in range(4)]
+    assert not LazyAllocsView(store.snapshot())     # empty cluster
+    allocs = []
+    for k in range(6):
+        a = mock.alloc()
+        a.node_id = nodes[k % 3].id
+        allocs.append(a)
+    store.upsert_allocs(200, allocs)
+    view = LazyAllocsView(store.snapshot())
+    nodes0, walks0 = _view_counters()
+    assert bool(view)
+    assert view, "a view over a snapshot that holds allocs is true"
+    assert _view_counters() == (nodes0, walks0)
+    assert not view._all and not dict.__len__(view)
+    # a node filled (and mutated) before a materialize keeps its list
+    first = view.get(nodes[0].id)
+    assert [a.id for a in first] == [allocs[0].id, allocs[3].id]
+    first.pop()
+    assert _view_counters() == (nodes0 + 1, walks0)
+    assert view.get(nodes[0].id) is first            # read once
+    assert _view_counters() == (nodes0 + 1, walks0)
+    # len(), items() and iteration need the whole world: one walk
+    assert len(view) == 3
+    assert _view_counters() == (nodes0 + 3, walks0 + 1)
+    assert view[nodes[0].id] is first
+    assert {k: [a.id for a in v] for k, v in view.items()} == {
+        nodes[0].id: [allocs[0].id],
+        nodes[1].id: [allocs[1].id, allocs[4].id],
+        nodes[2].id: [allocs[2].id, allocs[5].id]}
+    assert sorted(view) == sorted(n.id for n in nodes[:3])
+    assert _view_counters() == (nodes0 + 3, walks0 + 1)  # walked once
+    for walks in (len, iter, dict, lambda v: v.items(),
+                  lambda v: v.keys(), lambda v: v.values()):
+        fresh = LazyAllocsView(store.snapshot())
+        _, w = _view_counters()
+        walks(fresh)
+        assert _view_counters()[1] == w + 1, walks
+    # every alloc terminal: reads true without a walk, every get empty
+    for a in allocs:
+        a.desired_status = structs.ALLOC_DESIRED_STOP
+        a.client_status = structs.ALLOC_CLIENT_COMPLETE
+    stopped = LazyAllocsView(store.snapshot())
+    assert stopped and stopped.get(nodes[0].id, ()) == ()
+    assert len(stopped) == 0 and not stopped
+
+
+def _fixup_cluster(kind):
+    """8 nodes (2 GPUs each for `device`); nodes 0 and 1 hold a
+    resident alloc that the bin-pack score prefers and whose discrete
+    holdings the kernel cannot see: reserved port 8080 and one GPU."""
+    store = StateStore()
+    nodes = []
+    for i in range(8):
+        n = mock.gpu_node(n_gpus=2) if kind == "device" else mock.node()
+        n.node_resources.cpu, n.node_resources.memory_mb = 8000, 16384
+        store.upsert_node(100 + i, n)
+        nodes.append(n)
+    held = []
+    for n in nodes[:2]:
+        a = mock.alloc()
+        a.node_id = n.id
+        tr = a.allocated_resources.tasks["web"]
+        tr.cpu, tr.memory_mb = 2000, 2048
+        tr.networks = [structs.NetworkResource(
+            device="eth0", ip=n.node_resources.networks[0].ip, mbits=50,
+            reserved_ports=[structs.Port(label="admin", value=8080)])]
+        if kind == "device":
+            dev = n.node_resources.devices[0]
+            tr.devices = [structs.AllocatedDeviceResource(
+                vendor=dev.vendor, type=dev.type, name=dev.name,
+                device_ids=[dev.instances[0].id])]
+        held.append(a)
+    store.upsert_allocs(200, held)
+    job = mock.job()
+    tg = job.task_groups[0]
+    tg.count = 3
+    res = tg.tasks[0].resources
+    res.networks = []
+    if kind == "ports":
+        res.networks = [structs.NetworkResource(
+            mbits=10, reserved_ports=[structs.Port("admin", 8080)],
+            dynamic_ports=[structs.Port(label="http")])]
+    elif kind == "device":
+        res.devices = [structs.RequestedDevice(name="nvidia/gpu", count=1)]
+    store.upsert_job(201, job)
+    return store, nodes, held, job
+
+
+def _offers(out):
+    """(node, ports, device ids) of every placement, in ask order."""
+    rows = []
+    for p in out.placements:
+        tr = p.resources.tasks["web"] if p.node is not None else None
+        rows.append((
+            p.node.id if p.node is not None else None, round(p.score, 9),
+            [(pt.label, pt.value) for net in (tr.networks if tr else ())
+             for pt in net.reserved_ports + net.dynamic_ports],
+            [i for d in (tr.devices if tr else ()) for i in d.device_ids]))
+    return rows
+
+
+@pytest.mark.parametrize("kind", ["plain", "ports", "device"])
+def test_lazy_view_fixup_reads_touched_nodes_only(kind):
+    """The served path's solve (resident world, LazyAllocsView) never
+    walks the cluster, and its offers equal the eager dict's."""
+    store, nodes, held, job = _fixup_cluster(kind)
+    solver = Solver(store=store, resident_min_nodes=1)
+    snapshot = store.snapshot()
+    ready, by_dc = snapshot.ready_nodes_in_dcs(job.datacenters)
+    asks = _asks(job)
+
+    def solve(allocs_by_node):
+        assert solver.resident_active(snapshot)
+        return solver.solve(ready, asks, allocs_by_node, by_dc,
+                            snapshot=snapshot, proposed_delta=((), ()))
+
+    nodes0, walks0 = _view_counters()
+    lazy = solve(LazyAllocsView(snapshot))
+    nodes1, walks1 = _view_counters()
+    assert walks1 == walks0
+    n_place = len(lazy.placements)
+    assert n_place == 3 and all(p.node is not None
+                                for p in lazy.placements)
+    assert 0 < nodes1 - nodes0 <= n_place * TOP_K
+    eager = solve(_eager_allocs(snapshot, ready))
+    assert _view_counters() == (nodes1, walks1)    # a plain dict counts nothing
+    assert _offers(lazy) == _offers(eager)
+    # what the resident allocs hold is never offered again
+    holders = {a.node_id: a for a in held}
+    for node_id, _score, ports, device_ids in _offers(lazy):
+        if kind == "ports":
+            assert node_id not in holders
+            assert ("admin", 8080) in ports
+        if kind == "device" and node_id in holders:
+            taken = holders[node_id].allocated_resources.tasks[
+                "web"].devices[0].device_ids
+            assert device_ids and set(device_ids).isdisjoint(taken)
+
+
+def test_lazy_view_refuses_held_port_and_device_instance():
+    """One node, its reserved port and one of its two GPUs held by a
+    resident alloc: through the lazy view a second ask for the port is
+    refused, and the GPU offered is the free one, then none."""
+    store, nodes, held, job = _fixup_cluster("device")
+    node, snapshot = nodes[0], store.snapshot()
+    tg = job.task_groups[0]
+    ask = PlacementAsk(job=job, tg=tg, count=1)
+    taken = held[0].allocated_resources.tasks["web"].devices[0].device_ids
+    net_cache, dev_cache = {}, {}
+    view = LazyAllocsView(snapshot)
+    got = Solver._host_commit(node, 0, ask, net_cache, dev_cache, view)
+    ids = got.tasks["web"].devices[0].device_ids
+    assert len(ids) == 1 and ids[0] not in taken
+    # both instances are spoken for now (one resident, one in-batch)
+    assert Solver._host_commit(node, 0, ask, net_cache, dev_cache,
+                               view) is None
+    tg.tasks[0].resources.devices = []
+    tg.tasks[0].resources.networks = [structs.NetworkResource(
+        mbits=10, reserved_ports=[structs.Port("admin", 8080)])]
+    assert Solver._host_commit(node, 0, ask, {}, {}, view) is None
+    assert Solver._host_commit(nodes[5], 5, ask, {}, {}, view) is not None
+    # the eager dict and no dict at all, for contrast
+    eager = _eager_allocs(snapshot, nodes)
+    assert Solver._host_commit(node, 0, ask, {}, {}, eager) is None
+    assert Solver._host_commit(node, 0, ask, {}, {}, None) is not None
+    assert not view._all
 
 
 def test_changelog_window_and_truncation():
