@@ -3,6 +3,8 @@ from the configuration's file and counts of work done: never from padded
 shapes or from the program's own model of one implementation."""
 from __future__ import annotations
 
+import cluster
+
 WORD = 4      # bytes: the program is 32-bit everywhere on the device
 
 #: node-side planes any solve of any configuration here must read:
@@ -24,9 +26,11 @@ def attribute_columns(cfg: dict) -> int:
 
 def least_solve_bytes(cfg: dict, solves: float, placements: float) -> float:
     """Per solve, every node-side plane read once for the cluster's real
-    node count; per placement, the chosen node written.  A kernel that
+    node count (those above and each rule's `planes(cfg)`); per
+    placement, the chosen node written.  A kernel that
     keeps the planes in VMEM across the waves of a solve still reads them
     once, so the share reads the same work whatever implements it."""
     nodes = int(cfg["cluster"]["nodes"])
-    planes = BASE_PLANES + attribute_columns(cfg)
+    planes = BASE_PLANES + attribute_columns(cfg) \
+        + sum(of_rule(cfg) for of_rule in cluster.hooks(cfg, "planes"))
     return solves * planes * nodes * WORD + placements * WORD

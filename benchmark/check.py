@@ -45,6 +45,11 @@ the run cannot compute makes the run not correct.
                           jobs of sum_v |allocs on value v - even share| /
                           allocs, 0 = even
 
+A configuration's rules (`rules/<name>.py`, README.md "A rule") add
+fields to the rows (`alloc_row`), numbers of their own (`numbers`, named
+in the rule's `NUMBERS`) and what more a node needs to fit in the
+choice's replay (`rows_fit`).
+
 What state the program scored a node in is not in the rows: a plan is
 scored from a snapshot taken somewhere between its job's registration
 and its commit, and a fused round lets an eval see part of what its
@@ -72,17 +77,56 @@ import cluster
 import reference
 
 PLAN_ENTRIES = ("plan_result", "plan_results_batch")
+#: every number `compare` itself can give a limit something to hold
+NUMBERS = ("unknown_refs", "jobs_off_count", "overcommitted_nodes",
+           "constraint_violations", "not_raft_applied",
+           "off_device_solves", "scores_unrecorded", "score_mismatch_p99",
+           "score_mismatch_max", "choice_gap_p90", "choice_gap_max",
+           "spread_miss_share")
 
 
 def limits_of(cfg: dict) -> Dict[str, float]:
     return {k: v["limit"] for k, v in cfg["correct"]["limits"].items()}
 
 
-def rows_from_snapshot(snapshot, plain: cluster.PlainNodes) -> dict:
+def validate(cfg: dict) -> None:
+    """Before anything is built: every number the configuration holds
+    its runs to and every control it lists is one that this file, the
+    reference or one of its rules knows."""
+    numbers = set(NUMBERS)
+    for rule in cluster.rules_of(cfg):
+        numbers.update(getattr(rule, "NUMBERS", ()))
+    unknown = sorted(set(cfg["correct"]["limits"]) - numbers)
+    if unknown:
+        raise ValueError(
+            f"correct.limits of {cfg.get('name')} lists {unknown}, which "
+            f"neither check.py nor a rule of {cfg.get('rules', [])} "
+            "computes")
+    unknown = sorted(set(cfg["correct"]["controls"])
+                     - set(reference.controls_of(cfg)))
+    if unknown:
+        raise ValueError(
+            f"correct.controls of {cfg.get('name')} lists {unknown}, which "
+            f"neither reference.py nor a rule of {cfg.get('rules', [])} "
+            "defines")
+
+
+def _with_rule_fields(rows: dict, held: List[dict]) -> dict:
+    """The rules' fields of each alloc as columns of `rows` (lists, None
+    where a rule said nothing of that alloc)."""
+    for key in sorted({k for h in held for k in h}):
+        rows[key] = [h.get(key) for h in held]
+    return rows
+
+
+def rows_from_snapshot(cfg: dict, snapshot, plain: cluster.PlainNodes
+                       ) -> dict:
     """One row per live alloc, from the store alone; and the index at
     which each job was registered."""
     slot = {nid: i for i, nid in enumerate(plain.ids)}
     job_ids, groups, node_ix, res, created, score = [], [], [], [], [], []
+    alloc_row = cluster.hooks(cfg, "alloc_row")
+    held: List[dict] = []
     for a in snapshot.allocs():
         if a.terminal_status():
             continue
@@ -97,13 +141,17 @@ def rows_from_snapshot(snapshot, plain: cluster.PlainNodes) -> dict:
         recorded = a.metrics.scores.get(a.node_id) \
             if a.metrics is not None else None
         score.append(np.nan if recorded is None else float(recorded))
-    return {"job_id": job_ids, "group": groups,
-            "node": np.asarray(node_ix, np.int64),
-            "res": np.asarray(res, np.float64).reshape(-1, 3),
-            "create_index": np.asarray(created, np.int64),
-            "score": np.asarray(score, np.float64),
-            "job_index": {j.id: int(j.create_index)
-                          for j in snapshot.jobs()}}
+        if alloc_row:
+            held.append({k: v for fn in alloc_row
+                         for k, v in fn(a).items()})
+    return _with_rule_fields(
+        {"job_id": job_ids, "group": groups,
+         "node": np.asarray(node_ix, np.int64),
+         "res": np.asarray(res, np.float64).reshape(-1, 3),
+         "create_index": np.asarray(created, np.int64),
+         "score": np.asarray(score, np.float64),
+         "job_index": {j.id: int(j.create_index)
+                       for j in snapshot.jobs()}}, held)
 
 
 def raft_view(server) -> dict:
@@ -157,7 +205,7 @@ def compare(cfg: dict, plain: cluster.PlainNodes, rows: dict,
     np.add.at(usage, node[ok], rows["res"][ok])
     out["overcommitted_nodes"] = int((usage > plain.cap).any(axis=1).sum())
 
-    feasible = reference.constraint_mask(plain, cfg["job"])
+    feasible = reference.constraint_mask(cfg, plain)
     sent_set = set(sent_job_ids)
     is_sent = np.array([j in sent_set for j in rows["job_id"]], bool)
     out["constraint_violations"] = int((~feasible[node[ok & is_sent]]).sum())
@@ -203,6 +251,8 @@ def compare(cfg: dict, plain: cluster.PlainNodes, rows: dict,
                         / len(nis))
         if miss:
             out["spread_miss_share"] = float(np.mean(miss))
+    for numbers in cluster.hooks(cfg, "numbers"):
+        out.update(numbers(cfg, plain, rows, sent, ref))
     return out
 
 
@@ -324,7 +374,8 @@ def choice_gaps(cfg: dict, plain: cluster.PlainNodes, rows: dict,
     for; each plan under the most favourable of the store's states after
     each commit from its job's registration up to its own (module
     docstring).  Bin-pack, job anti-affinity and node affinity; no
-    spread."""
+    spread.  A node fits where cpu, memory and disk do and every rule's
+    `rows_fit` says so of the allocs live in that state."""
     jid, grp, node = rows["job_id"], rows["group"], rows["node"]
     ci, res = rows["create_index"], rows["res"]
     reg = rows["job_index"]
@@ -341,8 +392,14 @@ def choice_gaps(cfg: dict, plain: cluster.PlainNodes, rows: dict,
     oldest = np.minimum.accumulate(
         [reg.get(j, 0) for _c, j, _g in keys][::-1])[::-1]
 
+    rows_fit = cluster.hooks(cfg, "rows_fit")
+    # rows a rule counts as live in a state: every alloc that is not of
+    # the run's jobs, and the first m of theirs in commit order
+    others = np.setdiff1d(np.flatnonzero(node >= 0), mine)
+    committed: List[int] = []
     used = reference.resident_usage(cfg, n)
-    states = [(0, used.copy())]        # (index, usage after it), oldest first
+    # (index, usage after it, len(committed) after it), oldest first
+    states = [(0, used.copy(), 0)]
     placed: Dict[tuple, List[int]] = {}            # (job, group) -> rows
     gaps, at = [], 0
     for commit in sorted(by_commit):
@@ -359,12 +416,17 @@ def choice_gaps(cfg: dict, plain: cluster.PlainNodes, rows: dict,
             # not older than the job's registration, nor than the
             # group's own last plan (a retry is scored after it)
             since = max([reg.get(j, 0)] + [int(ci[k]) for k in before[-1:]])
-            first = max((i for i, (x, _u) in enumerate(states)
-                         if x <= since), default=0)
+            first = max((i for i, st in enumerate(states)
+                         if st[0] <= since), default=0)
             best = float("inf")
-            for _x, usage in states[first:]:
+            for _x, usage, m in states[first:]:
                 after = usage + ask
                 fits = feasible & (after <= plain.cap).all(axis=1)
+                if rows_fit:
+                    live = np.concatenate(
+                        [others, np.asarray(committed[:m], np.int64)])
+                    for rule_fit in rows_fit:
+                        fits &= rule_fit(cfg, plain, rows, live, group)
                 if asked > fits.sum():
                     best = 0.0       # fewer nodes fit than were asked for
                     break
@@ -380,10 +442,11 @@ def choice_gaps(cfg: dict, plain: cluster.PlainNodes, rows: dict,
             at += 1
         ks = by_commit[commit]
         np.add.at(used, node[ks], res[ks])
-        states.append((commit, used.copy()))
+        committed.extend(ks)
+        states.append((commit, used.copy(), len(committed)))
         if at < len(keys):
-            keep = max((i for i, (x, _u) in enumerate(states)
-                        if x <= oldest[at]), default=0)
+            keep = max((i for i, st in enumerate(states)
+                        if st[0] <= oldest[at]), default=0)
             del states[:keep]
     return np.array(gaps)
 
@@ -400,6 +463,7 @@ def reference_rows(cfg: dict, plain: cluster.PlainNodes,
     together = round_jobs if placer_kw.get("isolate") else 1
     r = cfg["resident"]
     job_ids, grp, node, res, created, score = [], [], [], [], [], []
+    held: List[dict] = []
     node_of = cluster.resident_node_index(cfg)
     k = 0
     for j, count in enumerate(cluster.resident_jobs(cfg)):
@@ -410,6 +474,7 @@ def reference_rows(cfg: dict, plain: cluster.PlainNodes,
             res.append((r["cpu_mhz"], r["memory_mb"], r["disk_mb"]))
             created.append(1)
             score.append(np.nan)
+            held.append({})
             k += 1
     placed = reference.place_sequence(cfg, plain, [s for _j, s in sent],
                                       round_jobs=round_jobs, **placer_kw)
@@ -417,7 +482,7 @@ def reference_rows(cfg: dict, plain: cluster.PlainNodes,
     for j, (jid, _shape) in enumerate(sent):
         first = 2 + 2 * together * (j // together)
         job_index[jid] = first + j % together
-    for j, gi, ni, sc in placed["rows"]:
+    for j, gi, ni, sc, holds in placed["rows"]:
         g = cluster.job_groups(cfg, sent[j][1])[gi]
         job_ids.append(sent[j][0])
         grp.append(g["name"])
@@ -425,12 +490,14 @@ def reference_rows(cfg: dict, plain: cluster.PlainNodes,
         res.append((g["cpu"], g["mem"], g["disk"]))
         created.append(job_index[sent[j][0]] + together)
         score.append(sc)
-    return {"job_id": job_ids, "group": grp,
-            "node": np.asarray(node, np.int64),
-            "res": np.asarray(res, np.float64).reshape(-1, 3),
-            "create_index": np.asarray(created, np.int64),
-            "score": np.asarray(score, np.float64),
-            "job_index": job_index}
+        held.append(holds)
+    return _with_rule_fields(
+        {"job_id": job_ids, "group": grp,
+         "node": np.asarray(node, np.int64),
+         "res": np.asarray(res, np.float64).reshape(-1, 3),
+         "create_index": np.asarray(created, np.int64),
+         "score": np.asarray(score, np.float64),
+         "job_index": job_index}, held)
 
 
 def verdict(cfg: dict, numbers: Dict[str, float]) -> dict:

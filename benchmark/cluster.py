@@ -12,14 +12,21 @@ the yardstick).  What `--seed` changes is the order nodes are
 registered in, every id, and nothing else: every seed gives the same
 multiset of node sizes and the same job shape, so every seed is the
 same amount of work.
+
+What a node holds and a job asks for beside cpu, memory and disk comes
+from the rules a configuration names (`"rules": ["devices"]` ->
+`rules/devices.py`, found by name; README.md, "A rule"): each generator
+here asks every loaded rule for its part.
 """
 from __future__ import annotations
 
 import copy
+import importlib.util
 import json
 import math
 import os
-from dataclasses import dataclass
+import re
+from dataclasses import dataclass, field
 from typing import Dict, List
 
 import numpy as np
@@ -34,6 +41,44 @@ REHEARSE_TRAFFIC = {"warmup_bursts": [1, 2, 4], "clients": 4, "senders": 2,
                     "wait_timeout_s": 30}
 
 
+#: directories a rule's file is looked for in, in order; a later PR adds
+#: `rules/<name>.py` and edits nothing
+RULE_PATH = [os.path.join(HERE, "rules")]
+_RULE_NAME = re.compile(r"^[A-Za-z0-9_]+$")
+_loaded: Dict[str, object] = {}       # file path -> the rule's module
+
+
+def load_rule(name: str):
+    """The module of `rules/<name>.py`, loaded once a process."""
+    if not _RULE_NAME.match(name):
+        raise ValueError(f"rule name {name!r}: letters, digits and _ only")
+    for d in RULE_PATH:
+        path = os.path.join(d, f"{name}.py")
+        if path in _loaded:
+            return _loaded[path]
+        if os.path.exists(path):
+            spec = importlib.util.spec_from_file_location(
+                f"benchmark_rule_{name}", path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            _loaded[path] = mod
+            return mod
+    raise FileNotFoundError(
+        f"the configuration names the rule {name!r} and no {name}.py is "
+        f"in {RULE_PATH}")
+
+
+def rules_of(cfg: dict) -> list:
+    """The rule modules a configuration names under `rules`, in its
+    order (none: the harness knows cpu, memory and disk alone)."""
+    return [load_rule(name) for name in cfg.get("rules", [])]
+
+
+def hooks(cfg: dict, name: str) -> list:
+    """The function `name` of every rule of `cfg` that defines it."""
+    return [getattr(r, name) for r in rules_of(cfg) if hasattr(r, name)]
+
+
 def load_config(name: str, rehearse: bool = False) -> dict:
     with open(os.path.join(HERE, "configs", f"{name}.json"),
               encoding="utf-8") as f:
@@ -41,6 +86,7 @@ def load_config(name: str, rehearse: bool = False) -> dict:
     if rehearse:
         cfg["cluster"]["nodes"] = REHEARSE["nodes"]
         cfg["resident"]["allocs"] = REHEARSE["resident_allocs"]
+    rules_of(cfg)         # a rule whose file is missing fails here
     return cfg
 
 
@@ -56,6 +102,8 @@ class PlainNodes:
     names: List[str]
     cap: np.ndarray         # [n, 3] float64: cpu MHz, memory MB, disk MB
     cols: Dict[str, np.ndarray]   # target -> the column, as strings
+    #: the rules' own columns (`node_columns`), by the names they chose
+    extra: Dict[str, np.ndarray] = field(default_factory=dict)
 
     def __len__(self) -> int:
         return len(self.ids)
@@ -92,29 +140,38 @@ def make_plain_nodes(cfg: dict, seed: int) -> PlainNodes:
     mem = np.asarray(c["memory_mb"],
                      np.float64)[order % len(c["memory_mb"])]
     disk = np.full(n, float(c["disk_mb"]))
+    extra: Dict[str, np.ndarray] = {}
+    for node_columns in hooks(cfg, "node_columns"):
+        extra.update(node_columns(cfg, order))
     return PlainNodes(
         ids=[hex_id(rng) for _ in range(n)],
         names=[f"node-{int(i)}" for i in order],
         cap=np.stack([cpu, mem, disk], axis=1),
         cols={target: attribute_column(spec, order)
-              for target, spec in c["attributes"].items()})
+              for target, spec in c["attributes"].items()},
+        extra=extra)
 
 
 def job_groups(cfg: dict, shape=None) -> List[dict]:
     """The job template's groups as plain dicts (name, count, cpu, mem,
-    disk): `bench.make_job`'s shapes at gen_seed 0.  `shape` = (groups,
-    count per group) cuts the template down for a warm-up job; the
-    window's jobs are always the whole template."""
+    disk, and what the configuration's rules ask for beside them):
+    `bench.make_job`'s shapes at gen_seed 0.  `shape` = (groups, count
+    per group) cuts the template down for a warm-up job; the window's
+    jobs are always the whole template."""
     j = cfg["job"]
     n_groups, count = shape or (int(j["groups"]),
                                 int(j["count_per_group"]))
-    return [{"name": f"g{g}", "count": int(count),
-             "cpu": float(j["cpu_mhz"] + (g % j["shape_period"])
-                          * j["cpu_step_mhz"]),
-             "mem": float(j["memory_mb"] + (g % j["shape_period"])
-                          * j["memory_step_mb"]),
-             "disk": float(j["disk_mb"])}
-            for g in range(int(n_groups))]
+    groups = [{"name": f"g{g}", "count": int(count),
+               "cpu": float(j["cpu_mhz"] + (g % j["shape_period"])
+                            * j["cpu_step_mhz"]),
+               "mem": float(j["memory_mb"] + (g % j["shape_period"])
+                            * j["memory_step_mb"]),
+               "disk": float(j["disk_mb"])}
+              for g in range(int(n_groups))]
+    for group_asks in hooks(cfg, "group_asks"):
+        for g, group in enumerate(groups):
+            group.update(group_asks(cfg, g, group))
+    return groups
 
 
 def job_count(cfg: dict, shape=None) -> int:
@@ -159,6 +216,7 @@ def build_nodes(plain: PlainNodes, cfg: dict) -> list:
     attrs = {t[len("${attr."):-1]: col for t, col in plain.cols.items()
              if t.startswith("${attr.")}
     dc = plain.cols[DATACENTER]
+    build_node = hooks(cfg, "build_node")
     nodes = []
     for i in range(len(plain)):
         n = mock.node(datacenter=str(dc[i]))
@@ -174,6 +232,8 @@ def build_nodes(plain: PlainNodes, cfg: dict) -> list:
         n.node_resources.disk_mb = int(plain.cap[i, 2])
         for net in n.node_resources.networks:
             net.mbits = mbits
+        for build in build_node:
+            build(n, plain, i, cfg)
         n.compute_class()
         nodes.append(n)
     return nodes
@@ -185,7 +245,9 @@ def _group(base, name, count, cpu, mem, disk):
     tg.count = count
     tg.constraints = []
     t = tg.tasks[0]
-    t.resources.networks = []          # every bench config strips ports
+    # ports and devices stripped, as every bench config strips them: a
+    # configuration that asks for some names the rule that puts them back
+    t.resources.networks = []
     t.resources.cpu = int(cpu)
     t.resources.memory_mb = int(mem)
     t.resources.devices = []
@@ -210,9 +272,14 @@ def build_job(cfg: dict, job_id: str, shape=None):
                    for at, w in j["spreads"]]
     base = job.task_groups[0]
     base.constraints = []
-    job.task_groups = [_group(base, g["name"], g["count"], g["cpu"],
-                              g["mem"], g["disk"])
-                       for g in job_groups(cfg, shape)]
+    build_group = hooks(cfg, "build_group")
+    job.task_groups = []
+    for g in job_groups(cfg, shape):
+        tg = _group(base, g["name"], g["count"], g["cpu"], g["mem"],
+                    g["disk"])
+        for build in build_group:
+            build(tg, g, cfg)
+        job.task_groups.append(tg)
     return job
 
 
@@ -269,6 +336,7 @@ def seed_cluster(server, cfg: dict, plain: PlainNodes, seed: int,
         client_status=structs.ALLOC_CLIENT_RUNNING,
         create_time=now, modify_time=now))
     empty_result = to_wire(PlanResult())
+    resident_alloc = hooks(cfg, "resident_alloc")
     k = 0
     items, in_chunk, entries = [], 0, 0
 
@@ -294,6 +362,8 @@ def seed_cluster(server, cfg: dict, plain: PlainNodes, seed: int,
             w["job_id"] = job.id
             w["node_id"] = plain.ids[ni]
             w["node_name"] = plain.names[ni]
+            for fill in resident_alloc:
+                fill(w, k, cfg)
             by_node.setdefault(plain.ids[ni], []).append(w)
             k += 1
         result = dict(empty_result)

@@ -5,11 +5,11 @@
 
 For each seed: the plain reference put in the program's place (`sound`)
 and each control the configuration's file lists (`correct.controls`, by
-its name in `reference.CONTROLS`) place the same `--jobs` jobs on the
-cell's cluster; the rows they leave go through the same comparison a
-run's store goes through.  `sound` has to come out correct and every
-control not correct.  Host work only:
-nothing here touches JAX or the program.
+its name in `reference.CONTROLS` or in a rule's `CONTROLS`) place the
+same `--jobs` jobs on the cell's cluster; the rows they leave go
+through the same comparison a run's store goes through.  `sound` has to
+come out correct and every control not correct.  Host work only: nothing
+here touches JAX or the program.
 """
 from __future__ import annotations
 
@@ -30,9 +30,10 @@ import reference  # noqa: E402
 def run_controls(cfg: dict, seed: int, n_jobs: int) -> dict:
     plain = cluster.make_plain_nodes(cfg, seed)
     ids = [(f"job-{seed}-{i}", None) for i in range(n_jobs)]
+    known = reference.controls_of(cfg)
     out = {}
     for name, kw in [("sound", dict)] + [
-            (c, reference.CONTROLS[c]) for c in cfg["correct"]["controls"]]:
+            (c, known[c]) for c in cfg["correct"]["controls"]]:
         rows = check.reference_rows(cfg, plain, ids, **kw())
         numbers = check.compare(cfg, plain, rows, ids, None, 0)
         numbers["not_raft_applied"] = 0     # no raft log to hold it to
@@ -51,6 +52,7 @@ def main(argv=None) -> int:
         bench = json.load(f)
     cell = next(w for w in bench["workloads"] if w["name"] == a.workload)
     cfg = cluster.load_config(cell["config"])
+    check.validate(cfg)
     bad = 0
     for seed in (int(s) for s in a.seeds.split(",")):
         t0 = time.monotonic()

@@ -19,13 +19,12 @@ name part says `wait`: `worker.dequeue_wait`, `plan.result_wait`,
 `worker.wait_index`), whatever their sums: a thread that waits says
 nothing about what the others do.  While the collector runs
 (`gc.pause`) no other thread does, so that stretch counts for it alone.
-`unattributed` only where no program span overlaps the gap.  A trace of a program that writes no
-`nomad.*` event (every trace before PR 25) reads `unattributed`
-throughout, with the gaps `xplane.idle_gaps` lists, in its order.
+`unattributed` only where no program span overlaps the gap.  A trace of
+a program that writes no `nomad.*` event (every trace before PR 25)
+reads `unattributed` throughout.
 
-Nothing here is wired into `run.py` yet: PR 25 may add files to the
-benchmark and edit none.  `reduce_idle_attributed` has the signature of
-`layers.REDUCERS`' entries for the `benchmark` PR that adds it there.
+`run.py per_layer` prints `idle_gaps` as `breakdown.idle_gaps`, and
+`reduce_idle_attributed` is `layers.REDUCERS["idle_attributed"]`.
 """
 from __future__ import annotations
 
@@ -143,7 +142,7 @@ def label(gap: Tuple[float, float], leaf_list: List[Leaf],
 
 def device_gaps(profile) -> List[Tuple[float, float]]:
     """(start_s, end_s) of every gap between device ops on the first
-    chip, in time order: the walk of `xplane.idle_gaps`, ends kept."""
+    chip, in time order."""
     planes = xplane.device_planes(profile)
     if not planes:
         return []
@@ -158,9 +157,8 @@ def device_gaps(profile) -> List[Tuple[float, float]]:
 
 
 def idle_gaps(profile, k: int = 10) -> List[list]:
-    """`xplane.idle_gaps` with each gap's label in place of the
-    constant: the same gaps, lengths, order and `[label, seconds]`
-    shape."""
+    """The `k` longest gaps between device ops on the first chip,
+    longest first, as `[label, seconds]`."""
     gaps = sorted(device_gaps(profile), key=lambda g: g[0] - g[1])[:k]
     leaf_list = leaves(host_spans(profile))
     starts = [s for _n, s, _e in leaf_list]
@@ -206,10 +204,11 @@ def idle_attributed_share(profile, waits: bool = True
 
 
 def reduce_idle_attributed(spec: dict, obs) -> Optional[float]:
-    """A `layers.REDUCERS` entry (`idle_attributed_share`)."""
+    """`layers.REDUCERS["idle_attributed"]`; the metric's file says
+    whether wait spans cover (`waits`, default true)."""
     if obs.profile is None:
         return None
-    return idle_attributed_share(obs.profile)
+    return idle_attributed_share(obs.profile, bool(spec.get("waits", True)))
 
 
 def leaf_seconds(profile) -> List[list]:

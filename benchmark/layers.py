@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 import bytes_models
+import host_spans
 import xplane
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -142,6 +143,7 @@ REDUCERS: Dict[str, Callable[[dict, Observed], Optional[float]]] = {
     "device_idle_share": _device_idle,
     "device_time_over": _device_time_over,
     "roofline": _roofline,
+    "idle_attributed": host_spans.reduce_idle_attributed,
 }
 
 

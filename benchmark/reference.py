@@ -18,6 +18,11 @@ Semantics are the reference scheduler's (hashicorp/nomad `scheduler/`):
 placement seeing every earlier one: the straightforward implementation.
 Its `controls` are the lower-precision and guarantee-breaking variants
 that `correct` has to tell apart from a sound run (see check.py).
+
+What a node holds beside cpu, memory and disk (device instances, ports)
+is a rule's: a file `rules/<name>.py` that the configuration names under
+`rules` (README.md, "A rule").  The mask and the placer ask each loaded
+rule; a configuration that names none reads as it always did.
 """
 from __future__ import annotations
 
@@ -28,8 +33,9 @@ import numpy as np
 import cluster
 
 
-def constraint_mask(plain: cluster.PlainNodes, job: dict) -> np.ndarray:
+def constraint_mask(cfg: dict, plain: cluster.PlainNodes) -> np.ndarray:
     """Nodes on which an alloc of the configuration's job may run."""
+    job = cfg["job"]
     ok = np.isin(plain.attr("${node.datacenter}"), list(job["datacenters"]))
     for lt, op, rt in job["constraints"]:
         col = plain.attr(lt)
@@ -47,6 +53,8 @@ def constraint_mask(plain: cluster.PlainNodes, job: dict) -> np.ndarray:
             ok &= col < rt
         else:
             raise ValueError(f"reference has no rule for operand {op!r}")
+    for feasible in cluster.hooks(cfg, "feasible"):
+        ok &= feasible(cfg, plain)
     return ok
 
 
@@ -139,21 +147,38 @@ class Placer:
                  scheduler's own LimitIterator, which scores about
                  log2(nodes) candidates; the configuration states that
                  every node is scored)
+    rule_kw      keywords of a rule's own controls (its `CONTROLS`), for
+                 its hooks to read
+
+    A rule keeps its state on the placer (`state[<its name>]`) through
+    `start(placer)`, `begin_round(placer)` (the `isolate` control's
+    copy) and `begin_job(placer)` (what this job sees); `fits(placer,
+    group)` says on which nodes one more alloc of the group finds what
+    the rule accounts for, and `commit(placer, ni, group)` debits it and
+    returns what the alloc then holds, for the reference's rows.
     """
 
     def __init__(self, cfg: dict, plain: cluster.PlainNodes,
                  dtype=np.float64, isolate: bool = False,
-                 use_spread: bool = True, sample: int = 0):
+                 use_spread: bool = True, sample: int = 0, **rule_kw):
         self.cfg, self.plain = cfg, plain
         self.dtype = dtype
         self.isolate, self.use_spread = isolate, use_spread
         self.sample = sample
+        unknown = set(rule_kw) - {
+            k for rule in cluster.rules_of(cfg)
+            for kw in getattr(rule, "CONTROLS", {}).values() for k in kw}
+        if unknown:
+            raise TypeError(f"Placer has no keyword {sorted(unknown)} and "
+                            "no control of the configuration's rules has")
+        self.rule_kw = rule_kw
+        self.state: Dict[str, object] = {}
         self.rng = np.random.default_rng(len(plain))
         n = len(plain)
         self.cap = plain.cap.astype(dtype)
         self.used = resident_usage(cfg, n).astype(dtype)
         job = cfg["job"]
-        self.feasible = constraint_mask(plain, job)
+        self.feasible = constraint_mask(cfg, plain)
         self.affinity = affinity_column(plain, job)
         self.spreads = []
         swsum = sum(w for _a, w in job["spreads"])
@@ -161,25 +186,37 @@ class Placer:
             vals, inv = np.unique(plain.attr(attr), return_inverse=True)
             self.spreads.append((inv, len(vals), w / swsum))
         self._round_base: Optional[np.ndarray] = None
+        self._fits = cluster.hooks(cfg, "fits")
+        self._commit = cluster.hooks(cfg, "commit")
+        self._call("start")
+
+    def _call(self, hook: str) -> None:
+        for fn in cluster.hooks(self.cfg, hook):
+            fn(self)
 
     # round handling for the `isolate` control
     def begin_round(self) -> None:
         self._round_base = self.used.copy() if self.isolate else None
+        self._call("begin_round")
 
     def place_job(self, shape=None) -> List[List[tuple]]:
         """Place one job (`shape` as `cluster.job_groups` takes it:
         None is the whole template); returns, per group, (node index,
-        the score it was chosen by) of every alloc placed (shorter than
-        the group's count where the reference finds no room).  Every
-        step scores every node; only the chosen node's terms are
-        recomputed between steps, which changes no value."""
+        the score it was chosen by, what the rules say the alloc holds)
+        of every alloc placed (shorter than the group's count where the
+        reference finds no room).  Every step scores every node; only
+        the chosen node's terms are recomputed between steps, which
+        changes no value."""
         seen = self.used if self._round_base is None \
             else self._round_base.copy()
+        self._call("begin_job")
         out = []
         for g in cluster.job_groups(self.cfg, shape):
             ask = np.array([g["cpu"], g["mem"], g["disk"]], self.dtype)
             after = (seen + ask).astype(self.dtype)
             fits = self.feasible & (after <= self.cap).all(axis=1)
+            for rule_fits in self._fits:
+                fits &= rule_fits(self, g)
             binpack = binpack_score(after, self.cap)
             on_node = np.zeros(len(self.plain))       # same job + group
             per_value = [np.zeros(nv) for _i, nv, _w in self.spreads]
@@ -199,13 +236,17 @@ class Placer:
                     looked[self.rng.choice(np.flatnonzero(fits),
                                            self.sample, replace=False)] = True
                 ni = int(np.argmax(np.where(looked, final, -np.inf)))
-                chosen.append((ni, float(final[ni])))
+                holds: dict = {}
+                for commit in self._commit:
+                    holds.update(commit(self, ni, g))
+                chosen.append((ni, float(final[ni]), holds))
                 seen[ni] = after[ni]
                 if seen is not self.used:
                     self.used[ni] = (self.used[ni] + ask).astype(self.dtype)
                 after[ni] = (seen[ni] + ask).astype(self.dtype)
                 fits[ni] = self.feasible[ni] and bool(
-                    (after[ni] <= self.cap[ni]).all())
+                    (after[ni] <= self.cap[ni]).all()) and all(
+                    rule_fits(self, g)[ni] for rule_fits in self._fits)
                 binpack[ni] = binpack_score(after[ni], self.cap[ni])
                 on_node[ni] += 1
                 for (inv, _nv, _w), cnt in zip(self.spreads, per_value):
@@ -218,7 +259,8 @@ def place_sequence(cfg: dict, plain: cluster.PlainNodes, shapes: list,
                    round_jobs: int = 32, **placer_kw) -> Dict[str, object]:
     """Place one job per entry of `shapes` (None: the whole template), in
     rounds of `round_jobs`; returns rows (job number, group number, node
-    index, score) and the per-job, per-group counts placed."""
+    index, score, what the rules say the alloc holds) and the per-job,
+    per-group counts placed."""
     p = Placer(cfg, plain, **placer_kw)
     rows, placed = [], []
     for j, shape in enumerate(shapes):
@@ -227,7 +269,7 @@ def place_sequence(cfg: dict, plain: cluster.PlainNodes, shapes: list,
         per_group = p.place_job(shape)
         placed.append([len(c) for c in per_group])
         for gi, chosen in enumerate(per_group):
-            rows.extend((j, gi, ni, sc) for ni, sc in chosen)
+            rows.extend((j, gi, ni, sc, holds) for ni, sc, holds in chosen)
     return {"rows": rows, "placed": placed}
 
 
@@ -244,3 +286,14 @@ CONTROLS = {
     "spread_ignored": lambda: {"use_spread": False},
     "sampled_14_nodes": lambda: {"sample": 14},
 }
+
+
+def controls_of(cfg: dict) -> Dict[str, object]:
+    """Every control the configuration may list: those above and its
+    rules' own (`CONTROLS = {name: Placer keywords}`), each as a
+    function that gives the keywords."""
+    out = dict(CONTROLS)
+    for rule in cluster.rules_of(cfg):
+        for name, kw in getattr(rule, "CONTROLS", {}).items():
+            out[name] = lambda kw=kw: dict(kw)
+    return out

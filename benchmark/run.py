@@ -177,7 +177,13 @@ def run(a) -> int:
     import load
     bench = load_benchmark()
     cell = find_cell(bench, a.workload)
+    # a configuration that cannot be held to what it lists fails here,
+    # before JAX, the chip or a Server is touched
+    cfg = cluster.load_config(cell["config"], rehearse=a.rehearse)
+    check.validate(cfg)
+    traffic = load.load_traffic(cell["traffic"])
     if a.rehearse:
+        traffic.update(cluster.REHEARSE_TRAFFIC)
         os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         from nomad_tpu.server.server import Server
@@ -198,10 +204,6 @@ def run(a) -> int:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     watch = CompileWatch().install()
 
-    cfg = cluster.load_config(cell["config"], rehearse=a.rehearse)
-    traffic = load.load_traffic(cell["traffic"])
-    if a.rehearse:
-        traffic.update(cluster.REHEARSE_TRAFFIC)
     plain = cluster.make_plain_nodes(cfg, a.seed)
     log(f"cell {cell['name']}: {len(plain)} nodes, "
         f"{cfg['resident']['allocs']} resident allocs, traffic "
@@ -270,7 +272,7 @@ def run(a) -> int:
         server.stop()
 
     t0 = time.monotonic()
-    rows = check.rows_from_snapshot(snapshot, plain)
+    rows = check.rows_from_snapshot(cfg, snapshot, plain)
     del snapshot
     sent = [(r.job_id, r.shape) for r in gen.sent if r.error is None]
     numbers = check.compare(cfg, plain, rows, sent, raft, off_device)
@@ -391,18 +393,20 @@ def start_trace(a) -> str:
 
 
 def per_layer(bench, cell, cfg, devs, traced, a) -> dict:
+    import host_spans
     import layers
-    import xplane
     import stats
+    import xplane
     p0, p1 = traced["p0"], traced["p1"]
     d = layers.diff_dumps(p0["dump"], p1["dump"])
     lat = [r.t_visible - r.t_due for r in traced["regs"]
            if r.t_visible is not None]
+    summary = stats.latency_summary_ms(lat) if lat else {}
     obs = layers.Observed(
         counters=d["counters"], samples=d["samples"], hists=d["hists"],
         harness={"window_s": traced["window_s"],
-                 "reg_to_visible_p50_ms": stats.latency_summary_ms(
-                     lat)["p50"] if lat else None,
+                 "reg_to_visible_p50_ms": summary.get("p50"),
+                 "reg_to_visible_p95_ms": summary.get("p95"),
                  "evals_completed": p1["evals"] - p0["evals"],
                  "placements_visible": p1["placements"] - p0["placements"],
                  "compile_requests": p1["compile"]["requests"]
@@ -417,7 +421,7 @@ def per_layer(bench, cell, cfg, devs, traced, a) -> dict:
         traced["busy_s"] = xplane.busy_seconds(obs.profile)
         traced["breakdown"] = {
             "device_ops": xplane.top_ops(obs.profile),
-            "idle_gaps": xplane.idle_gaps(obs.profile)}
+            "idle_gaps": host_spans.idle_gaps(obs.profile)}
     out = {}
     for m in metrics_of(bench, "per_layer", cell["name"]):
         value = layers.read_metric(m["name"], obs)

@@ -37,9 +37,9 @@ def test_a_sound_run_is_correct(capsys, monkeypatch):
     assert line["correct"] is True, line["compared"]
     assert line["failed"] == 0 and line["attempted"] > 0
     assert list(line)[-1] == "compared"
+    # the tail is per layer in this cell (`gc_edge_reg_to_visible_p95_ms`)
     assert set(line["metrics"]) == {"placements_per_s", "setup_s",
-                                    "reg_to_visible_p50_ms",
-                                    "reg_to_visible_p95_ms"}
+                                    "reg_to_visible_p50_ms"}
 
 
 def test_half_of_a_batch_left_out(capsys, monkeypatch):

@@ -124,23 +124,6 @@ def top_ops(profile, k: int = 10) -> List[list]:
     return [[n, t / n_chips] for n, t in rows]
 
 
-def idle_gaps(profile, k: int = 10) -> List[list]:
-    """The longest gaps between device ops on the first chip.  The
-    program writes no `TraceAnnotation`, so what the host did in a gap is
-    not known: every gap is listed as "unattributed"."""
-    planes = device_planes(profile)
-    if not planes:
-        return []
-    iv = sorted((s, e) for _n, s, e in events(planes[0], OPS_LINE))
-    gaps, end = [], None
-    for s, e in iv:
-        if end is not None and s > end:
-            gaps.append(s - end)
-        end = e if end is None else max(end, e)
-    gaps.sort(reverse=True)
-    return [["unattributed", g] for g in gaps[:k]]
-
-
 def describe(profile, max_names: int = 12) -> dict:
     """Planes, lines and the commonest event names: what a builder looks
     at by hand before writing a pattern."""
@@ -167,5 +150,4 @@ if __name__ == "__main__":
     prof = load(sys.argv[1])
     print(json.dumps({"planes": describe(prof),
                       "busy_s": busy_seconds(prof),
-                      "top_ops": top_ops(prof),
-                      "idle_gaps": idle_gaps(prof)}, indent=1))
+                      "top_ops": top_ops(prof)}, indent=1))
