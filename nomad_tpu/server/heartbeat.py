@@ -10,6 +10,20 @@ stays bounded (reference: nomad/heartbeat.go:34 nodeHeartbeater,
 one Python thread per node would not scale to the 10K-node target, so
 the deadline heap replaces the timer map — a reset simply moves the
 node's authoritative deadline, and stale heap entries are skipped.
+
+One departure from heartbeat.go, bounded: the TTL a node is TOLD is the
+reference's (scaled to the timers tracked when it is told), but a
+deadline that comes due is held against the fleet tracked THEN.  A node
+is declared down once it has been silent for longer than both what it
+was told and what the fleet it now belongs to is allowed (its own
+stagger kept).  A fleet that registers in a burst tells its first few
+hundred nodes 10 to 20 s while it is still small; by the time those
+deadlines come due every node that heartbeats is told the full fleet's
+TTL, and the early ones are held to no less.  The wait is never above
+`2 * rate_scaled_interval(tracked now) + grace` after the node was last
+heard: what any node of that fleet that has heartbeated once is given.
+A fleet that does not grow past `min_ttl * max_rate` nodes is told and
+held to exactly the reference's deadlines.
 """
 from __future__ import annotations
 
@@ -53,6 +67,8 @@ class NodeHeartbeater:
         # node id -> authoritative deadline; heap entries are advisory and
         # skipped unless they match the authoritative value
         self._deadlines: Dict[str, float] = {}
+        # node id -> (when last heard, its stagger as a share of the TTL)
+        self._heard: Dict[str, Tuple[float, float]] = {}
         self._heap: List[Tuple[float, str]] = []
         self._cv = threading.Condition()
         self._enabled = False
@@ -74,6 +90,7 @@ class NodeHeartbeater:
                 self._watcher.start()
             else:
                 self._deadlines.clear()
+                self._heard.clear()
                 self._heap.clear()
                 watcher, self._watcher = self._watcher, None
                 self._cv.notify_all()
@@ -89,6 +106,7 @@ class NodeHeartbeater:
                 return
             now = _time.monotonic()
             for nid in node_ids:
+                self._heard[nid] = (now, 0.0)
                 self._set_deadline_locked(nid, now + self.failover_ttl)
             self._cv.notify_all()
 
@@ -101,10 +119,12 @@ class NodeHeartbeater:
             if not self._enabled:
                 return None
             n = len(self._deadlines)
-            ttl = rate_scaled_interval(self.max_rate, self.min_ttl, n)
-            ttl += random.uniform(0, ttl)   # stagger, reference :107
-            self._set_deadline_locked(
-                node_id, _time.monotonic() + ttl + self.grace)
+            stagger = random.random()       # reference :107
+            ttl = (1.0 + stagger) * rate_scaled_interval(
+                self.max_rate, self.min_ttl, n)
+            now = _time.monotonic()
+            self._heard[node_id] = (now, stagger)
+            self._set_deadline_locked(node_id, now + ttl + self.grace)
             self._cv.notify_all()
             return ttl
 
@@ -117,26 +137,43 @@ class NodeHeartbeater:
         skipped by the watcher; reference: heartbeat.go:171)."""
         with self._cv:
             self._deadlines.pop(node_id, None)
+            self._heard.pop(node_id, None)
 
     def active(self) -> int:
         with self._cv:
             return len(self._deadlines)
 
     # ------------------------------------------------------------- watcher
+    def _pop_expired_locked(self, now: float) -> List[str]:
+        """Nodes whose deadline has come due by `now` and whom the fleet
+        tracked at `now` allows no longer silence (module docstring); a
+        node it does allow more is re-armed at that."""
+        expired: List[str] = []
+        while self._heap and self._heap[0][0] <= now:
+            deadline, nid = heapq.heappop(self._heap)
+            # only authoritative (not reset-superseded or cleared)
+            # entries expire the node
+            if self._deadlines.get(nid) != deadline:
+                continue
+            heard, stagger = self._heard[nid]
+            allowed = heard + self.grace + (1.0 + stagger) \
+                * rate_scaled_interval(self.max_rate, self.min_ttl,
+                                       len(self._deadlines))
+            if allowed > now:
+                self._set_deadline_locked(nid, allowed)
+                continue
+            del self._deadlines[nid]
+            del self._heard[nid]
+            expired.append(nid)
+        return expired
+
     def _watch(self) -> None:
         while True:
-            expired: List[str] = []
             with self._cv:
                 if not self._enabled:
                     return
                 now = _time.monotonic()
-                while self._heap and self._heap[0][0] <= now:
-                    deadline, nid = heapq.heappop(self._heap)
-                    # only authoritative (not reset-superseded or cleared)
-                    # entries expire the node
-                    if self._deadlines.get(nid) == deadline:
-                        del self._deadlines[nid]
-                        expired.append(nid)
+                expired = self._pop_expired_locked(now)
                 if not expired:
                     wait = 0.5
                     if self._heap:

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time as _t
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -361,6 +362,32 @@ class SolveOutput:
     trace: Dict = field(default_factory=dict)
 
 
+class _DeviceTally:
+    """What the device asks of one solve cost its host fixup, summed
+    over the placements by `Solver._host_commit`; `_finish_solve`
+    writes it out once (a solve whose tasks ask for no device leaves
+    it at zero and nothing is written)."""
+
+    __slots__ = ("commits", "seconds", "instances", "refused")
+
+    def __init__(self):
+        self.commits = 0        # _host_commit calls whose group asks
+        self.seconds = 0.0      # ... and the time of their device part
+        self.instances = 0      # instance ids handed out
+        self.refused = 0        # chosen nodes the instance ids refused
+
+    def add(self, offers: Optional[Dict[str, list]],
+            seconds: float) -> None:
+        self.commits += 1
+        self.seconds += seconds
+        if offers is None:
+            self.refused += 1
+        else:
+            self.instances += sum(len(got.device_ids)
+                                  for task in offers.values()
+                                  for got in task)
+
+
 class PendingSolve:
     """An in-flight fused solve: packed and dispatched to the device,
     fetch + host fixup deferred.  `wait()` is the ONLY blocking step —
@@ -631,7 +658,6 @@ class Solver:
         `spans` names the layer spans of pack, dispatch, fetch and
         fixup: `solve.*` on the single-eval path, `fleet.*` from the
         fused round, so that neither's samples count the other's."""
-        import time as _t
         if not asks:
             return PendingSolve(self, out=SolveOutput(placements=[]))
         with _tr.layer(spans + ".pack") as pack:
@@ -715,6 +741,7 @@ class Solver:
         # re-enforced here across in-batch commits.
         net_cache: Dict[int, NetworkIndex] = {}
         dev_cache: Dict[int, DeviceAccounter] = {}
+        dev_tally = _DeviceTally()
         host_used = pb.used0.copy()
         chosen_by_ask: Dict[int, set] = {}
         # distinct_property charges shared batch-wide by (scope, target) key
@@ -767,7 +794,7 @@ class Solver:
                 placed = self._evict_commit(
                     int(choice[p, 0]), g, ask, pb, sol_nodes,
                     allocs_by_node, evict[p], host_used,
-                    float(score[p, 0]), m)
+                    float(score[p, 0]), m, dev_tally)
                 if placed is not None:
                     by_p[p] = placed
                     continue
@@ -788,7 +815,8 @@ class Solver:
                 if prop_vals is None:
                     continue
                 resources = self._host_commit(node, ni, ask, net_cache,
-                                              dev_cache, allocs_by_node)
+                                              dev_cache, allocs_by_node,
+                                              dev_tally)
                 if resources is None:
                     continue
                 host_used[ni] += ask_vec
@@ -823,6 +851,15 @@ class Solver:
         # position
         placements: List[Placement] = [by_p[p]
                                        for p in range(pb.n_place)]
+        if dev_tally.commits:
+            # the device part of the walk, once a solve: a layer span a
+            # placement would cost more than the work it times
+            _m.incr_counter("solver.device.instances",
+                            dev_tally.instances)
+            _m.incr_counter("solver.device.refused", dev_tally.refused)
+            if spans == "solve":
+                # no metric reads the fused round's
+                _tr.summed("solve.devices", dev_tally.seconds)
 
         # class eligibility for blocked-eval optimization
         class_elig: List[Dict[str, bool]] = []
@@ -845,8 +882,8 @@ class Solver:
     def _evict_commit(self, ni: int, g: int, ask: PlacementAsk,
                       pb: PackedBatch, sol_nodes, allocs_by_node,
                       ev_row: np.ndarray, host_used: np.ndarray,
-                      score: float, m: AllocMetric
-                      ) -> Optional[Placement]:
+                      score: float, m: AllocMetric,
+                      dev_tally: _DeviceTally) -> Optional[Placement]:
         """Host fixup for a kernel-committed (place, evict) pair: map
         the victim-slot mask back to alloc ids through the packed
         `ev_ids` rows, re-check capacity net of the freed usage, and
@@ -879,7 +916,7 @@ class Solver:
             return None
         remaining = [a for a in proposed if a.id not in vset]
         resources = self._host_commit(node, ni, ask, {}, {},
-                                      {node.id: remaining})
+                                      {node.id: remaining}, dev_tally)
         if resources is None:
             return None
         host_used[ni] += ask_vec - freed
@@ -893,13 +930,18 @@ class Solver:
     def _host_commit(node: Node, node_ix: int, ask: PlacementAsk,
                      net_cache: Dict[int, NetworkIndex],
                      dev_cache: Dict[int, DeviceAccounter],
-                     allocs_by_node) -> Optional[AllocatedResources]:
+                     allocs_by_node,
+                     dev_tally: Optional[_DeviceTally] = None
+                     ) -> Optional[AllocatedResources]:
         """Build AllocatedResources with real ports + device instance ids.
 
         Works on clones and reserves each offer immediately, so multiple
         tasks in one group see each other's ports/instances; the clone is
         only promoted into the cache on success (all-or-nothing).
         Returns None if the discrete assignment fails on this node.
+        The node's DeviceAccounter is built, and `dev_tally` (a
+        solve's; the schedulers' own walks keep none) written, only
+        where a task of the group asks for a device.
         """
         idx = net_cache.get(node_ix)
         if idx is None:
@@ -908,15 +950,19 @@ class Solver:
             if allocs_by_node is not None:
                 idx.add_allocs(allocs_by_node.get(node.id, ()))
             net_cache[node_ix] = idx
-        acct = dev_cache.get(node_ix)
-        if acct is None:
-            acct = DeviceAccounter(node)
-            if allocs_by_node is not None:
-                acct.add_allocs(allocs_by_node.get(node.id, ()))
-            dev_cache[node_ix] = acct
-
         idx = idx.clone()
-        acct = acct.clone()
+
+        acct, dev_offers = None, {}
+        if any(t.resources.devices for t in ask.tg.tasks):
+            t0 = _t.perf_counter()
+            offered = Solver._device_offers(node, node_ix, ask, dev_cache,
+                                            allocs_by_node)
+            if dev_tally is not None:
+                dev_tally.add(None if offered is None else offered[1],
+                              _t.perf_counter() - t0)
+            if offered is None:
+                return None
+            acct, dev_offers = offered
 
         out = AllocatedResources()
         for t in ask.tg.tasks:
@@ -928,13 +974,7 @@ class Solver:
                     return None
                 idx.add_reserved(offer)
                 tr.networks.append(offer)
-            for d in t.resources.devices:
-                got = Solver._assign_devices(acct, node, d)
-                if got is None:
-                    return None
-                acct.add_reserved(got.vendor, got.type, got.name,
-                                  got.device_ids)
-                tr.devices.append(got)
+            tr.devices.extend(dev_offers.get(t.name, ()))
             out.tasks[t.name] = tr
         shared_nets = []
         for ask_net in ask.tg.networks:
@@ -946,8 +986,37 @@ class Solver:
         out.shared = AllocatedSharedResources(
             disk_mb=ask.tg.ephemeral_disk.size_mb, networks=shared_nets)
         net_cache[node_ix] = idx
-        dev_cache[node_ix] = acct
+        if acct is not None:
+            dev_cache[node_ix] = acct
         return out
+
+    @staticmethod
+    def _device_offers(node: Node, node_ix: int, ask: PlacementAsk,
+                       dev_cache: Dict[int, DeviceAccounter],
+                       allocs_by_node):
+        """The device part of `_host_commit`: a clone of the node's
+        accounter (built from the node's allocs at its first touch in a
+        solve) with the instance ids of every task of the group reserved
+        on it, and those offers by task name.  None where the node
+        cannot serve an ask: the device's fit counted instances, the ids
+        are settled here."""
+        acct = dev_cache.get(node_ix)
+        if acct is None:
+            acct = DeviceAccounter(node)
+            if allocs_by_node is not None:
+                acct.add_allocs(allocs_by_node.get(node.id, ()))
+            dev_cache[node_ix] = acct
+        acct = acct.clone()
+        offers: Dict[str, list] = {}
+        for t in ask.tg.tasks:
+            for d in t.resources.devices:
+                got = Solver._assign_devices(acct, node, d)
+                if got is None:
+                    return None
+                acct.add_reserved(got.vendor, got.type, got.name,
+                                  got.device_ids)
+                offers.setdefault(t.name, []).append(got)
+        return acct, offers
 
     @staticmethod
     def _property_fit(node: Node, ask: PlacementAsk,

@@ -355,6 +355,22 @@ class FlightRecorder:
         global_metrics.add_sample(key or "span." + name,
                                   max(now - t_start, 0.0))
 
+    def summed(self, name: str, dur_s: float) -> None:
+        """Record `dur_s` seconds of work that ran in many short
+        stretches inside the enclosing layer span of this thread, where
+        a `layer` a stretch would cost more than the work: one recorder
+        span, a child of that layer span, which ends now and is `dur_s`
+        long, and the sample, as `layer` writes them; no profiler event,
+        the stretches are not contiguous."""
+        stack = self._layer_stack()
+        outer = stack[-1] if stack else None
+        if outer is not None and outer.span_id:
+            sp = self.span(outer.trace_id, name, parent=outer.span_id)
+            if sp is not NULL_SPAN:
+                sp.t_start -= dur_s
+                sp.end()
+        global_metrics.add_sample("span." + name, dur_s)
+
     def watch_gc(self) -> None:
         """Mark every collection of CPython's garbage collector above
         the youngest generation as `gc.pause`: a `nomad.gc.pause` event

@@ -1,0 +1,128 @@
+"""`BENCHMARK.json` against the files it names: every configuration, cell
+and per-layer metric is findable as `benchmark/run.py` finds it (by
+name, under `benchmark/`), so that an entry nobody can run, or a file no
+entry names, fails here, on the CPU, without git and without the chip.
+"""
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+
+with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
+    BENCH = json.load(_f)
+
+CONFIGS = [c["name"] for c in BENCH["configs"]]
+CELLS = [w["name"] for w in BENCH["workloads"]]
+PER_LAYER = [m["name"] for m in BENCH["per_layer"]]
+END_TO_END = {m["name"]: m for m in BENCH["end_to_end"]}
+
+
+@pytest.fixture(scope="module")
+def cluster():
+    """`benchmark/cluster.py` under its bare name, as the harness
+    imports it (numpy and the standard library at module level)."""
+    sys.path.insert(0, BENCH_DIR)
+    import cluster
+    yield cluster
+    sys.path.remove(BENCH_DIR)
+
+
+def reports(cell: str, metric: str) -> bool:
+    m = END_TO_END[metric]
+    return "workloads" not in m or cell in m["workloads"]
+
+
+def by_name(section: str, name: str) -> dict:
+    return next(e for e in BENCH[section] if e["name"] == name)
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_a_configuration_has_its_file_source_and_rules(cluster, name):
+    c = by_name("configs", name)
+    assert c["file"] == f"benchmark/configs/{name}.json"
+    assert os.path.exists(os.path.join(ROOT, c["file"]))
+    assert 1 <= len(c["source"]) <= 200 and "\n" not in c["source"]
+    assert isinstance(c["reduced"], list)
+    cfg = cluster.load_config(name)            # loads its rules too
+    assert cfg["name"] == name and cfg["reduced"] == c["reduced"]
+    assert [r.__name__ for r in cluster.rules_of(cfg)] == \
+        [f"benchmark_rule_{r}" for r in cfg.get("rules", [])]
+    assert any(w["config"] == name for w in BENCH["workloads"])
+    # every limit says what it holds and carries its two readings
+    for number, lim in cfg["correct"]["limits"].items():
+        assert {"limit", "holds", "lower", "upper"} <= set(lim), number
+        assert "not read yet" not in lim["lower"] + lim["upper"], number
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_a_cell_names_what_exists(name):
+    w = by_name("workloads", name)
+    assert w["config"] in CONFIGS
+    assert name == f"{w['config']}.{w['traffic']}"
+    assert os.path.exists(os.path.join(
+        BENCH_DIR, "traffic", f"{w['traffic']}.json"))
+    assert w["chips"] in (1, 4)
+    assert 1 <= len(w["why"]) <= 200
+    # it reports setup_s, another end-to-end metric, a per-layer metric
+    assert reports(name, "setup_s")
+    assert any(reports(name, m) for m in END_TO_END if m != "setup_s")
+    assert any(name in m.get("workloads", CELLS)
+               for m in BENCH["per_layer"])
+
+
+@pytest.mark.parametrize("name", PER_LAYER)
+def test_a_per_layer_metric_has_its_file_and_lists_cells_that_report(name):
+    m = by_name("per_layer", name)
+    path = os.path.join(BENCH_DIR, "layer_metrics", f"{name}.json")
+    with open(path, encoding="utf-8") as f:
+        spec = json.load(f)
+    assert spec["name"] == name and spec["layer"] == m["layer"]
+    assert "reducer" in spec
+    assert m["moves"] in END_TO_END
+    for cell in m.get("workloads", CELLS):
+        assert cell in CELLS, (name, cell)
+        assert reports(cell, m["moves"]), (name, cell, m["moves"])
+
+
+def test_no_metric_file_without_an_entry():
+    files = {f[:-len(".json")] for f in os.listdir(
+        os.path.join(BENCH_DIR, "layer_metrics")) if f.endswith(".json")}
+    assert files == set(PER_LAYER)
+    assert len(set(PER_LAYER)) == len(PER_LAYER)
+    assert len(set(CELLS)) == len(CELLS)
+    assert len(set(CONFIGS)) == len(CONFIGS)
+
+
+def test_the_device_deployment_and_its_cell_are_there(cluster):
+    assert "c4-devices-10k" in CONFIGS
+    cell = by_name("workloads", "c4-devices-10k.closed1")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("c4-devices-10k", "closed1", 1)
+    for metric in ("placements_per_s", "reg_to_visible_p50_ms", "setup_s"):
+        assert reports(cell["name"], metric)
+    cfg = cluster.load_config("c4-devices-10k")
+    assert cfg["rules"] == ["devices"] and cfg["reduced"] == []
+    assert cfg["cluster"]["nodes"] == 10_000
+    assert cfg["cluster"]["devices"] == {
+        "name": "google/tpu/v4", "instances": 8, "every": 2}
+    assert cfg["job"]["devices"] == {"name": "google/tpu/v4", "count": 1}
+    assert cfg["job"]["count_per_group"] == 16
+    assert cfg["resident"]["allocs"] == 50_000
+    limits, controls = cfg["correct"]["limits"], cfg["correct"]["controls"]
+    assert limits["device_overbooked"]["limit"] == 0
+    assert limits["device_unmet"]["limit"] == 0
+    assert "devices_unaccounted" in controls
+    # the ceiling never asks for more instances than the cluster holds
+    d = cfg["cluster"]["devices"]
+    held = cfg["cluster"]["nodes"] // d["every"] * d["instances"]
+    assert cluster.ceiling_jobs(cfg) * cluster.job_count(cfg) <= held
+    # what the program adds for it is read by a metric the cell lists
+    for name in ("device_assign_ms", "device_refused_per_solve",
+                 "device_instances_per_solve", "plan_evaluate_ms",
+                 "wave_loop_roofline", "waves_per_solve",
+                 "device_ms_per_wave", "device_idle_share"):
+        assert cell["name"] in by_name("per_layer", name)["workloads"]

@@ -138,3 +138,96 @@ def test_down_node_resuming_heartbeats_restored_to_ready():
             structs.NODE_STATUS_READY
     finally:
         server.stop()
+
+
+# --- a deadline that comes due is held against the fleet tracked then ---
+# (heartbeat.py's one departure from heartbeat.go; every case asserts the
+# upper limit as well as the hold).  The clock is the argument of
+# `_pop_expired_locked`, so nothing sleeps; the defaults (10 s, 50 a
+# second, 10 s grace) put the watcher's own deadlines out of the test's way.
+
+def _tracking(n, **kw):
+    hb = NodeHeartbeater(lambda nid: None, **kw)
+    hb.set_enabled(True)
+    t0 = time.monotonic()
+    told = {f"n{i}": hb.reset(f"n{i}") for i in range(n)}
+    return hb, t0, told, time.monotonic()
+
+
+def _expired_by(hb, now):
+    with hb._cv:
+        return set(hb._pop_expired_locked(now))
+
+
+def test_a_burst_that_stays_small_is_held_to_the_references_deadlines():
+    """400 nodes register at once and fall silent: each is told 10 to
+    20 s and is down at what it was told plus the grace, never later."""
+    hb, t0, told, t1 = _tracking(400)
+    try:
+        assert all(10.0 <= ttl < 20.0 for ttl in told.values())
+        assert _expired_by(hb, t0 + 19.9) == set()
+        early = {nid for nid, ttl in told.items() if ttl < 12.0}
+        late = {nid for nid, ttl in told.items() if ttl > 13.0}
+        gone = _expired_by(hb, t1 + 22.5)
+        assert early <= gone and not (late & gone)
+        _expired_by(hb, t1 + 30.0)
+        assert hb.active() == 0
+    finally:
+        hb.set_enabled(False)
+
+
+def test_a_told_ttl_is_the_references_whatever_follows():
+    hb, t0, told, t1 = _tracking(2000)
+    try:
+        for i in (0, 499, 500, 1000, 1999):
+            base = rate_scaled_interval(50.0, 10.0, i)
+            assert base <= told[f"n{i}"] < 2 * base
+    finally:
+        hb.set_enabled(False)
+
+
+def test_an_early_node_of_a_grown_fleet_is_held_to_that_fleet_and_no_longer():
+    """The first nodes of 10,000 that register in a burst are told 10 to
+    20 s; when that comes due the fleet's nodes are told 200 to 400 s,
+    and the early ones are held to the same: not down at 30 s, down by
+    2 x 200 + 10 s after they were last heard."""
+    hb, t0, told, t1 = _tracking(10_000)
+    try:
+        early = [f"n{i}" for i in range(500)]
+        assert all(told[nid] < 20.0 for nid in early)
+        assert _expired_by(hb, t1 + 30.0) == set()
+        assert hb.active() == 10_000
+        with hb._cv:
+            for nid in early:
+                heard, stagger = hb._heard[nid]
+                assert hb._deadlines[nid] <= heard + 2 * 200.0 + 10.0
+                assert hb._deadlines[nid] >= heard + 200.0 + 10.0
+        # the upper limit, for the whole silent fleet
+        _expired_by(hb, t1 + 410.0)
+        assert hb.active() == 0
+    finally:
+        hb.set_enabled(False)
+
+
+def test_a_heartbeat_after_the_hold_is_told_the_fleets_ttl():
+    hb, t0, told, t1 = _tracking(10_000)
+    try:
+        _expired_by(hb, t1 + 30.0)          # n0 is held, not down
+        assert 200.0 <= hb.reset("n0") < 400.0
+    finally:
+        hb.set_enabled(False)
+
+
+def test_a_fleet_that_shrinks_expires_no_node_before_it_was_told():
+    hb, t0, told, t1 = _tracking(10_000)
+    try:
+        last = "n9999"
+        assert told[last] >= 199.0
+        for i in range(9_999):
+            hb.clear(f"n{i}")
+        # alone now, and a fleet of one is allowed 10 to 20 s: it still
+        # keeps what it was told
+        assert _expired_by(hb, t0 + told[last] + 9.9) == set()
+        assert _expired_by(hb, t1 + told[last] + 10.0) == {last}
+    finally:
+        hb.set_enabled(False)
