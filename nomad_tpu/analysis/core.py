@@ -188,8 +188,8 @@ class AnalysisConfig:
     # warn) that carry a baseline justification naming the bound.
     obs_metric_prefixes: Tuple[str, ...] = (
         "broker", "coordinator", "health", "mesh", "metrics", "plan",
-        "rpc", "scheduler", "serving", "slo", "solver", "telemetry",
-        "watchdog", "worker",
+        "rpc", "scheduler", "serving", "slo", "solver", "state",
+        "telemetry", "watchdog", "worker",
     )
     # the sinks themselves (name arrives as a parameter there; the
     # tracer's layer spans write the sample `span.<name>`, or the key

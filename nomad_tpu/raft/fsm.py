@@ -297,6 +297,7 @@ class StateFSM:
                 by_job.setdefault((a.namespace, a.job_id), set()).add(a.id)
             st._t["_allocs_by_node"] = by_node
             st._t["_allocs_by_job"] = by_job
+            st._disown_indexes_locked()
             st._ix = dict(snap.get("table_indexes", {}))
             st.index = snap.get("latest_index", 0)
             st._watch.notify_all()

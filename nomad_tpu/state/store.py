@@ -5,7 +5,9 @@ Rebuild notes: instead of radix-tree MVCC we keep plain dict tables plus
 secondary indexes, and give schedulers immutable *snapshots* (shallow table
 copies). Entries are treated as immutable once inserted — writers replace
 objects, never mutate in place — which is what makes the shallow snapshot
-sound (same discipline the reference enforces via memdb).
+sound (same discipline the reference enforces via memdb).  The one
+value a writer does mutate, an alloc index's id set, it copies first
+where a snapshot may share it (`ALLOC_INDEXES`).
 
 Every write carries a raft-style log index; per-table indexes power blocking
 queries (reference: rpc.go blocking-query min-index machinery).
@@ -22,12 +24,18 @@ from ..structs import (ALLOC_CLIENT_LOST, ALLOC_DESIRED_STOP, Allocation,
                        PlanResult)
 from ..structs.consts import (EVAL_STATUS_BLOCKED, EVAL_STATUS_COMPLETE,
                               EVAL_STATUS_PENDING, JOB_TYPE_SYSTEM)
+from ..utils.metrics import global_metrics as _m
 
 TABLES = ("nodes", "jobs", "job_versions", "job_summaries", "evals", "allocs",
           "deployments", "periodic_launches", "scheduler_config", "indexes",
           "acl_policies", "acl_tokens", "scaling_policies", "scaling_events",
           "vault_accessors", "csi_volumes", "csi_plugins", "cluster_meta",
           "services", "secrets")
+
+#: the alloc table's secondary indexes: key -> set of alloc ids.  A
+#: snapshot shares the sets with the store, which copies a key's set
+#: before its first write after that snapshot.
+ALLOC_INDEXES = ("_allocs_by_node", "_allocs_by_job")
 
 
 class JobSummary:
@@ -242,9 +250,12 @@ class StateStore(StateSnapshot):
 
     def __init__(self) -> None:
         tables: Dict[str, dict] = {name: {} for name in TABLES}
-        tables["_allocs_by_node"] = {}
-        tables["_allocs_by_job"] = {}
+        for name in ALLOC_INDEXES:
+            tables[name] = {}
         super().__init__(tables, {}, 0)
+        # index name -> the keys whose id set no snapshot shares: only
+        # those may be mutated in place (`_own_ids_locked`)
+        self._owned: Dict[str, set] = {name: set() for name in ALLOC_INDEXES}
         self._lock = threading.RLock()
         self._watch = threading.Condition(self._lock)
         self.changelog = ChangeLog()
@@ -322,13 +333,29 @@ class StateStore(StateSnapshot):
     # -- snapshot & watch --
     def snapshot(self) -> StateSnapshot:
         with self._lock:
-            copied = {}
-            for name, table in self._t.items():
-                if name in ("_allocs_by_node", "_allocs_by_job"):
-                    copied[name] = {k: set(v) for k, v in table.items()}
-                else:
-                    copied[name] = dict(table)
+            copied = {name: dict(table) for name, table in self._t.items()}
+            self._disown_indexes_locked()
             return StateSnapshot(copied, self._ix, self.index)
+
+    def _disown_indexes_locked(self) -> None:
+        """Every id set of the alloc indexes may be shared from here on
+        (a snapshot took them, or a restore installed new tables)."""
+        for owned in self._owned.values():
+            owned.clear()
+
+    def _own_ids_locked(self, name: str, key) -> set:
+        """The id set under `key` of alloc index `name`, safe to mutate:
+        a set a snapshot may share is replaced by the store's own copy
+        first, so a write costs the ids under the keys it touches."""
+        table, owned = self._t[name], self._owned[name]
+        if key not in owned:
+            owned.add(key)
+            shared = table.get(key)
+            table[key] = set(shared or ())
+            if shared is not None:
+                _m.incr_counter("state.index.keys_copied")
+                _m.incr_counter("state.index.ids_copied", len(shared))
+        return table[key]
 
     def latest_index(self) -> int:
         with self._lock:
@@ -634,9 +661,9 @@ class StateStore(StateSnapshot):
         self._update_summary_with_alloc_locked(index, a, existing)
         self._t["allocs"][a.id] = a
         self.changelog.append(index, "alloc", a.id)
-        self._t["_allocs_by_node"].setdefault(a.node_id, set()).add(a.id)
-        self._t["_allocs_by_job"].setdefault(
-            (a.namespace, a.job_id), set()).add(a.id)
+        self._own_ids_locked("_allocs_by_node", a.node_id).add(a.id)
+        self._own_ids_locked("_allocs_by_job",
+                             (a.namespace, a.job_id)).add(a.id)
         # server-side terminal transitions (lost nodes, evictions) must
         # drop the alloc's service registrations too — the dead client
         # will never send the update that would
@@ -721,12 +748,10 @@ class StateStore(StateSnapshot):
         if a is None:
             return
         self.changelog.append(index or self.index, "alloc", alloc_id)
-        s = self._t["_allocs_by_node"].get(a.node_id)
-        if s:
-            s.discard(alloc_id)
-        s = self._t["_allocs_by_job"].get((a.namespace, a.job_id))
-        if s:
-            s.discard(alloc_id)
+        for name, key in (("_allocs_by_node", a.node_id),
+                          ("_allocs_by_job", (a.namespace, a.job_id))):
+            if self._t[name].get(key):
+                self._own_ids_locked(name, key).discard(alloc_id)
         # a reaped alloc releases its CSI claims even if it never
         # reported client-terminal (lost node, forced GC) — otherwise
         # the volume is stuck in-use forever

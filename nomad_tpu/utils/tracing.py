@@ -375,9 +375,11 @@ class FlightRecorder:
         """Mark every collection of CPython's garbage collector above
         the youngest generation as `gc.pause`: a `nomad.gc.pause` event
         of a running profiler trace, on the thread the collection
-        stopped, and a sample `span.gc.pause`.  With a hundred thousand
-        allocs in the store a full collection stops every thread for
-        about a second, inside whatever span happened to be open.
+        stopped, and a sample `span.gc.pause`; a full collection
+        (generation 2) is also a sample `span.gc.full`.  With a hundred
+        thousand allocs in the store a full collection stops every
+        thread for about a second, inside whatever span happened to be
+        open.
         Idempotent and process-wide (the collector is); a server calls
         it when it starts."""
         if not self._gc_watched:
@@ -404,15 +406,17 @@ class FlightRecorder:
             (ann, t0), self._gc_open = self._gc_open, None
             if ann is not None:
                 ann.__exit__(None, None, None)
-            self._gc_done.append(_time.monotonic() - t0)
+            self._gc_done.append((generation, _time.monotonic() - t0))
 
     def _drain_gc(self) -> None:
         while True:
             try:
-                dur_s = self._gc_done.popleft()
+                generation, dur_s = self._gc_done.popleft()
             except IndexError:
                 return
             global_metrics.add_sample("span.gc.pause", dur_s)
+            if generation == 2:
+                global_metrics.add_sample("span.gc.full", dur_s)
 
     def _record(self, sp: Span) -> None:
         row = {

@@ -126,3 +126,48 @@ def test_the_device_deployment_and_its_cell_are_there(cluster):
                  "wave_loop_roofline", "waves_per_solve",
                  "device_ms_per_wave", "device_idle_share"):
         assert cell["name"] in by_name("per_layer", name)["workloads"]
+
+
+@pytest.fixture(scope="module")
+def layers(cluster):
+    """`benchmark/layers.py` under its bare name, as `run.py` imports
+    it (the `cluster` fixture holds `benchmark/` on the path)."""
+    import layers
+    return layers
+
+
+#: ISSUE 31's three metrics: the layer each names, an `Observed` that
+#: holds what it reads and what it then says, and which key a program
+#: from before the change lacks
+STORE_METRICS = {
+    "plan_snapshot_ms": (
+        "Plan apply", {"samples": {"span.plan.snapshot": (0.9, 300)}},
+        3.0, None),
+    "index_ids_copied_per_eval": (
+        "Plan apply", {"counters": {"state.index.ids_copied": 48_000.0},
+                       "harness": {"evals_completed": 320}},
+        150.0, "counters"),
+    "gc_full_collections": (
+        "Interpreter", {"samples": {"span.gc.full": (2.4, 3)}},
+        3.0, "samples"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STORE_METRICS))
+def test_the_store_metrics_read_what_is_there_and_nothing_otherwise(
+        layers, name):
+    layer, fields, reads, absent_before = STORE_METRICS[name]
+    spec = layers.load_metric(name)
+    entry = by_name("per_layer", name)
+    assert spec["layer"] == entry["layer"] == layer
+    # a layer the table already had, under the same letters
+    assert layer in {m["layer"] for m in BENCH["per_layer"]
+                     if m["name"] not in STORE_METRICS}
+    assert entry["workloads"] == CELLS and entry["better"] == "lower"
+    assert layers.read_metric(name, layers.Observed(**fields)) \
+        == pytest.approx(reads)
+    # a program without the counter or the sample: nothing, no raise
+    assert layers.read_metric(name, layers.Observed()) is None
+    if absent_before:
+        fields = dict(fields, **{absent_before: {}})
+        assert layers.read_metric(name, layers.Observed(**fields)) is None

@@ -321,3 +321,28 @@ def test_collector_pauses_are_sampled_and_marked_without_a_lock():
     with global_tracer.layer("test.after_gc"):
         pass
     assert _grew(before, _samples(), "span.gc.pause")[0] >= 2
+
+
+@pytest.mark.parametrize("generation, full", [(1, 0), (2, 1)])
+def test_a_full_collection_is_also_sampled_as_gc_full(generation, full):
+    """`span.gc.full` counts the generation-2 collections among
+    `span.gc.pause`'s, so how many full collections a window held is a
+    reading (`gc_full_collections`).  The collector is held off around
+    the one collection asked for, so none of its own is counted."""
+    global_tracer.watch_gc()
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        with global_tracer.layer("test.drain_gc"):
+            pass
+        before = _samples()
+        gc.collect(generation)
+        with global_tracer.layer("test.after_gc"):
+            pass
+        after = _samples()
+    finally:
+        if was_enabled:
+            gc.enable()
+    assert _grew(before, after, "span.gc.pause")[0] == 1
+    count, seconds = _grew(before, after, "span.gc.full")
+    assert count == full and (seconds > 0) == bool(full)
