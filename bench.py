@@ -35,7 +35,6 @@ measured; placement-QUALITY is compared with a pack-to-capacity duel
 import json
 import math
 import os
-import statistics
 import subprocess
 import sys
 import time
@@ -93,8 +92,7 @@ def pct(sorted_ms, p):
 
 def latency_summary(latencies_s):
     """p50/p99 (ms) of a latency sample in seconds — the one latency
-    summary used by the closed-loop, latency-mode, and open-loop
-    phases."""
+    summary used by the closed-loop and latency-mode phases."""
     lat_ms = sorted(1000.0 * x for x in latencies_s)
     return {"p50_ms": round(pct(lat_ms, 0.5), 3),
             "p99_ms": round(pct(lat_ms, 0.99), 3)}
@@ -1487,1761 +1485,6 @@ def run_chaos(n_devices=8, n_regions=4, write_detail=True, seed=14):
     return out
 
 
-# ---------------- open-loop serving phase (ISSUE 6) -----------------
-
-def poisson_arrivals(rate, duration_s, rng):
-    """Memoryless open-loop arrivals: [(t_offset, namespace), ...]."""
-    t, out = 0.0, []
-    while True:
-        t += rng.expovariate(rate)
-        if t >= duration_s:
-            return out
-        out.append((t, "default"))
-
-
-def trace_arrivals(rate, duration_s, rng, n_tenants=6,
-                   mean_burst=8.0):
-    """Tesserae-shaped trace family (arxiv 2508.04953): DL-cluster
-    scheduler workloads are bursty and multi-tenant.  Per tenant, an
-    ON/OFF burst train — bursts arrive Poisson, each carrying a
-    lognormal-sized run of back-to-back evals — with one hot tenant
-    holding ~3x the share of the rest (the flapping tenant the
-    admission fairness buckets exist for).  Mean rate ~= `rate`."""
-    shares = [3.0] + [1.0] * (n_tenants - 1)
-    total = sum(shares)
-    out = []
-    for ti, share in enumerate(shares):
-        tenant_rate = rate * share / total
-        burst_rate = tenant_rate / mean_burst
-        t = 0.0
-        while True:
-            t += rng.expovariate(burst_rate)
-            if t >= duration_s:
-                break
-            n = max(1, int(rng.lognormvariate(1.7, 0.8)))
-            for k in range(n):
-                out.append((min(duration_s - 1e-6, t + k * 1e-4),
-                            f"tenant-{ti}"))
-    out.sort()
-    return out
-
-
-class _ServingHarness:
-    """The serving tier wired end to end for the bench: a real
-    EvalBroker + BlockedEvals + AdmissionController feeding the real
-    ResidentSolver — the production worker loop's shape (adaptive
-    dequeue sizing, bypass lane, pause-nack, shed/readmit) without the
-    scheduler/raft plane around it, so the measured number is the
-    broker -> solver serving path itself."""
-
-    def __init__(self, rs, template_ask, count, policy, slo_s,
-                 max_batch, fixed_batch, max_pending):
-        import threading
-
-        from nomad_tpu.server.blocked_evals import BlockedEvals
-        from nomad_tpu.server.eval_broker import EvalBroker
-        from nomad_tpu.server.serving import (AdmissionController,
-                                              BatchController,
-                                              EwmaSolveModel)
-        self.rs = rs
-        self.template_ask = template_ask
-        self.count = count
-        self.policy = policy            # "adaptive" | "fixed"
-        self.fixed_batch = fixed_batch
-        self.max_batch = max_batch
-        self.broker = EvalBroker(nack_delay_s=60.0)
-        self.broker.set_enabled(True)
-        self.blocked = BlockedEvals(self.broker)
-        self.blocked.set_enabled(True)
-        self.model = EwmaSolveModel()
-        self.controller = BatchController(self.model, slo_budget_s=slo_s,
-                                          max_batch=max_batch)
-        self.admission = AdmissionController(
-            max_pending=max_pending, protect_priority=80,
-            ns_rate=max(64.0, max_pending / 2.0),
-            ns_burst=max(128.0, float(max_pending)),
-            brownout_after_s=0.25)
-        self.arrival_t = {}             # eval id -> arrival perf_counter
-        self.readmitted = set()
-        self.warmup_ids = set()         # excluded from the percentiles
-        self.lat_s = []                 # direct-admitted completions
-        self.lat_express_s = []         # bypass-lane completions
-        self.completed = 0
-        self.offered = 0
-        self.batch_sizes = []
-        self.stop = threading.Event()
-        self._seq = 0
-
-    # ---- ingress (arrival thread)
-    def ingress(self, ev):
-        self.offered += 1
-        self.arrival_t[ev.id] = time.perf_counter()
-        if self.admission.offer(ev, self.broker.ready_count()):
-            self.broker.enqueue(ev)
-        else:
-            self.blocked.shed(ev)
-
-    # ---- the serving loop (worker analog)
-    def serve_loop(self):
-        broker = self.broker
-        while not self.stop.is_set():
-            if self.policy == "adaptive":
-                target = self.controller.target_batch(
-                    broker.ready_count(), broker.oldest_ready_age())
-            else:
-                target = self.fixed_batch
-            batch = broker.dequeue_batch(["service"], target, 0.002)
-            if not batch:
-                self._readmit()
-                continue
-            t0 = time.perf_counter()
-            for ev, tok in batch:
-                broker.pause_nack_timeout(ev.id, tok)
-            express = [(e, t) for e, t in batch if e.priority >= 80]
-            bulk = [(e, t) for e, t in batch if e.priority < 80]
-            for group in (express, bulk):
-                if group:
-                    self._solve([e for e, _ in group])
-            now = time.perf_counter()
-            for ev, tok in batch:
-                broker.ack(ev.id, tok)
-                t_arr = self.arrival_t.pop(ev.id, None)
-                if (t_arr is None or ev.id in self.readmitted
-                        or ev.id in self.warmup_ids):
-                    continue
-                if ev.priority >= 80:
-                    self.lat_express_s.append(now - t_arr)
-                else:
-                    self.lat_s.append(now - t_arr)
-            self.completed += len(batch)
-            self.batch_sizes.append(len(batch))
-            self.model.observe(len(batch), now - t0)
-            self._readmit()
-
-    def _solve(self, evs):
-        # every eval is one config-2-shaped placement ask; identical
-        # signatures merge to a single packed row with summed count
-        # (the columnar coalescing payoff), solved in ONE device call
-        asks = [self.template_ask] * len(evs)
-        masks, keys = self.rs.merge_asks(asks)
-        pb = self.rs.pack_batch(masks)
-        self._seq += 1
-        self.rs.solve_stream([pb], seeds=[self._seq])
-
-    def _readmit(self):
-        quota = self.admission.readmit_quota(
-            self.broker.ready_count(), batch=self.max_batch)
-        if quota > 0:
-            for ev in self.blocked.pop_shed(quota):
-                self.readmitted.add(ev.id)
-                self.broker.enqueue(ev)
-
-    # ---- accounting
-    def leftovers(self):
-        st = self.broker.stats()
-        return (st["total_ready"] + st["total_unacked"]
-                + st["total_waiting"] + st["total_blocked"]
-                + self.blocked.shed_count())
-
-
-def _run_open_loop_leg(rs, template_ask, count, policy, arrivals,
-                       duration_s, slo_s, max_batch, fixed_batch,
-                       max_pending, used0, warmup_s=0.5,
-                       express_every_s=0.0):
-    """Drive one (policy, arrival process) leg and return its record."""
-    import gc
-    import threading
-
-    from nomad_tpu.structs import Evaluation
-
-    gc.collect()          # a mid-leg GC hiccup lands straight in p99
-    rs.reset_usage(used0=used0)
-    h = _ServingHarness(rs, template_ask, count, policy, slo_s,
-                        max_batch, fixed_batch, max_pending)
-    loop = threading.Thread(target=h.serve_loop, daemon=True)
-    loop.start()
-    # bypass-lane probes (the config-1 interactive class) ride along at
-    # a fixed low rate when requested
-    if express_every_s:
-        express = [(t, "_express") for t in
-                   _frange(express_every_s, duration_s, express_every_s)]
-        arrivals = sorted(arrivals + express)
-    t_start = time.perf_counter()
-    i, n = 0, len(arrivals)
-    while i < n:
-        now = time.perf_counter() - t_start
-        while i < n and arrivals[i][0] <= now:
-            t_off, ns = arrivals[i]
-            i += 1
-            if ns == "_express":
-                ev = Evaluation(job_id=f"ol-x-{i}", priority=90)
-            else:
-                ev = Evaluation(job_id=f"ol-{i}", namespace=ns,
-                                priority=50)
-            if t_off < warmup_s:
-                # warmup window: served and counted for throughput, but
-                # excluded from the percentiles (the EWMA model trains
-                # during it)
-                h.warmup_ids.add(ev.id)
-            h.ingress(ev)
-        if i < n:
-            time.sleep(min(0.001, max(0.0, arrivals[i][0]
-                                      - (time.perf_counter() - t_start))))
-    # grace drain: overload legs stay bounded by admission, so this
-    # terminates fast either way
-    t_grace = time.perf_counter()
-    while (time.perf_counter() - t_grace < 2.0
-           and h.broker.stats()["total_ready"] > 0):
-        time.sleep(0.01)
-    h.stop.set()
-    loop.join(timeout=5.0)
-    elapsed = time.perf_counter() - t_start
-    admitted = h.admission.stats()
-    shed_left = h.blocked.shed_count()
-    lost = h.offered - h.completed - h.leftovers()
-    lat = latency_summary(h.lat_s)
-    bs = sorted(h.batch_sizes)
-    return {
-        "policy": policy,
-        "offered": h.offered,
-        "completed": h.completed,
-        "elapsed_s": round(elapsed, 3),
-        "completed_per_sec": round(h.completed / max(elapsed, 1e-9), 1),
-        "offered_rate_per_sec": round(h.offered / max(duration_s, 1e-9),
-                                      1),
-        "p50_ms": lat["p50_ms"], "p99_ms": lat["p99_ms"],
-        "interactive": (latency_summary(h.lat_express_s)
-                        if h.lat_express_s else None),
-        "shed": admitted["shed"],
-        "shed_remaining": shed_left,
-        "readmitted": len(h.readmitted),
-        "brownouts_entered": admitted["brownouts_entered"],
-        "lost": lost,
-        "batch_size_p50": pct([float(x) for x in bs], 0.5),
-        "batch_size_p99": pct([float(x) for x in bs], 0.99),
-    }
-
-
-def _frange(start, stop, step):
-    out = []
-    t = start
-    while t < stop:
-        out.append(t)
-        t += step
-    return out
-
-
-def run_open_loop(n_nodes=2048, count=4, max_batch=128, fixed_batch=8,
-                  slo_ms=50.0, duration_s=4.0, resident=5000,
-                  loads=(0.5, 0.75, 1.0, 1.5, 2.0), seed=7,
-                  write_detail=True):
-    """Open-loop serving-tier phase (ISSUE 6 acceptance).
-
-    Measures the broker -> resident-solver serving path under
-    Poisson/trace-driven arrivals at load multiples of each policy's
-    MEASURED capacity (saturation probe), reporting sustained evals/sec
-    at p99 < slo_ms plus the saturation/brownout curve:
-
-      * adaptive: BatchController-sized dequeues (SLO-budget close
-        rule, EWMA solve model, drain mode) + admission control
-      * fixed:    the pre-serving-tier baseline — fixed-size dequeue
-        (`server.batch_size` analog) with the same admission bound
-
-    The acceptance figure `adaptive_vs_fixed_sustained` compares the
-    highest sustained throughput each policy achieves while holding
-    p99 < slo_ms across its own load sweep.  The per-dispatch overhead
-    the adaptive batcher amortizes exists on every backend."""
-    import random
-
-    from nomad_tpu.solver.resident import ResidentSolver
-    from nomad_tpu.solver.tensorize import Tensorizer
-
-    rng = random.Random(seed)
-    slo_s = slo_ms / 1000.0
-    nodes = make_nodes(n_nodes)
-    probe_job = make_job(2, 0, count)
-    template_ask = asks_for(probe_job)[0]
-    gp_need = len({Tensorizer.ask_signature(a)
-                   for a in asks_for(probe_job)})
-    t0 = time.perf_counter()
-    rs = ResidentSolver(nodes, asks_for(probe_job),
-                        gp=1 << max(0, (gp_need - 1).bit_length()),
-                        kp=1 << max(0, (count * max_batch - 1)
-                                    .bit_length()),
-                        max_waves=18)
-    used0 = resident_used0(rs.template, n_nodes, resident)
-    rs.reset_usage(used0=used0)
-    # warm every pow2 group_count_hint bucket the sweep can hit: batch
-    # sizes vary, padded shapes do not — no compiles in the timed legs
-    import dataclasses
-    k = 1
-    while k <= max_batch:
-        asks = [dataclasses.replace(template_ask, count=count)] * k
-        masks, keys = rs.merge_asks(asks)
-        rs.solve_stream([rs.pack_batch(masks)], seeds=[1])
-        k <<= 1
-    rs.reset_usage(used0=used0)
-    startup_s = time.perf_counter() - t0
-
-    # ---- capacity probe per policy: saturating arrivals, completed/s.
-    # Peak drain throughput is a rho=1 operating point — open-loop
-    # arrivals AT it queue without bound by Little's law — so the
-    # sweep's "1.0x capacity" is 0.9x the measured peak, the classic
-    # sustainable-utilization derating.
-    def capacity(policy):
-        import gc
-        rate = 60000.0
-        peaks = []
-        for trial in range(3):
-            gc.collect()
-            probe = poisson_arrivals(rate, 1.5,
-                                     random.Random(seed + 1 + trial))
-            rec = _run_open_loop_leg(
-                rs, template_ask, count, policy, probe, 1.5, slo_s,
-                max_batch, fixed_batch, max_pending=1 << 30,
-                used0=used0, warmup_s=0.25)
-            peaks.append(rec["completed_per_sec"])
-        return round(0.9 * statistics.median(peaks), 1)
-
-    cap = {p: capacity(p) for p in ("adaptive", "fixed")}
-    sys.stderr.write(f"open-loop capacity: adaptive={cap['adaptive']}"
-                     f" fixed={cap['fixed']} evals/s\n")
-
-    out = {"phase": "open_loop", "n_nodes": n_nodes, "count": count,
-           "slo_ms": slo_ms, "max_batch": max_batch,
-           "fixed_batch": fixed_batch, "duration_s": duration_s,
-           "startup_s": round(startup_s, 2),
-           "capacity_evals_per_sec": cap, "sweep": [], "trace": None}
-
-    sustained = {}
-    for policy in ("adaptive", "fixed"):
-        # bounded ingress worth ~2 SLO budgets of service at capacity:
-        # the queue the admission controller allows is the p99 the
-        # admitted traffic pays at saturation
-        max_pending = max(64, int(cap[policy] * slo_s * 2))
-        best = 0.0
-        for load in loads:
-            rate = cap[policy] * load
-            arrivals = poisson_arrivals(rate, duration_s,
-                                        random.Random(seed + 10))
-            rec = _run_open_loop_leg(
-                rs, template_ask, count, policy, arrivals, duration_s,
-                slo_s, max_batch, fixed_batch, max_pending, used0,
-                express_every_s=0.05)
-            rec.update({"load": load, "arrival": "poisson",
-                        "rate_per_sec": round(rate, 1),
-                        "max_pending": max_pending})
-            out["sweep"].append(rec)
-            if rec["p99_ms"] < slo_ms and rec["lost"] == 0:
-                best = max(best, rec["completed_per_sec"])
-            sys.stderr.write(
-                f"open-loop {policy} load={load}: "
-                f"{rec['completed_per_sec']}/s p99={rec['p99_ms']}ms "
-                f"shed={rec['shed']} lost={rec['lost']}\n")
-        sustained[policy] = best
-
-    # ---- Tesserae-shaped trace leg at 1.0x (adaptive): bursty
-    # multi-tenant arrivals exercising the fairness buckets
-    trace = trace_arrivals(cap["adaptive"], duration_s,
-                           random.Random(seed + 20))
-    max_pending = max(64, int(cap["adaptive"] * slo_s * 2))
-    rec = _run_open_loop_leg(
-        rs, template_ask, count, "adaptive", trace, duration_s, slo_s,
-        max_batch, fixed_batch, max_pending, used0,
-        express_every_s=0.05)
-    rec.update({"load": 1.0, "arrival": "tesserae-trace",
-                "max_pending": max_pending})
-    out["trace"] = rec
-
-    ratio = (sustained["adaptive"] / sustained["fixed"]
-             if sustained["fixed"] else float("inf"))
-    two_x = [r for r in out["sweep"]
-             if r["policy"] == "adaptive" and r["load"] == 2.0]
-    out["sustained_at_slo_evals_per_sec"] = sustained
-    out["adaptive_vs_fixed_sustained"] = round(ratio, 2)
-    out["acceptance"] = {
-        "adaptive_ge_1_3x_fixed_at_slo": ratio >= 1.3,
-        "overload_2x_bounded_p99_ms": (two_x[0]["p99_ms"]
-                                       if two_x else None),
-        "overload_2x_shed": two_x[0]["shed"] if two_x else None,
-        "overload_2x_zero_lost": (two_x[0]["lost"] == 0
-                                  if two_x else None),
-        "overload_2x_brownouts": (two_x[0]["brownouts_entered"]
-                                  if two_x else None),
-    }
-    out["ok"] = bool(out["acceptance"]["adaptive_ge_1_3x_fixed_at_slo"]
-                     and out["acceptance"]["overload_2x_zero_lost"])
-    if write_detail:
-        # merge into BENCH_DETAIL.json preserving the other phases
-        path = os.path.join(REPO, "BENCH_DETAIL.json")
-        try:
-            with open(path) as f:
-                detail = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            detail = {}
-        detail["open_loop"] = out
-        with open(path, "w") as f:
-            json.dump(detail, f, indent=1)
-    return out
-
-
-# ---------------- scale-out serving phase (ISSUE 17) ----------------
-
-#: PR 17's recorded BENCH_DETAIL.json scaleout best (4x4 fused,
-#: serialized rounds) — the fixed reference the ISSUE 19 ">= 3x"
-#: acceptance names.  The regenerated detail keeps a same-machine
-#: serialized reference leg alongside, so both ratios stay honest.
-PR17_RECORDED_BEST = 3768.0
-
-#: PR 19's recorded BENCH_DETAIL.json scaleout best (2x2 pipelined
-#: rounds): throughput and the leader-serial `device` stage wall over
-#: the 2s measured window.  ISSUE 20's lane acceptance binds on the
-#: recorded device stage — the lane sweep's best leg must cut it by
-#: >= 30% (serial scan depth B -> B/L shows up exactly there).
-PR19_RECORDED_BEST = 24409.7
-PR19_RECORDED_DEVICE_S = 1.721
-#: the same leg normalized per eval: device stage seconds over the 2s
-#: window's completed count (24409.7/s x 2s) — the lane acceptance
-#: compares device time PER EVAL, which survives window-length and
-#: machine-speed drift where the raw stage wall does not
-PR19_RECORDED_DEVICE_US_PER_EVAL = round(
-    PR19_RECORDED_DEVICE_S / (PR19_RECORDED_BEST * 2.0) * 1e6, 2)
-
-class _ScaleoutHarness:
-    """N worker threads on an S-shard broker feeding the single
-    resident solver through the REAL SolveCoordinator: the production
-    scale-out shape (home-shard dequeue + work stealing, cross-worker
-    fusion, one pinned device world) with the scheduler/raft plane
-    stripped away, so the measured number is the sharded broker ->
-    coordinator -> fused-solve serving path itself."""
-
-    def __init__(self, rs, template_ask, count, n_workers, n_shards,
-                 fuse, slo_s, max_batch, max_pending, pipelined=True,
-                 lane_spec=None):
-        import threading
-
-        from nomad_tpu.scheduler.fleet import SolveCoordinator
-        from nomad_tpu.server.blocked_evals import BlockedEvals
-        from nomad_tpu.server.eval_broker import EvalBroker
-        from nomad_tpu.server.serving import (AdmissionController,
-                                              BatchController,
-                                              EwmaSolveModel)
-        self.rs = rs
-        self.template_ask = template_ask
-        self.count = count
-        self.n_workers = n_workers
-        self.max_batch = max_batch
-        self.broker = EvalBroker(nack_delay_s=60.0, shards=n_shards)
-        self.broker.set_enabled(True)
-        self.blocked = BlockedEvals(self.broker)
-        self.blocked.set_enabled(True)
-        self.model = EwmaSolveModel()
-        self.controller = BatchController(self.model, slo_budget_s=slo_s,
-                                          max_batch=max_batch)
-        self.admission = AdmissionController(
-            max_pending=max_pending, protect_priority=80,
-            ns_rate=1e9, ns_burst=1e9, brownout_after_s=0.25)
-        self.coordinator = None
-        #: lane mode (ISSUE 20): each pipelined round dispatches up to
-        #: `round_b` member batches as ONE chunked scan-of-vmap call
-        #: (`solve_stream_async(..., lanes=L)`), padding ragged rounds
-        #: with zero-placement batches so every leg compiles exactly
-        #: one (lanes, B) kernel variant.  lane_spec keys:
-        #:   lanes      fixed width L (ignored when controller set)
-        #:   controller LaneWidthController -> adaptive width per round
-        #:   families   N dc-pinned family jobs cycled over lane slots
-        #:              (conflict-aware ordering via form_lanes)
-        #:   round_b    member batches per lane call (default: lanes)
-        self.lane_spec = dict(lane_spec) if lane_spec else None
-        if self.lane_spec is not None:
-            self.lane_ctrl = self.lane_spec.get("controller")
-            self.lane_width = (self.lane_ctrl.width if self.lane_ctrl
-                               else max(1, int(self.lane_spec["lanes"])))
-            self.lane_round_b = int(
-                self.lane_spec.get("round_b", 0)) or max(
-                self.lane_width,
-                self.lane_ctrl.max_width if self.lane_ctrl else 0)
-            self.lane_families = int(self.lane_spec.get("families", 0))
-            self._fam_rot = 0
-            self._lane_pb = {}       # (slot_kind, n) -> PackedBatch
-            self._lane_pad = {}      # slot -> zero-placement pad batch
-            self.lane_rounds = 0
-            self.lane_calls = 0
-            self.lane_bounced = 0
-            self.lane_committed = 0
-            self.lane_width_hist = []
-        #: pipelined legs: the coordinator finish phase owns ack +
-        #: latency accounting (the drain leader releases submitters
-        #: only after fetch); serialized legs ack in the worker loop
-        self._coord_acks = False
-        #: pipelined legs use the ISSUE 19 batched broker ops; the
-        #: pr17 reference leg keeps PR 17's per-eval pause/ack calls so
-        #: the A/B measures the whole serving-path delta
-        self.batched_ops = bool(pipelined)
-        #: worker back-off bound: stop dequeueing once this many
-        #: submissions are queued behind the in-flight round.  Lane
-        #: rounds fuse `round_b` member batches, so the backlog must
-        #: hold a whole round's worth before dequeueing pauses —
-        #: backing off at 1 would starve lane rounds down to one lane
-        self._pending_bound = (self.lane_round_b
-                               if self.lane_spec is not None else 1)
-        if fuse and n_workers > 1:
-            if pipelined:
-                fused_cap = max_batch * (self.lane_round_b
-                                         if self.lane_spec is not None
-                                         else 1)
-                self.coordinator = SolveCoordinator(
-                    None, max_fused=fused_cap,
-                    dispatch_fn=(self._dispatch_lane_round
-                                 if self.lane_spec is not None
-                                 else self._dispatch_round),
-                    finish_fn=self._finish_round)
-                self._coord_acks = True
-            else:
-                # PR-17 shape: fused but serialized end to end — the
-                # same-machine reference the pipelined legs are
-                # measured against
-                self.coordinator = SolveCoordinator(
-                    None, max_fused=max_batch,
-                    solve_fn=lambda _srv, _w, batch: self._solve(
-                        [e for e, _t in batch]))
-        self.arrival_t = {}
-        self.readmitted = set()         # excluded from the percentiles
-        self.lat_s = []
-        self.completed = 0
-        self.offered = 0
-        self.device_busy_s = 0.0
-        self.device_waves = 0
-        self.solve_calls = 0
-        #: leader-serial stage totals (ISSUE 19): pack/dispatch/device/
-        #: fetch/apply over the measured window.  `fetch` is the wall
-        #: blocked on the device result and OVERLAPS `device` (the
-        #: union-interval accounting) — the largest-stage comparison
-        #: excludes it.
-        self.stages = {k: 0.0 for k in
-                       ("pack", "dispatch", "device", "fetch", "apply")}
-        #: host->device bytes each round's dispatch actually shipped
-        #: (ISSUE 20 satellite: the staging-buffer + stream-stack-cache
-        #: work should drive steady-state rounds to ~0)
-        self.bytes_shipped = 0
-        self._prev_fetch_done = 0.0
-        #: pipelined-path packed-batch memo by chunk size: the template
-        #: asks carry no per-eval state, so every round's chunk packs to
-        #: identical tensors — the `pack_batch_cached` steady-state
-        #: idiom, which also keeps the dispatch from re-shipping fresh
-        #: host arrays to the device each round
-        self._pb_cache = {}
-        self._solve_lock = threading.Lock()
-        self._lat_lock = threading.Lock()
-        self.stop = threading.Event()
-        self._seq = 0
-
-    def reset_window(self):
-        """Drop warmup accounting; the measured window starts now."""
-        with self._lat_lock:
-            self.lat_s.clear()
-            self.completed = 0
-        self.device_busy_s = 0.0
-        self.device_waves = 0
-        self.solve_calls = 0
-        self.stages = {k: 0.0 for k in self.stages}
-        self.bytes_shipped = 0
-        if self.lane_spec is not None:
-            self.lane_rounds = 0
-            self.lane_calls = 0
-            self.lane_bounced = 0
-            self.lane_committed = 0
-            self.lane_width_hist = []
-
-    def ingress(self, ev):
-        self.offered += 1
-        self.arrival_t[ev.id] = time.perf_counter()
-        if self.admission.offer(ev, self.broker.ready_count()):
-            self.broker.enqueue(ev)
-            return True
-        self.blocked.shed(ev)
-        return False
-
-    def ingress_burst(self, evs):
-        """Admit a burst with one ready-count probe and one bulk
-        enqueue; returns the number admitted."""
-        now = time.perf_counter()
-        ready = self.broker.ready_count()
-        admitted = []
-        for ev in evs:
-            self.offered += 1
-            self.arrival_t[ev.id] = now
-            if self.admission.offer(ev, ready):
-                admitted.append(ev)
-            else:
-                self.blocked.shed(ev)
-        if admitted:
-            self.broker.enqueue_batch(admitted)
-        return len(admitted)
-
-    def worker_loop(self, index):
-        broker = self.broker
-        # batch hold-back bound: wait for a full batch only while the
-        # oldest ready eval still has most of its SLO budget left
-        hold_age_s = self.controller.slo_budget_s * 0.25
-        while not self.stop.is_set():
-            if self._coord_acks and self.coordinator is not None \
-                    and self.coordinator.pending() >= self._pending_bound:
-                # pending bound (fire-and-forget legs): with a whole
-                # round already queued behind the in-flight one the
-                # device cannot go idle before this worker's next pass,
-                # so dequeueing MORE now only fragments the backlog into
-                # partial rounds and stretches p99
-                self.stop.wait(0.0002)
-                continue
-            ready = broker.ready_count()
-            if self.batched_ops and index >= 2 \
-                    and ready < self.max_batch * index:
-                # staggered engagement (pipelined legs): workers 0 and 1
-                # always run — one leads the drain while the other
-                # dequeues and submits the NEXT round, which is the
-                # cross-round overlap the pipeline depends on.  Worker
-                # k >= 2 wakes only once k full batches are backlogged:
-                # extra dequeue threads split one batch N ways, shrinking
-                # every fused round and spending GIL slices on dequeue
-                # parallelism the single drain leader cannot use.
-                self.stop.wait(0.001)
-                self._readmit()
-                continue
-            target = self.controller.target_batch(
-                ready, broker.oldest_ready_age())
-            if self.batched_ops and ready and ready < self.max_batch \
-                    and broker.oldest_ready_age() < hold_age_s:
-                # hold-back (pipelined legs): a short wait lets the
-                # feeder fill a whole max_batch — fixed-size rounds
-                # amortize the per-dispatch kernel cost and keep the
-                # packed-batch memo hot, and the age bound keeps the
-                # wait invisible to p99
-                self.stop.wait(0.0002)
-                continue
-            batch = broker.dequeue_batch(["service"], target, 0.002,
-                                         home=index)
-            if not batch:
-                self._readmit()
-                continue
-            t0 = time.perf_counter()
-            if self.batched_ops:
-                broker.pause_nack_batch(
-                    [(ev.id, tok) for ev, tok in batch])
-            else:
-                for ev, tok in batch:
-                    broker.pause_nack_timeout(ev.id, tok)
-            if self.coordinator is not None:
-                if self._coord_acks:
-                    # fire-and-forget fan-back: the round's finish_fn
-                    # acks and records latency, so the submitter goes
-                    # straight back to dequeueing — a blocked submitter
-                    # would leave the device idle for a whole dequeue
-                    self.coordinator.submit_nowait(index, batch)
-                else:
-                    self.coordinator.submit(index, batch)
-                    self._finalize(batch, t0)
-            else:
-                self._solve([e for e, _t in batch])
-                self._finalize(batch, t0)
-            self._readmit()
-
-    def _finalize(self, batch, t0):
-        """Serialized-path completion: batched ack, latency fan-back,
-        end-to-end wall into the sizing model (device ~= wall when
-        nothing overlaps)."""
-        now = time.perf_counter()
-        if self.batched_ops:
-            self.broker.ack_batch([(ev.id, tok) for ev, tok in batch])
-        else:
-            for ev, tok in batch:
-                self.broker.ack(ev.id, tok)
-        lats = []
-        for ev, _tok in batch:
-            t_arr = self.arrival_t.pop(ev.id, None)
-            if t_arr is not None and ev.id not in self.readmitted:
-                lats.append(now - t_arr)
-        with self._lat_lock:
-            self.lat_s.extend(lats)
-            self.completed += len(batch)
-        self.model.observe(len(batch), now - t0)
-
-    def _readmit(self):
-        # drain capacity back to the shed lane — also the hook that
-        # clears brownout once the queue is under the low watermark
-        quota = self.admission.readmit_quota(
-            self.broker.ready_count(), batch=self.max_batch)
-        if quota > 0:
-            for ev in self.blocked.pop_shed(quota):
-                self.readmitted.add(ev.id)
-                self.broker.enqueue(ev)
-
-    def _solve(self, evs):
-        # one fused device call for however many evals the coordinator
-        # coalesced; identical ask signatures merge to one packed row.
-        # The coordinator's round can overshoot max_fused by one
-        # member's batch, so chunk to the packed capacity — still a
-        # single stream dispatch (jobs are unique per stream here)
-        with self._solve_lock:
-            for lo in range(0, len(evs), self.max_batch):
-                n = min(self.max_batch, len(evs) - lo)
-                masks, _keys = self.rs.merge_asks(
-                    [self.template_ask] * n)
-                pb = self.rs.pack_batch(masks)
-                self._seq += 1
-                # one stream per chunk: every chunk shares the template
-                # job identity, and a job may appear in at most one
-                # batch per stream
-                self.rs.solve_stream([pb], seeds=[self._seq])
-                self.device_busy_s += self.rs.last_solve_stats["wall_s"]
-                waves = getattr(self.rs, "last_waves", None)
-                if waves is not None:
-                    import numpy as _np
-                    self.device_waves += int(_np.asarray(waves).sum())
-                self.solve_calls += 1
-
-    # ----------------------- pipelined round (ISSUE 19) -----------------
-    # The coordinator's drain leader calls _dispatch_round for batch b+1
-    # BEFORE _finish_round for batch b: the device solves b while the
-    # leader packs b+1.  Both run on the single leader thread, so no
-    # lock is held across the blocking fetch (the LOCK305 shape).
-
-    def _dispatch_round(self, _server, _worker, batch):
-        rnd = _PipeRound(list(batch))
-        rnd.t_dispatch_start = time.perf_counter()
-        evs = rnd.batch
-        for lo in range(0, len(evs), self.max_batch):
-            n = min(self.max_batch, len(evs) - lo)
-            t0 = time.perf_counter()
-            pb = self._pb_cache.get(n)
-            if pb is None:
-                masks, _keys = self.rs.merge_asks(
-                    [self.template_ask] * n)
-                pb = self.rs.pack_batch(masks)
-                self._pb_cache[n] = pb
-            t1 = time.perf_counter()
-            self._seq += 1
-            rnd.handles.append(
-                self.rs.solve_stream_async([pb], seeds=[self._seq]))
-            rnd.waves.append(getattr(self.rs, "last_waves", None))
-            self.bytes_shipped += getattr(self.rs,
-                                          "last_dispatch_bytes", 0) or 0
-            t2 = time.perf_counter()
-            self.stages["pack"] += t1 - t0
-            self.stages["dispatch"] += t2 - t1
-        rnd.t_dispatched = time.perf_counter()
-        return rnd
-
-    # ----------------------- lane round (ISSUE 20) ----------------------
-    # One fused solve call carries up to round_b member batches through
-    # the chunked scan-of-vmap: serial depth B -> B/L.  Ragged rounds
-    # are padded with zero-placement batches so every leg runs exactly
-    # one compiled (lanes, B) kernel variant — a mid-window retrace
-    # would eat the whole measured window.
-
-    def _lane_member_pb(self, slot, n):
-        """Member batch for lane `slot` holding `n` fused evals.  Each
-        slot carries a distinct synthetic job identity (a job may
-        appear in at most one batch per stream); the family variant
-        additionally pins each slot's job to one datacenter, which is
-        the conflict footprint form_lanes separates on."""
-        if self.lane_families:
-            f = slot % self.lane_families
-            key = ("fam", f, n)
-            pb = self._lane_pb.get(key)
-            if pb is None:
-                job = make_job(2, 9000 + f, self.count)
-                job.id = f"lane-fam-{f}"
-                job.name = job.id
-                job.datacenters = [f"dc{f % 4}"]
-                masks, _keys = self.rs.merge_asks(
-                    [asks_for(job)[0]] * n)
-                pb = self.rs.pack_batch(masks, job_keys={("fam", f)})
-                self._lane_pb[key] = pb
-            return pb, (f"dc{f % 4}",)
-        key = ("lane", slot, n)
-        pb = self._lane_pb.get(key)
-        if pb is None:
-            masks, _keys = self.rs.merge_asks([self.template_ask] * n)
-            pb = self.rs.pack_batch(masks, job_keys={("lane", slot)})
-            self._lane_pb[key] = pb
-        # template members share every node as footprint; the former
-        # has nothing to separate, so footprint is the slot itself
-        return pb, (slot,)
-
-    def _lane_pad_pb(self, i, like):
-        """Zero-placement pad batch: same tensors (same compiled
-        shape), n_place=0 so the kernel commits nothing for it."""
-        pad = self._lane_pad.get(i)
-        if pad is None:
-            import copy as _copy
-            pad = _copy.copy(like)
-            pad.n_place = 0
-            pad.job_keys = {("pad", i)}
-            self._lane_pad[i] = pad
-        return pad
-
-    def _dispatch_serial_tail(self, rnd, n_evs):
-        """Serial B=1 dispatch for a lane round's ragged remainder:
-        any eval count's pow2 `group_count_hint` bucket is already
-        compiled by the startup warm loop, so the tail never retraces
-        — only FULL max_batch member batches ride the lane call (a
-        ragged member would shift the static hint and retrace
-        mid-window)."""
-        t0 = time.perf_counter()
-        pb = self._pb_cache.get(n_evs)
-        if pb is None:
-            masks, _keys = self.rs.merge_asks(
-                [self.template_ask] * n_evs)
-            pb = self.rs.pack_batch(masks)
-            self._pb_cache[n_evs] = pb
-        t1 = time.perf_counter()
-        self._seq += 1
-        rnd.handles.append(
-            self.rs.solve_stream_async([pb], seeds=[self._seq]))
-        rnd.waves.append(getattr(self.rs, "last_waves", None))
-        self.bytes_shipped += getattr(self.rs,
-                                      "last_dispatch_bytes", 0) or 0
-        t2 = time.perf_counter()
-        self.stages["pack"] += t1 - t0
-        self.stages["dispatch"] += t2 - t1
-
-    def _dispatch_lane_round(self, _server, _worker, batch):
-        from nomad_tpu.scheduler.fleet import form_lanes
-        rnd = _PipeRound(list(batch))
-        rnd.t_dispatch_start = time.perf_counter()
-        evs = rnd.batch
-        lanes = self.lane_width
-        n_full = len(evs) // self.max_batch
-        if lanes <= 1 or n_full < 2:
-            # too few full member batches for a chunk: serial rounds
-            # (also the adaptive controller's width-1 regime)
-            for lo in range(0, len(evs), self.max_batch):
-                self._dispatch_serial_tail(
-                    rnd, min(self.max_batch, len(evs) - lo))
-            self.lane_rounds += 1
-            rnd.t_dispatched = time.perf_counter()
-            return rnd
-        t0 = time.perf_counter()
-        # adaptive legs dispatch B=width calls (every pow2 width's
-        # (L, B=L) variant is warmed); fixed legs dispatch B=round_b
-        # (the families leg runs round_b=2*width -> a 2-chunk scan)
-        call_b = lanes if self.lane_ctrl is not None \
-            else self.lane_round_b
-        members = []
-        for slot in range(n_full):
-            pb, footprint = self._lane_member_pb(
-                (self._fam_rot + slot) if self.lane_families else slot,
-                self.max_batch)
-            members.append((pb, footprint))
-        if self.lane_families:
-            self._fam_rot = (self._fam_rot + len(members)) \
-                % self.lane_families
-            # conflict-aware chunk formation: order members so each
-            # consecutive `lanes`-block holds disjoint dc footprints
-            members = form_lanes(members, lanes,
-                                 key_fn=lambda m: m[1])
-        t1 = time.perf_counter()
-        self.stages["pack"] += t1 - t0
-        for lo in range(0, len(members), call_b):
-            group = [pb for pb, _fp in members[lo:lo + call_b]]
-            while len(group) < call_b:
-                group.append(self._lane_pad_pb(len(group), group[-1]))
-            td = time.perf_counter()
-            seeds = []
-            for _ in group:
-                self._seq += 1
-                seeds.append(self._seq)
-            rnd.handles.append(self.rs.solve_stream_async(
-                group, seeds=seeds, lanes=lanes))
-            rnd.waves.append(getattr(self.rs, "last_waves", None))
-            raw = getattr(self.rs, "last_lane_counters", None)
-            if raw is not None:
-                # device scalars captured AT dispatch (the attribute is
-                # per-call state; the next dispatch overwrites it) and
-                # fetched in the finish phase after the solve completes
-                rnd.lane_raw.append(raw)
-            self.bytes_shipped += getattr(self.rs,
-                                          "last_dispatch_bytes", 0) or 0
-            self.stages["dispatch"] += time.perf_counter() - td
-            self.lane_calls += 1
-        rem = len(evs) - n_full * self.max_batch
-        if rem:
-            self._dispatch_serial_tail(rnd, rem)
-        self.lane_rounds += 1
-        rnd.t_dispatched = time.perf_counter()
-        return rnd
-
-    def _finish_round(self, _server, _worker, rnd):
-        import numpy as _np
-        t0 = time.perf_counter()
-        for h in rnd.handles:
-            self.rs.finish_stream(h)
-        now = time.perf_counter()
-        self.stages["fetch"] += now - t0
-        # device-pipeline busy as the union of in-order intervals
-        # [dispatch start, fetch done] — enqueue + h2d + kernel, the
-        # same span PR-17's synchronous solve wall covered — with each
-        # round's interval clipped to start after the previous round's
-        # fetch completed, so overlapped rounds are never double-counted
-        device = max(0.0, now - max(rnd.t_dispatch_start,
-                                    self._prev_fetch_done))
-        self._prev_fetch_done = now
-        self.device_busy_s += device
-        self.stages["device"] += device
-        self.solve_calls += len(rnd.handles)
-        for w in rnd.waves:
-            if w is not None:
-                self.device_waves += int(_np.asarray(w).sum())
-        # sizing-model feed: DEVICE time, not round wall — the round
-        # wall double-counts the neighbor round's in-flight solve (see
-        # ServingTier.note_device_solve)
-        self.model.observe(len(rnd.batch), device)
-        if self.lane_spec is not None and rnd.lane_raw:
-            b = c = 0
-            for raw in rnd.lane_raw:
-                # per-member device arrays; the sum syncs AFTER the
-                # round's fetch, so this is a host add, not a stall
-                b += int(_np.asarray(raw["bounced"]).sum())
-                c += int(_np.asarray(raw["committed"]).sum())
-            self.lane_bounced += b
-            self.lane_committed += c
-            if self.lane_ctrl is not None:
-                rate = b / max(b + c, 1)
-                # device_frac: is the device stage still dominant over
-                # the leader-serial breakdown?  (fetch overlaps device,
-                # excluded — same rule as largest_stage)
-                host = sum(v for k, v in self.stages.items()
-                           if k not in ("device", "fetch"))
-                frac = self.stages["device"] \
-                    / max(self.stages["device"] + host, 1e-9)
-                w = self.lane_ctrl.record(rate, frac)
-                if w != self.lane_width:
-                    self.lane_width = w
-                self.lane_width_hist.append(w)
-        t1 = time.perf_counter()
-        self.broker.ack_batch([(ev.id, tok) for ev, tok in rnd.batch])
-        lats = []
-        for ev, _tok in rnd.batch:
-            t_arr = self.arrival_t.pop(ev.id, None)
-            if t_arr is not None and ev.id not in self.readmitted:
-                lats.append(now - t_arr)
-        with self._lat_lock:
-            self.lat_s.extend(lats)
-            self.completed += len(rnd.batch)
-        self.stages["apply"] += time.perf_counter() - t1
-
-
-class _PipeRound:
-    """One dispatched-not-fetched fused round in the bench harness."""
-    __slots__ = ("batch", "handles", "waves", "lane_raw",
-                 "t_dispatch_start", "t_dispatched")
-
-    def __init__(self, batch):
-        self.batch = batch       # [(Evaluation, token)]
-        self.handles = []        # device-side packed results
-        self.waves = []          # per-chunk device wave counters
-        self.lane_raw = []       # per-call lane counters (device
-        #                          scalars; fetched in finish)
-        self.t_dispatch_start = 0.0
-        self.t_dispatched = 0.0
-
-
-def _run_scaleout_leg(rs, template_ask, count, n_workers, n_shards,
-                      fuse, duration_s, slo_s, max_batch, max_pending,
-                      used0, warmup_s=0.4, pipelined=True,
-                      lane_spec=None):
-    """Saturate one (workers, shards, fuse) config and return its
-    record: the feeder offers as fast as admission allows, so the
-    completed rate IS the config's capacity."""
-    import gc
-    import threading
-
-    from nomad_tpu.structs import Evaluation
-    from nomad_tpu.utils.metrics import global_metrics as _gm
-
-    gc.collect()
-    # collector off for the measured window (re-enabled after the
-    # join): a mid-window gen2 pass stops every thread for tens of ms,
-    # which lands on every queued eval's latency at once — the classic
-    # phantom p99 spike.  The harness allocates no cycles, so garbage
-    # cannot accumulate meaningfully in a few seconds.  Applies to
-    # every leg equally.
-    gc.disable()
-    rs.reset_usage(used0=used0)
-    # GIL hygiene for the measured window: the default 5ms switch
-    # interval lets the CPU-bound feeder hog whole 5ms slices while the
-    # drain leader's dispatch waits; a finer interval is the standard
-    # setting for latency-sensitive mixed IO/CPU thread pools.  Applies
-    # to every leg equally.
-    old_switch = sys.getswitchinterval()
-    sys.setswitchinterval(0.0005)
-    h = _ScaleoutHarness(rs, template_ask, count, n_workers, n_shards,
-                         fuse, slo_s, max_batch, max_pending,
-                         pipelined=pipelined, lane_spec=lane_spec)
-    c0 = _gm.dump()["counters"]
-    workers = [threading.Thread(target=h.worker_loop, args=(i,),
-                                daemon=True) for i in range(n_workers)]
-    for t in workers:
-        t.start()
-    t_start = time.perf_counter()
-    t_meas = t_start
-    i = 0
-    warmup_done = False
-    while time.perf_counter() - t_start < warmup_s + duration_s:
-        if not warmup_done and time.perf_counter() - t_start >= warmup_s:
-            # restart the clocks: the EWMA model is trained, drop the
-            # warmup completions/latencies from the measured window
-            h.reset_window()
-            t_meas = time.perf_counter()
-            warmup_done = True
-        # burst ingress: one admission probe + one bulk enqueue per
-        # burst keeps the feeder's GIL share small at saturation (the
-        # per-eval enqueue's lock + condition traffic was the single
-        # largest host cost at 20k evals/s).  Explicit sequential ids
-        # skip the uuid default_factory — the single largest cost of
-        # constructing a synthetic eval, and harness cost, not serving
-        # cost (real ingress arrives with ids)
-        burst = [Evaluation(id=f"sc-{i + j}", job_id=f"sc-{i + j}",
-                            priority=50)
-                 for j in range(32)]
-        i += 32
-        if h.ingress_burst(burst) == 0:
-            time.sleep(0.0005)       # admission-bounded: back off
-    elapsed = time.perf_counter() - t_meas
-    h.stop.set()
-    for t in workers:
-        t.join(timeout=5.0)
-    sys.setswitchinterval(old_switch)
-    gc.enable()
-    c1 = _gm.dump()["counters"]
-    lat = latency_summary(h.lat_s)
-    stages = {k: round(v, 3) for k, v in h.stages.items()}
-    # largest stage over the leader-serial breakdown; `fetch` is the
-    # blocked-on-device wall and overlaps `device`, so it is excluded
-    # from the comparison (it is an alias of device wait, not work)
-    comparable = {k: v for k, v in h.stages.items() if k != "fetch"}
-    largest = (max(comparable, key=comparable.get)
-               if any(comparable.values()) else None)
-    rec = {
-        "workers": n_workers, "shards": n_shards, "fused": bool(fuse),
-        "pipelined": bool(pipelined and fuse and n_workers > 1),
-        "completed": h.completed,
-        "evals_per_sec": round(h.completed / max(elapsed, 1e-9), 1),
-        "p50_ms": lat["p50_ms"], "p99_ms": lat["p99_ms"],
-        "device_occupancy": round(h.device_busy_s
-                                  / max(elapsed, 1e-9), 3),
-        "device_waves": h.device_waves,
-        "solve_calls": h.solve_calls,
-        "evals_per_solve": round(h.completed
-                                 / max(h.solve_calls, 1), 1),
-        "cross_worker_rounds": round(
-            c1.get("coordinator.cross_worker_rounds", 0)
-            - c0.get("coordinator.cross_worker_rounds", 0)),
-        "stages_s": stages,
-        "largest_stage": largest,
-        "bytes_shipped": h.bytes_shipped,
-    }
-    if lane_spec is not None:
-        b, c = h.lane_bounced, h.lane_committed
-        rec["lanes"] = ("auto" if h.lane_ctrl is not None
-                        else h.lane_width)
-        rec["lane_rounds"] = h.lane_rounds
-        rec["lane_calls"] = h.lane_calls
-        rec["revalidation"] = {
-            "bounced": b, "committed": c,
-            "bounce_rate": round(b / max(b + c, 1), 4),
-        }
-        if h.lane_families:
-            rec["lane_families"] = h.lane_families
-        if h.lane_ctrl is not None:
-            hist = h.lane_width_hist
-            rec["lane_width_final"] = h.lane_width
-            # compressed trajectory: width after each round, run-length
-            # encoded so a 2s window's hundreds of rounds stay readable
-            traj = []
-            for w in hist:
-                if traj and traj[-1][0] == w:
-                    traj[-1][1] += 1
-                else:
-                    traj.append([w, 1])
-            rec["lane_width_trajectory"] = traj
-    return rec
-
-
-def _run_group_commit_leg(group_commit, n_plans=300, n_nodes=64):
-    """Plan applies through the real PlanApplier against a durable
-    fsynced log: group_commit=K amortizes one fsync (and one raft
-    entry) over K plans."""
-    import tempfile
-    import threading
-
-    from nomad_tpu import mock
-    from nomad_tpu.server.plan_apply import PlanApplier
-    from nomad_tpu.server.plan_queue import PlanQueue
-    from nomad_tpu.state.store import StateStore
-    from nomad_tpu.structs import Plan
-    from nomad_tpu.utils.codec import to_wire
-
-    store = StateStore()
-    nodes = []
-    for i in range(n_nodes):
-        node = mock.node()
-        node.node_resources.cpu = 1 << 20
-        node.node_resources.memory_mb = 1 << 20
-        node.reserved_resources.cpu = 0
-        node.reserved_resources.memory_mb = 0
-        store.upsert_node(i + 1, node)
-        nodes.append(node)
-
-    state = {"index": 100, "fsyncs": 0, "entries": 0}
-    lock = threading.Lock()
-    fh = tempfile.TemporaryFile(mode="w+")
-
-    def _commit(items):
-        # leader append: serialize + flush + fsync ONCE per entry, the
-        # raft-boltdb discipline the group commit amortizes
-        with lock:
-            state["index"] += 1
-            ix = state["index"]
-            fh.write(json.dumps([to_wire(res) for _pl, res in items])
-                     + "\n")
-            fh.flush()
-            os.fsync(fh.fileno())
-            state["fsyncs"] += 1
-            state["entries"] += 1
-        for plan, result in items:
-            store.upsert_plan_results(ix, result, job=plan.job)
-
-        def finish(timeout=10.0):
-            return ix
-        return 0, finish
-
-    queue = PlanQueue()
-    queue.set_enabled(True)
-    applier = PlanApplier(
-        queue, store, None, None,
-        apply_async_fn=lambda plan, res: _commit([(plan, res)]),
-        apply_batch_async_fn=_commit if group_commit > 1 else None,
-        group_commit=group_commit)
-
-    def plan_for(i):
-        job = mock.job()
-        node = nodes[i % n_nodes]
-        plan = Plan(job=job)
-        a = mock.alloc(job=job, node_id=node.id)
-        for tr in a.allocated_resources.tasks.values():
-            tr.networks = []
-            tr.cpu = 10
-            tr.memory_mb = 10
-        plan.node_allocation[node.id] = [a]
-        return plan
-
-    plans = [plan_for(i) for i in range(n_plans)]
-    applier.start()
-    try:
-        t0 = time.perf_counter()
-        pendings = [queue.enqueue(p) for p in plans]
-        for p in pendings:
-            result, err = p.future.wait(30.0)
-            assert err is None, err
-        elapsed = time.perf_counter() - t0
-    finally:
-        applier.stop()
-        queue.set_enabled(False)
-        fh.close()
-    return {
-        "group_commit": group_commit, "plans": n_plans,
-        "raft_entries": state["entries"], "fsyncs": state["fsyncs"],
-        "plans_per_fsync": round(n_plans / max(state["fsyncs"], 1), 2),
-        "plans_per_sec": round(n_plans / max(elapsed, 1e-9), 1),
-    }
-
-
-def run_scaleout(n_nodes=2048, count=4, max_batch=128, slo_ms=50.0,
-                 duration_s=2.0, resident=5000, seed=11,
-                 grid=((1, 1), (2, 2), (4, 4), (8, 8)),
-                 write_detail=True):
-    """Scale-out control-plane phase (ISSUE 17 acceptance).
-
-    Sweeps (workers x broker shards) over the sharded-broker ->
-    SolveCoordinator -> fused-resident-solve path and reports each
-    config's saturated evals/sec at its p99, the device-occupancy
-    fraction (fused solve wall over elapsed), and the coordinator's
-    cross-worker fusion counters; plus the group-commit leg's
-    plans-per-fsync amortization.  The acceptance figure is the best
-    config's throughput relative to the single-worker single-shard
-    baseline (same solver, same machine — CPU-backend numbers are the
-    recorded profile the issue allows; the serialization the
-    coordinator removes exists on every backend)."""
-    from nomad_tpu.solver.resident import ResidentSolver
-    from nomad_tpu.solver.tensorize import Tensorizer
-
-    slo_s = slo_ms / 1000.0
-    nodes = make_nodes(n_nodes)
-    probe_job = make_job(2, 0, count)
-    template_ask = asks_for(probe_job)[0]
-    gp_need = len({Tensorizer.ask_signature(a)
-                   for a in asks_for(probe_job)})
-    t0 = time.perf_counter()
-    rs = ResidentSolver(nodes, asks_for(probe_job),
-                        gp=1 << max(0, (gp_need - 1).bit_length()),
-                        kp=1 << max(0, (count * max_batch - 1)
-                                    .bit_length()),
-                        max_waves=18)
-    used0 = resident_used0(rs.template, n_nodes, resident)
-    rs.reset_usage(used0=used0)
-    import dataclasses
-    k = 1
-    while k <= max_batch:
-        asks = [dataclasses.replace(template_ask, count=count)] * k
-        masks, _keys = rs.merge_asks(asks)
-        rs.solve_stream([rs.pack_batch(masks)], seeds=[1])
-        k <<= 1
-    # lane-variant warmup (ISSUE 20): lanes and B are trace shapes, so
-    # each (lanes, B) pair the sweep dispatches compiles exactly once,
-    # here — a mid-window retrace would eat the whole measured window.
-    # (4, 8) is the families leg's 2-chunk scan; family batches share
-    # the template's tensor shapes, so the template warms them too.
-    for lane_l, lane_b in ((2, 2), (4, 4), (8, 8), (4, 8)):
-        pbs = []
-        for s in range(lane_b):
-            masks, _keys = rs.merge_asks(
-                [dataclasses.replace(template_ask, count=count)]
-                * max_batch)
-            pbs.append(rs.pack_batch(masks, job_keys={("lane", s)}))
-        rs.finish_stream(rs.solve_stream_async(
-            pbs, seeds=list(range(1, lane_b + 1)), lanes=lane_l))
-    rs.reset_usage(used0=used0)
-    startup_s = time.perf_counter() - t0
-
-    # admission bound sized to 2 fused batches of backlog: deep enough
-    # that every worker's dequeue fills a whole max_batch (fixed-size
-    # rounds keep the packed-batch memo hot and the device waves full),
-    # shallow enough that the admitted traffic's p99 stays queue-bounded
-    # — with a round queued at the coordinator and one in flight, total
-    # in-system work is ~4 rounds, which at the measured service rate
-    # keeps p99 inside the 50ms SLO budget
-    max_pending = max_batch * 2
-    # deterministic trace sampling at a serving-rate-appropriate rate
-    # (ISSUE 15's mechanism: per-trace-id crc32 threshold — sampled
-    # evals keep whole timelines).  Full-rate tracing costs ~19us per
-    # span on this path, which at >10k evals/s is the GIL's whole
-    # budget; EVERY leg (baseline, pr17 reference, pipelined sweep)
-    # runs under the same rate, so the A/B ratios are unaffected.
-    trace_sample = 0.01
-    out = {"phase": "scaleout", "n_nodes": n_nodes, "count": count,
-           "slo_ms": slo_ms, "max_batch": max_batch,
-           "duration_s": duration_s, "max_pending": max_pending,
-           "trace_sample": trace_sample,
-           "startup_s": round(startup_s, 2), "sweep": []}
-
-    from nomad_tpu.utils.tracing import global_tracer as _gt
-    old_sample, old_cut = _gt.sample, _gt._sample_cut
-    _gt.sample = trace_sample
-    _gt._sample_cut = int(trace_sample * (1 << 32))
-    try:
-        base = _run_scaleout_leg(rs, template_ask, count, 1, 1, False,
-                                 duration_s, slo_s, max_batch,
-                                 max_pending, used0)
-        out["baseline"] = base
-        sys.stderr.write(f"scaleout baseline 1wx1s: "
-                         f"{base['evals_per_sec']}/s "
-                         f"p99={base['p99_ms']}ms "
-                         f"occ={base['device_occupancy']}\n")
-        # PR-17 same-machine reference: fused but serialized end to end
-        # (the pre-pipeline coordinator) at its best recorded config —
-        # the A/B the pipelined sweep's 3x acceptance is measured
-        # against, immune to machine-speed drift in the recorded
-        # profile
-        pr17 = _run_scaleout_leg(rs, template_ask, count, 4, 4, True,
-                                 duration_s, slo_s, max_batch,
-                                 max_pending, used0, pipelined=False)
-        out["pr17_reference"] = pr17
-        sys.stderr.write(f"scaleout pr17-ref 4wx4s serialized: "
-                         f"{pr17['evals_per_sec']}/s "
-                         f"p99={pr17['p99_ms']}ms "
-                         f"occ={pr17['device_occupancy']}\n")
-        for n_workers, n_shards in grid:
-            if (n_workers, n_shards) == (1, 1):
-                continue
-            rec = _run_scaleout_leg(rs, template_ask, count, n_workers,
-                                    n_shards, True, duration_s, slo_s,
-                                    max_batch, max_pending, used0)
-            out["sweep"].append(rec)
-            sys.stderr.write(
-                f"scaleout {n_workers}wx{n_shards}s pipelined: "
-                f"{rec['evals_per_sec']}/s p99={rec['p99_ms']}ms "
-                f"occ={rec['device_occupancy']} "
-                f"largest={rec['largest_stage']} "
-                f"xw_rounds={rec['cross_worker_rounds']}\n")
-
-        # ---- lane sweep (ISSUE 20): chunked scan-of-vmap rounds ----
-        # All lane legs run 2 workers x 2 shards (the recorded PR-19
-        # best config); the L=1 serial reference IS that config's plain
-        # pipelined leg from the sweep above.  Lane legs fuse L member
-        # batches per round, so the admission bound scales with L to
-        # keep a full round of backlog behind the in-flight one.
-        from nomad_tpu.scheduler.fleet import LaneWidthController
-        lane_ref = next((r for r in out["sweep"]
-                         if r["workers"] == 2 and r["shards"] == 2),
-                        None)
-        out["lane_serial_reference"] = lane_ref
-        out["lane_sweep"] = []
-
-        def _lane_leg(spec, label, round_b):
-            rec = _run_scaleout_leg(
-                rs, template_ask, count, 2, 2, True, duration_s,
-                slo_s, max_batch, max_batch * round_b * 2, used0,
-                lane_spec=spec)
-            rec["leg"] = label
-            out["lane_sweep"].append(rec)
-            rv = rec.get("revalidation", {})
-            sys.stderr.write(
-                f"scaleout lane {label}: {rec['evals_per_sec']}/s "
-                f"p99={rec['p99_ms']}ms "
-                f"device={rec['stages_s'].get('device')}s "
-                f"bounce={rv.get('bounce_rate')} "
-                f"bytes={rec['bytes_shipped']}\n")
-            return rec
-
-        for lane_l in (2, 4, 8):
-            _lane_leg({"lanes": lane_l}, f"L={lane_l}", lane_l)
-        # dc-pinned families: 8 jobs pinned round-robin over 4 dcs,
-        # form_lanes packs each 4-lane chunk from disjoint dcs (the
-        # conflict-aware formation the coordinator hook exists for)
-        _lane_leg({"lanes": 4, "families": 8, "round_b": 8},
-                  "L=4 families=8", 8)
-        # adaptive width, run LAST: every pow2 (L, B=L) variant is
-        # already compiled, so the controller can roam freely
-        _lane_leg({"controller": LaneWidthController(max_width=8,
-                                                     start=2)},
-                  "L=auto", 8)
-    finally:
-        _gt.sample, _gt._sample_cut = old_sample, old_cut
-
-    # workers sweep must be monotone non-decreasing through 8 (ISSUE 19
-    # satellite; 5% jitter tolerance) — a regressing step auto-caps the
-    # recommended worker count at the last non-regressing config and
-    # records why
-    monotone = True
-    auto_cap = None
-    prev = None
-    for rec in out["sweep"]:
-        if prev is not None and \
-                rec["evals_per_sec"] < prev["evals_per_sec"] * 0.95:
-            monotone = False
-            # name the culprit stage (ISSUE 20 satellite): the stage
-            # whose leader-serial wall grew most vs the previous
-            # config — `fetch` overlaps `device` and is excluded, same
-            # rule as largest_stage.  At 8x8 the historical culprit is
-            # `dispatch`+`pack` (GIL contention: more dequeue threads
-            # splitting the same single drain leader's slices), not
-            # the device — which is why the auto-cap, not a solver
-            # change, is the right fix.
-            ps = prev.get("stages_s", {})
-            cs = rec.get("stages_s", {})
-            deltas = {k: round(cs.get(k, 0.0) - ps.get(k, 0.0), 3)
-                      for k in cs if k != "fetch"}
-            culprit = (max(deltas, key=deltas.get)
-                       if deltas else None)
-            auto_cap = {
-                "workers": prev["workers"], "shards": prev["shards"],
-                "culprit_stage": culprit,
-                "stage_deltas_s": deltas,
-                "reason": (f"{rec['workers']}x{rec['shards']} regressed "
-                           f"to {rec['evals_per_sec']}/s from "
-                           f"{prev['evals_per_sec']}/s at "
-                           f"{prev['workers']}x{prev['shards']}"
-                           + (f"; culprit stage: {culprit} "
-                              f"(+{deltas[culprit]}s)"
-                              if culprit else "")),
-            }
-            break
-        prev = rec
-    out["workers_monotone"] = monotone
-    out["workers_auto_cap"] = auto_cap
-
-    # best selection subject to the SLO bound (ISSUE 19 satellite): the
-    # raw-throughput winner is recorded, but `best` must hold p99
-    # inside the latency budget — a config that wins evals/s by letting
-    # the queue blow the SLO is not the config to run
-    candidates = [base] + out["sweep"] + out["lane_sweep"]
-    best_raw = max(candidates, key=lambda r: r["evals_per_sec"])
-    slo_ok = [r for r in candidates if r["p99_ms"] is not None
-              and r["p99_ms"] <= slo_ms]
-    best = (max(slo_ok, key=lambda r: r["evals_per_sec"])
-            if slo_ok else best_raw)
-    out["best_raw"] = best_raw
-    out["best_meets_slo"] = bool(slo_ok)
-
-    gc_legs = [_run_group_commit_leg(k) for k in (1, 8, 32)]
-    out["group_commit"] = gc_legs
-    for leg in gc_legs:
-        sys.stderr.write(
-            f"group-commit K={leg['group_commit']}: "
-            f"{leg['plans_per_sec']}/s "
-            f"{leg['plans_per_fsync']} plans/fsync\n")
-
-    rel = (best["evals_per_sec"] / base["evals_per_sec"]
-           if base["evals_per_sec"] else float("inf"))
-    rel_pr17 = (best["evals_per_sec"] / pr17["evals_per_sec"]
-                if pr17["evals_per_sec"] else float("inf"))
-    amortized = max(leg["plans_per_fsync"] for leg in gc_legs)
-    out["best"] = best
-    out["relative_speedup"] = round(rel, 2)
-    out["relative_speedup_vs_pr17"] = round(rel_pr17, 2)
-    out["pr17_recorded_best_evals_per_sec"] = PR17_RECORDED_BEST
-    out["acceptance"] = {
-        "best_evals_per_sec": best["evals_per_sec"],
-        "ge_50k_evals_per_sec": best["evals_per_sec"] >= 50_000,
-        "ge_10x_relative": rel >= 10.0,
-        "ge_3x_pr17_recorded":
-            best["evals_per_sec"] >= 3.0 * PR17_RECORDED_BEST,
-        "ge_3x_pr17_same_machine": rel_pr17 >= 3.0,
-        "best_meets_slo": bool(slo_ok),
-        "bounded_p99_ms": best["p99_ms"],
-        "device_occupancy_ge_0_85":
-            best["device_occupancy"] >= 0.85,
-        "workers_monotone_through_8": bool(monotone or auto_cap),
-        "device_largest_stage":
-            best.get("largest_stage") == "device",
-        "group_commit_amortizes_fsync": amortized > 1.5,
-        "backend": "cpu (recorded profile; the issue's 10x target "
-                   "binds on accelerator backends)",
-    }
-    # ---- ISSUE 20 lane acceptance: best lane leg inside the SLO ----
-    lane_slo = [r for r in out["lane_sweep"]
-                if r["p99_ms"] is not None and r["p99_ms"] <= slo_ms]
-    lane_best = (max(lane_slo, key=lambda r: r["evals_per_sec"])
-                 if lane_slo
-                 else max(out["lane_sweep"],
-                          key=lambda r: r["evals_per_sec"]))
-    out["lane_best"] = lane_best
-    lane_dev_us = (lane_best["stages_s"].get("device", 0.0)
-                   / max(lane_best["completed"], 1) * 1e6)
-    out["acceptance"]["lane_best_evals_per_sec"] = \
-        lane_best["evals_per_sec"]
-    out["acceptance"]["lane_ge_40k_evals_per_sec"] = \
-        bool(lane_slo) and lane_best["evals_per_sec"] >= 40_000
-    out["acceptance"]["lane_ge_50k_stretch"] = \
-        bool(lane_slo) and lane_best["evals_per_sec"] >= 50_000
-    out["acceptance"]["lane_p99_ms"] = lane_best["p99_ms"]
-    out["acceptance"]["lane_bounce_rate"] = \
-        lane_best.get("revalidation", {}).get("bounce_rate")
-    out["acceptance"]["pr19_recorded_device_us_per_eval"] = \
-        PR19_RECORDED_DEVICE_US_PER_EVAL
-    out["acceptance"]["lane_device_us_per_eval"] = \
-        round(lane_dev_us, 2)
-    out["acceptance"]["device_stage_reduced_30pct"] = \
-        lane_dev_us <= 0.7 * PR19_RECORDED_DEVICE_US_PER_EVAL
-    out["acceptance"]["lane_backend_note"] = (
-        "cpu recorded profile: vmapped lanes serialize on a "
-        "single-core host, so the 40k and -30% device targets bind on "
-        "accelerator backends where lanes are data-parallel; the "
-        "conflict-aware formation result (families leg bounce rate vs "
-        "unformed L=4) is backend-independent")
-    out["ok"] = bool(rel > 1.0
-                     and out["acceptance"]["group_commit_amortizes_fsync"])
-    if write_detail:
-        # merge into BENCH_DETAIL.json preserving the other phases
-        path = os.path.join(REPO, "BENCH_DETAIL.json")
-        try:
-            with open(path) as f:
-                detail = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            detail = {}
-        detail["scaleout"] = out
-        with open(path, "w") as f:
-            json.dump(detail, f, indent=1)
-    return out
-
-
-def run_tracing_overhead(n_nodes=10_000, count=64, resident=100_000,
-                         batch=32, iters=24, reps=5, warmup=4,
-                         write_detail=True):
-    """Tracing-overhead leg (ISSUE 10 acceptance): traced vs untraced
-    steady-state solve wall at config-3 scale (10K nodes, 100K resident
-    allocs, count-64 asks).
-
-    Each iteration solves one fused batch through the resident stream
-    engine; the traced leg records per eval exactly what the serving
-    path records (create/admit/enqueue/dequeue/batch events plus a
-    solve span carrying the ResidentSolver wave/delta counters), so
-    the measured delta IS the flight recorder's serving-path cost.
-    Legs interleave per rep so transport/CPU drift cancels; the
-    acceptance bar is traced within 2% of untraced."""
-    import dataclasses
-
-    from nomad_tpu.solver.resident import ResidentSolver
-    from nomad_tpu.solver.tensorize import Tensorizer
-    from nomad_tpu.utils.tracing import FlightRecorder
-
-    nodes = make_nodes(n_nodes)
-    probe_job = make_job(3, 0, count)
-    template_ask = asks_for(probe_job)[0]
-    gp_need = len({Tensorizer.ask_signature(a)
-                   for a in asks_for(probe_job)})
-    t0 = time.perf_counter()
-    rs = ResidentSolver(nodes, asks_for(probe_job),
-                        gp=1 << max(0, (gp_need - 1).bit_length()),
-                        kp=1 << max(0, (count * batch - 1)
-                                    .bit_length()),
-                        max_waves=18)
-    used0 = resident_used0(rs.template, n_nodes, resident)
-    rs.reset_usage(used0=used0)
-    asks = [dataclasses.replace(template_ask, count=count)] * batch
-    masks, _keys = rs.merge_asks(asks)
-    pb = rs.pack_batch(masks)
-    rs.solve_stream([pb], seeds=[1])        # compile outside the legs
-    startup_s = time.perf_counter() - t0
-
-    seq = [0]
-
-    def one_iter(rec, i):
-        evs = [f"to-{i}-{k}" for k in range(batch)]
-        for eid in evs:
-            rec.event(eid, "create", parent="", job_id="bench",
-                      namespace="default", priority=50)
-            rec.event(eid, "admit", admitted=True)
-            rec.event(eid, "broker.enqueue", queue="service")
-        for eid in evs:
-            rec.event(eid, "broker.dequeue", queue_age_s=0.0,
-                      delivery=1)
-            rec.event(eid, "worker.batch", batch_size=batch,
-                      lane="bulk")
-        spans = [rec.stage(eid, "solve", job_id="bench", fused=True,
-                           fused_batch=batch) for eid in evs]
-        seq[0] += 1
-        rs.solve_stream([pb], seeds=[seq[0]])
-        attrs = rs.trace_attrs()
-        for sp in spans:
-            sp.set(**attrs)
-            sp.end()
-
-    def leg(rec):
-        rs.reset_usage(used0=used0)
-        for i in range(warmup):
-            one_iter(rec, i)
-        t = time.perf_counter()
-        for i in range(iters):
-            one_iter(rec, warmup + i)
-        return time.perf_counter() - t
-
-    off_rec = FlightRecorder(depth=512, enabled=False)
-    on_rec = FlightRecorder(depth=512, enabled=True)
-    walls_off, walls_on = [], []
-    for _rep in range(reps):
-        walls_off.append(leg(off_rec))
-        walls_on.append(leg(on_rec))
-    # best-of-reps: the solve wall on a shared CPU carries multi-% rep-
-    # to-rep noise that dwarfs the recorder's microsecond-scale appends;
-    # the per-leg FLOOR isolates the systematic cost the acceptance bar
-    # is about (both legs get identical treatment)
-    off = min(walls_off)
-    on = min(walls_on)
-    overhead_pct = 100.0 * (on - off) / max(off, 1e-9)
-    out = {
-        "phase": "tracing_overhead",
-        "n_nodes": n_nodes, "count": count, "resident": resident,
-        "batch": batch, "iters": iters, "reps": reps,
-        "startup_s": round(startup_s, 2),
-        "untraced_wall_s": [round(w, 4) for w in walls_off],
-        "traced_wall_s": [round(w, 4) for w in walls_on],
-        "untraced_evals_per_sec": round(batch * iters / off, 1),
-        "traced_evals_per_sec": round(batch * iters / on, 1),
-        "overhead_pct": round(overhead_pct, 3),
-        "recorder": on_rec.stats(),
-        "acceptance": {"traced_within_2pct": overhead_pct <= 2.0},
-    }
-    out["ok"] = bool(out["acceptance"]["traced_within_2pct"])
-    if write_detail:
-        # merge into BENCH_DETAIL.json preserving the other phases
-        path = os.path.join(REPO, "BENCH_DETAIL.json")
-        try:
-            with open(path) as f:
-                detail = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            detail = {}
-        detail["tracing_overhead"] = out
-        with open(path, "w") as f:
-            json.dump(detail, f, indent=1)
-    return out
-
-
-def run_telemetry_overhead(n_nodes=10_000, count=64, resident=100_000,
-                           batch=32, iters=24, reps=9, warmup=4,
-                           sample_every=5, churn_steps=8,
-                           write_detail=True):
-    """Telemetry leg (ISSUE 15 acceptance): steady-state solve wall
-    with the fleet health kernel sampling every `sample_every` solves
-    vs never, at config-3 scale (10K nodes, 100K resident allocs,
-    count-64 asks).
-
-    The sampled leg is deliberately harsher than production: at this
-    scale the stream runs ~10 solves/s, so sample_every=5 is ~2 Hz —
-    roughly 10x the server's shipped duty cycle (one sample per
-    HEALTH_SAMPLE_EVERY=5 export beats, i.e. per 5 s).  The record
-    also carries the measured per-sample unit cost
-    (health_sample_cost_ms, ~2 ms at this scale: on the CPU backend
-    the kernel serializes with solves on one XLA stream, so the unit
-    cost IS the kernel wall) so any cadence's overhead can be read
-    off directly.  Legs interleave per rep so transport/CPU drift
-    cancels; min-of-reps isolates the systematic cost from
-    shared-CPU noise (same floor treatment as the tracing leg
-    above).
-
-    A second churn phase strands CPU on a growing fraction of nodes
-    (plenty of memory/disk free, but less CPU than the smallest probe
-    ask needs) and records the fragmentation-index trajectory the
-    health plane reports, through a real TimeSeriesStore ring so the
-    record also proves the series plumbing end to end."""
-    import dataclasses
-
-    import numpy as np
-
-    from nomad_tpu.solver.resident import ResidentSolver
-    from nomad_tpu.solver.tensorize import Tensorizer
-    from nomad_tpu.telemetry.health import (device_health_counters,
-                                            device_health_raw,
-                                            fetch_health)
-    from nomad_tpu.telemetry.series import TimeSeriesStore
-
-    nodes = make_nodes(n_nodes)
-    probe_job = make_job(3, 0, count)
-    template_ask = asks_for(probe_job)[0]
-    gp_need = len({Tensorizer.ask_signature(a)
-                   for a in asks_for(probe_job)})
-    t0 = time.perf_counter()
-    rs = ResidentSolver(nodes, asks_for(probe_job),
-                        gp=1 << max(0, (gp_need - 1).bit_length()),
-                        kp=1 << max(0, (count * batch - 1)
-                                    .bit_length()),
-                        max_waves=18)
-    used0 = resident_used0(rs.template, n_nodes, resident)
-    rs.reset_usage(used0=used0)
-    asks = [dataclasses.replace(template_ask, count=count)] * batch
-    masks, _keys = rs.merge_asks(asks)
-    pb = rs.pack_batch(masks)
-    rs.solve_stream([pb], seeds=[1])        # compile outside the legs
-    device_health_counters(rs)              # compile the health kernel
-    startup_s = time.perf_counter() - t0
-
-    seq = [0]
-
-    def leg(sample_health):
-        rs.reset_usage(used0=used0)
-        it = [0]
-        # double-buffered sampling, the way a production device-side
-        # sampler runs: dispatch this beat's kernel, materialize the
-        # PREVIOUS beat's (long since done) — a blocking fetch right
-        # after dispatch would charge the stream's in-flight tail to
-        # the sample
-        pending = [None]
-
-        def fetch_pending():
-            if pending[0] is not None:
-                fetch_health(pending[0])
-                pending[0] = None
-
-        def one_iter():
-            seq[0] += 1
-            it[0] += 1
-            rs.solve_stream([pb], seeds=[seq[0]])
-            if sample_health and it[0] % sample_every == 0:
-                fetch_pending()
-                pending[0] = device_health_raw(rs)
-
-        for _ in range(warmup):
-            one_iter()
-        t = time.perf_counter()
-        for _ in range(iters):
-            one_iter()
-        fetch_pending()
-        return time.perf_counter() - t
-
-    walls_off, walls_on = [], []
-    for _rep in range(reps):
-        walls_off.append(leg(False))
-        walls_on.append(leg(True))
-    off = min(walls_off)
-    on = min(walls_on)
-    overhead_pct = 100.0 * (on - off) / max(off, 1e-9)
-
-    # ---- churn phase: stranded-CPU fragmentation trajectory --------
-    # The smallest config-3 group asks 400 CPU; leaving 350 free makes
-    # a node un-placeable while its memory/disk headroom stays large —
-    # the classic fragmentation picture the index is built to surface.
-    avail = np.asarray(rs.template.avail, np.float32)
-    # start at t=1: the points() cursor is bucket_start > since and the
-    # default since is 0, which would hide a bucket starting at 0
-    fake_t = [1.0]
-    churn_store = TimeSeriesStore(resolutions=((1, 4 * churn_steps),),
-                                  clock=lambda: fake_t[0])
-    traj = []
-    for step in range(churn_steps + 1):
-        frac = step / churn_steps
-        n_churn = int(frac * n_nodes)
-        churned = used0.copy()
-        if n_churn:
-            churned[:n_churn, 0] = np.maximum(
-                avail[:n_churn, 0] - 350.0, churned[:n_churn, 0])
-        rs.reset_usage(used0=churned)
-        hc = device_health_counters(rs)
-        frag = hc.fragmentation_index()
-        traj.append({"churn_frac": round(frac, 3),
-                     "fragmentation_index": round(frag, 4),
-                     "nodes_stranded": hc.nodes_stranded,
-                     "nodes_busy": hc.nodes_busy})
-        churn_store.record("health.fragmentation_index", frag,
-                           now=fake_t[0])
-        fake_t[0] += 1.0
-    churn_store.flush(now=fake_t[0])
-    ring = churn_store.points("health.fragmentation_index", res=1)
-    frags = [p["fragmentation_index"] for p in traj]
-    # samples landing inside the timed window (iteration counter spans
-    # warmup too, so the modulo grid does not restart at the timer)
-    n_samples = len([i for i in range(warmup + 1, warmup + iters + 1)
-                     if i % sample_every == 0])
-    out = {
-        "phase": "telemetry",
-        "n_nodes": n_nodes, "count": count, "resident": resident,
-        "batch": batch, "iters": iters, "reps": reps,
-        "sample_every": sample_every,
-        "startup_s": round(startup_s, 2),
-        "unsampled_wall_s": [round(w, 4) for w in walls_off],
-        "sampled_wall_s": [round(w, 4) for w in walls_on],
-        "unsampled_evals_per_sec": round(batch * iters / off, 1),
-        "sampled_evals_per_sec": round(batch * iters / on, 1),
-        "overhead_pct": round(overhead_pct, 3),
-        "health_samples_per_leg": n_samples,
-        "health_sample_cost_ms": round(
-            1000.0 * (on - off) / max(n_samples, 1), 3),
-        "fragmentation_trajectory": traj,
-        "series_ring_points": len(ring),
-        "acceptance": {
-            "telemetry_within_2pct": overhead_pct <= 2.0,
-            "fragmentation_monotone": all(
-                b >= a - 1e-9 for a, b in zip(frags, frags[1:])),
-            "fragmentation_rises": frags[-1] > frags[0] + 0.25,
-            "ring_kept_every_sample": len(ring) == churn_steps + 1,
-        },
-    }
-    out["ok"] = all(out["acceptance"].values())
-    if write_detail:
-        # merge into BENCH_DETAIL.json preserving the other phases
-        path = os.path.join(REPO, "BENCH_DETAIL.json")
-        try:
-            with open(path) as f:
-                detail = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            detail = {}
-        detail["telemetry"] = out
-        with open(path, "w") as f:
-            json.dump(detail, f, indent=1)
-    return out
-
-
 def run_ours_latency(config, n_nodes, n_evals, count, resident):
     """Single-eval-per-call mode: what one interactive eval costs.
 
@@ -3937,11 +2180,7 @@ _CHILD_PHASES = {
     "--multichip": (run_multichip, "multichip"),
     "--multiregion": (run_multiregion, "multiregion"),
     "--chaos": (run_chaos, "chaos"),
-    "--open-loop": (run_open_loop, "open_loop"),
-    "--scaleout": (run_scaleout, "scaleout"),
     "--overcommit": (run_overcommit, "overcommit"),
-    "--tracing": (run_tracing_overhead, "tracing_overhead"),
-    "--telemetry": (run_telemetry_overhead, "telemetry"),
     "--analysis": (run_analysis, None),
 }
 
@@ -4022,9 +2261,7 @@ def main():
         for flag in ("--multichip", "--multiregion"):
             detail[_CHILD_PHASES[flag][1]] = _run_child([flag],
                                                         env=mesh_env)
-        for flag in ("--open-loop", "--scaleout", "--overcommit",
-                     "--tracing", "--telemetry"):
-            detail[_CHILD_PHASES[flag][1]] = _run_child([flag])
+        detail["overcommit"] = _run_child(["--overcommit"])
         detail.update(_run_child(["--analysis"]))
         detail["notes"] = [
             "denominator: bench/stock_engine.cc — reference semantics "

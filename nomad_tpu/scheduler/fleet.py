@@ -51,8 +51,7 @@ ROUND_STAGES = ("dequeue", "reconcile", "pack", "dispatch", "device",
 def record_stage_metrics(stages: Dict[str, float],
                          prefix: str = "coordinator.stage") -> None:
     """Publish one round's stage breakdown as metrics histograms
-    (explicit latency buckets, surfaced at /v1/metrics and consumed by
-    bench.py --scaleout)."""
+    (explicit latency buckets, surfaced at /v1/metrics)."""
     for name, v in stages.items():
         _m.observe_hist(f"{prefix}.{name}_s", float(v))
 
@@ -142,8 +141,7 @@ class LaneWidthController:
         self.narrow_above = float(narrow_above)
         self.patience = max(1, int(patience))
         self._streak = 0          # +n widen votes, -n narrow votes
-        #: observation log (bounce_rate, device_frac, width) — the
-        #: bench's lane leg reports the trajectory
+        #: observation log (bounce_rate, device_frac, width)
         self.history: List[Tuple[float, float, int]] = []
 
     def record(self, bounce_rate: float,
@@ -413,6 +411,17 @@ def fleet_finish(server, worker, rnd: _FleetRound,
     record_stage_metrics(rnd.stages)
 
 
+def fleet_begin_dispatch(server, worker,
+                         batch: List[Tuple[Evaluation, str]]
+                         ) -> Optional[_FleetRound]:
+    """First half of a round: reconcile, then launch the fused kernel.
+    Returns None when nothing was left to fuse."""
+    rnd = fleet_begin(server, worker, batch)
+    if rnd is not None:
+        fleet_dispatch(server, worker, rnd)
+    return rnd
+
+
 def process_fleet(server, worker, batch: List[Tuple[Evaluation, str]]
                   ) -> None:
     """Process a dequeued eval batch with one fused solve. `worker` is the
@@ -420,11 +429,9 @@ def process_fleet(server, worker, batch: List[Tuple[Evaluation, str]]
     processor for anything the fused path can't finish.  Serialized
     composition of the three pipeline phases — the coordinator overlaps
     them across rounds instead."""
-    rnd = fleet_begin(server, worker, batch)
-    if rnd is None:
-        return
-    fleet_dispatch(server, worker, rnd)
-    fleet_finish(server, worker, rnd)
+    rnd = fleet_begin_dispatch(server, worker, batch)
+    if rnd is not None:
+        fleet_finish(server, worker, rnd)
 
 
 class _FusedSubmission:
@@ -475,34 +482,20 @@ class SolveCoordinator:
     identical to serialized singles."""
 
     def __init__(self, server, max_fused: int = DEFAULT_MAX_FUSED,
-                 solve_fn=None, pipeline: bool = True,
-                 dispatch_fn=None, finish_fn=None,
-                 lane_former=None, lane_controller=None):
+                 dispatch_fn=None, finish_fn=None):
         self.server = server
         self.max_fused = max(1, int(max_fused))
-        #: conflict-aware chunk formation (ISSUE 20): when set, the
-        #: drain leader reorders each round's combined member list via
-        #: `lane_former(members, width)` before dispatch, so the lane
-        #: kernel's consecutive L-blocks hold non-conflicting members
-        #: (`form_lanes` partially applied over a footprint key_fn is
-        #: the standard former).  `lane_controller` supplies the width
-        #: and is fed by the round's finish path (the bench's lane leg
-        #: and the sharded drain both read the solver's lane counters
-        #: there — the coordinator itself never blocks on a fetch to
-        #: learn the bounce rate).
-        self.lane_former = lane_former
-        self.lane_controller = lane_controller
-        #: (server, worker, combined_batch) -> None; serialized custom
-        #: path (bench A/B legs, tests) — disables pipelining
-        self.solve_fn = solve_fn
-        #: split custom path: dispatch_fn(server, worker, batch) -> round
-        #: handle (or None when nothing to solve), finish_fn(server,
-        #: worker, round) -> None.  The bench injects a direct resident-
-        #: solver pair here to measure pipelined fusion alone.
-        self.dispatch_fn = dispatch_fn
-        self.finish_fn = finish_fn
-        self.pipeline = (bool(pipeline) and solve_fn is None) \
-            or dispatch_fn is not None
+        #: the two halves of a round.  This pair is the TEST SEAM, the
+        #: only one: tests substitute fakes to watch the drain order and
+        #: the fan-back without a solver; nothing else passes it.
+        #: dispatch_fn(server, worker, batch) -> round handle (or None
+        #: when nothing was left to solve), finish_fn(server, worker,
+        #: round) -> None.
+        self.dispatch_fn = dispatch_fn or fleet_begin_dispatch
+        self.finish_fn = finish_fn or self._fleet_finish
+        # fetch-completion stamp of the last finished round (the clip
+        # `fleet_finish` needs to account device time in order)
+        self._prev_fetch_done = 0.0
         self._lock = threading.Lock()
         # signalled on every submission: the drain leader parks here
         # (briefly, bounded) when it has a round in flight but nothing
@@ -557,10 +550,8 @@ class SolveCoordinator:
         that keeps dequeue threads feeding the pipeline (a blocked
         submitter cannot fetch the next batch, so with blocking
         submits the device idles between rounds exactly as long as a
-        dequeue takes).  Callers that fire-and-forget must arrange
-        ack/nack inside the round itself (the bench's finish_fn does);
-        callers that need results wait on the future — `submit` is
-        that composition."""
+        dequeue takes).  Callers that need results wait on the
+        future — `submit` is that composition."""
         sub = _FusedSubmission(worker, batch)
         with self._lock:
             self._queue.append(sub)
@@ -597,16 +588,15 @@ class SolveCoordinator:
         round).  The role flag hand-off is atomic with the queue check,
         so a submission is never left behind without a drainer.
 
-        Pipelined mode keeps one round in flight: each iteration
-        dispatches round b+1 FIRST (the device starts solving), then
-        finishes round b (fetch + fan-back + ack) — so the Python
-        reconcile/plan work of every round overlaps the device solve of
-        its neighbor.  The leader never returns with a round in flight,
-        and a submitter's `done` fires only after its round's finish
-        phase (no eval is released between dispatch and fetch)."""
+        One round is kept in flight: each iteration dispatches round
+        b+1 FIRST (the device starts solving), then finishes round b
+        (fetch + fan-back + ack) — so the Python reconcile/plan work of
+        every round overlaps the device solve of its neighbor.  The
+        leader never returns with a round in flight, and a submitter's
+        `done` fires only after its round's finish phase (no eval is
+        released between dispatch and fetch)."""
         # (submitters, round handle) of the dispatched-not-fetched round
         inflight: Optional[Tuple[List[_FusedSubmission], object]] = None
-        prev_fetch_done = 0.0
         while True:
             with self._lock:
                 if inflight is not None and not self._queue \
@@ -637,42 +627,18 @@ class SolveCoordinator:
             rnd = None
             if round_subs:
                 combined = [pair for s in round_subs for pair in s.batch]
-                if self.lane_former is not None:
-                    w = (self.lane_controller.width
-                         if self.lane_controller is not None else 0)
-                    combined = self.lane_former(combined, w)
                 _m.add_sample("coordinator.fused_evals",
                               float(len(combined)))
                 if len(round_subs) > 1:
                     _m.incr_counter("coordinator.cross_worker_rounds")
                 _m.incr_counter("coordinator.rounds")
-                if not self.pipeline:
-                    # serialized path (legacy solve_fn or pipeline off):
-                    # run the round end to end; nothing ever in flight
-                    try:
-                        (self.solve_fn or process_fleet)(
-                            self.server, solve_worker, combined)
-                    except Exception as exc:
-                        # each submitter nacks its OWN evals from its
-                        # worker loop's failure path — the coordinator
-                        # only relays
-                        for s in round_subs:
-                            s.error = exc
-                    finally:
-                        for s in round_subs:
-                            s.done.set()
-                    continue
                 try:
-                    if self.dispatch_fn is not None:
-                        rnd = self.dispatch_fn(self.server, solve_worker,
-                                               combined)
-                    else:
-                        rnd = fleet_begin(self.server, solve_worker,
-                                          combined)
-                        if rnd is not None:
-                            fleet_dispatch(self.server, solve_worker,
-                                           rnd)
+                    rnd = self.dispatch_fn(self.server, solve_worker,
+                                           combined)
                 except Exception as exc:
+                    # each submitter nacks its OWN evals from its
+                    # worker loop's failure path — the coordinator
+                    # only relays
                     for s in round_subs:
                         s.error = exc
                         s.done.set()
@@ -687,25 +653,21 @@ class SolveCoordinator:
             # reconciled + dispatched above; finish it now and release
             # its submitters
             if inflight is not None:
-                prev_fetch_done = self._finish_inflight(
-                    solve_worker, inflight, prev_fetch_done)
+                self._finish_inflight(solve_worker, inflight)
             inflight = (round_subs, rnd) if round_subs else None
 
-    def _finish_inflight(self, worker, inflight, prev_fetch_done: float
-                         ) -> float:
+    def _finish_inflight(self, worker, inflight) -> None:
         subs, rnd = inflight
-        t_done = prev_fetch_done
         try:
-            if self.finish_fn is not None:
-                self.finish_fn(self.server, worker, rnd)
-            else:
-                fleet_finish(self.server, worker, rnd,
-                             prev_fetch_done=prev_fetch_done)
-            t_done = getattr(rnd, "t_fetch_done", 0.0) or prev_fetch_done
+            self.finish_fn(self.server, worker, rnd)
         except Exception as exc:
             for s in subs:
                 s.error = exc
         finally:
             for s in subs:
                 s.done.set()
-        return t_done
+
+    def _fleet_finish(self, server, worker, rnd: _FleetRound) -> None:
+        fleet_finish(server, worker, rnd,
+                     prev_fetch_done=self._prev_fetch_done)
+        self._prev_fetch_done = rnd.t_fetch_done or self._prev_fetch_done

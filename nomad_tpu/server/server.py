@@ -119,10 +119,9 @@ class Server:
         # worker coalesce into one device wave; express lane stays
         # single-solve inside the worker
         self.solve_coordinator = None
-        if self.serving.coordinator and num_workers > 1:
+        if num_workers > 1:
             from ..scheduler.fleet import SolveCoordinator
-            self.solve_coordinator = SolveCoordinator(
-                self, pipeline=self.serving.pipeline)
+            self.solve_coordinator = SolveCoordinator(self)
         self.heartbeater = NodeHeartbeater(
             self._on_heartbeat_expired,
             min_heartbeat_ttl_s=min_heartbeat_ttl_s,

@@ -371,29 +371,15 @@ class ServingTier:
                               600.0),
         "slo_slow_burn": ("NOMAD_TPU_SLO_SLOW_BURN", float, 2.0),
         # scale-out plane (ISSUE 17): broker sharding, dequeue worker
-        # count, raft group-commit width, cross-worker solve fusion
+        # count, raft group-commit width
         "broker_shards": ("NOMAD_TPU_BROKER_SHARDS", int, 1),
         "num_workers": ("NOMAD_TPU_NUM_WORKERS", int, 2),
         "group_commit": ("NOMAD_TPU_GROUP_COMMIT", int, 8),
-        "coordinator": ("NOMAD_TPU_COORDINATOR", int, 1),
-        # double-buffered coordinator pipelining (ISSUE 19): dispatch
-        # round b+1 while round b's device solve is in flight
-        "pipeline": ("NOMAD_TPU_PIPELINE", int, 1),
         # leader soft-pause fraction of workers; -1 = auto (0 once the
         # broker is sharded — pausing dequeue parallelism defeats shard
         # homing — else the reference's 3/4)
         "worker_pause_fraction": ("NOMAD_TPU_WORKER_PAUSE_FRACTION",
                                   float, -1.0),
-        # lane-parallel fused solve (ISSUE 20): starting lane width of
-        # the chunked scan-of-vmap (1 = the serial scan, bit-for-bit),
-        # the adaptive controller's pow2 ceiling, and its widen/narrow
-        # bounce-rate thresholds (fractions of lane placements bounced
-        # to STATUS_RETRY by the cross-lane revalidation)
-        "fused_lanes": ("NOMAD_TPU_FUSED_LANES", int, 1),
-        "max_lanes": ("NOMAD_TPU_MAX_LANES", int, 8),
-        "lane_widen_below": ("NOMAD_TPU_LANE_WIDEN_BELOW", float, 0.05),
-        "lane_narrow_above": ("NOMAD_TPU_LANE_NARROW_ABOVE", float,
-                              0.25),
     }
 
     def __init__(self, adaptive: bool = True,
@@ -415,13 +401,7 @@ class ServingTier:
         self.broker_shards = max(1, k["broker_shards"])
         self.num_workers = max(1, k["num_workers"])
         self.group_commit = max(1, k["group_commit"])
-        self.coordinator = bool(k["coordinator"])
-        self.pipeline = bool(k["pipeline"])
         self.worker_pause_fraction = k["worker_pause_fraction"]
-        self.fused_lanes = max(1, k["fused_lanes"])
-        self.max_lanes = max(1, k["max_lanes"])
-        self.lane_widen_below = k["lane_widen_below"]
-        self.lane_narrow_above = k["lane_narrow_above"]
         self.solve_model = EwmaSolveModel()
         self.batch_controller = BatchController(
             self.solve_model, slo_budget_s=k["slo_budget_s"],
@@ -477,10 +457,6 @@ class ServingTier:
             "broker_shards": self.broker_shards,
             "num_workers": self.num_workers,
             "group_commit": self.group_commit,
-            "coordinator": self.coordinator,
-            "pipeline": self.pipeline,
-            "fused_lanes": self.fused_lanes,
-            "max_lanes": self.max_lanes,
             "last_target_batch": self.batch_controller.last_target(),
             "model_observations": self.solve_model.observations(),
             "admission": self.admission.stats(),

@@ -722,8 +722,8 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
     # tie-break jitter: the reference visits nodes in per-worker shuffled
     # order (stack.go NewRandomIterator), so equal-scoring nodes resolve
     # differently per worker. seed=0 keeps exact deterministic scoring;
-    # seed != 0 decorrelates both sibling batches (resident.solve_parallel
-    # passes distinct seeds) and sibling GROUPS within a batch, fanning
+    # seed != 0 decorrelates both sibling batches (a stream's caller may
+    # pass distinct seeds) and sibling GROUPS within a batch, fanning
     # same-shaped asks across equal-scoring nodes instead of colliding on
     # one argmax — fewer contention waves for identical placements.
     node_gids = jnp.arange(Np, dtype=jnp.uint32)

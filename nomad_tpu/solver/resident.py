@@ -263,85 +263,6 @@ def _solve_one(avail, reserved, valid, node_dc, attr_rank, dev_cap,
 @functools.partial(jax.jit,
                    static_argnames=("has_spread", "group_count_hint",
                                     "max_waves", "wave_mode",
-                                    "has_distinct", "has_devices"))
-def _parallel_kernel(avail, reserved, valid, node_dc, attr_rank, dev_cap,
-                     used0, dev_used0, stacked, n_places, seeds,
-                     has_spread=True, group_count_hint=0, max_waves=0,
-                     wave_mode="while", has_distinct=True,
-                     has_devices=True):
-    """The TPU recast of the reference's optimistic worker concurrency
-    (nomad/worker.go goroutines + nomad/plan_apply.go serial applier):
-    vmap B batch-solves against ONE shared usage snapshot — each with its
-    own tie-break seed, the analog of per-worker shuffled node order —
-    then revalidate every batch's placements serially against cumulative
-    usage, bouncing whatever no longer fits.  All on device; one round
-    trip for the whole fleet of batches."""
-    res = jax.vmap(
-        lambda b, n, s: _solve_one(avail, reserved, valid, node_dc,
-                                   attr_rank, dev_cap, used0, dev_used0,
-                                   b, n, s, has_spread,
-                                   group_count_hint, max_waves,
-                                   wave_mode, has_distinct, has_devices,
-                                   # vmapped lanes turn the shortlist
-                                   # cond into a select (both branches
-                                   # run) — pure overhead here
-                                   shortlist_c=-1)
-    )(stacked, n_places, seeds)
-    # res.* have a leading [B] axis; slot-0 choices are the commits
-    K = res.choice.shape[1]
-    ks = jnp.arange(K)
-
-    def apply_batch(carry, xs):
-        used, dev_used = carry
-        choice, ok0, score, unfin, res_k, dev_k, n_place = xs
-        cand = choice[:, 0]
-        ok = ok0[:, 0] & (ks < n_place)
-        # cumulative same-node load within this batch, in placement
-        # order. Conservative one-round revalidation: a bounced
-        # placement's load still counts toward later same-node
-        # placements (exact first-fit would need a per-node serial
-        # walk), so a bounce can cascade — every bounce is reported
-        # STATUS_RETRY, never failed, and clears in the retry stream.
-        earlier = ks[None, :] < ks[:, None]
-        same = (cand[None, :] == cand[:, None]) & ok[None, :] \
-            & ok[:, None] & earlier
-        prior = exact_dot(same.astype(jnp.float32), res_k * ok[:, None])
-        prior_dev = exact_dot(same.astype(jnp.float32),
-                              dev_k * ok[:, None])
-        fits = ((used[cand] + prior + res_k) <= avail[cand]).all(-1)
-        dev_fits = ((dev_used[cand] + prior_dev + dev_k)
-                    <= dev_cap[cand]).all(-1)
-        commit = ok & fits & dev_fits
-        cm = commit[:, None]
-        used = used.at[cand].add(res_k * cm)
-        dev_used = dev_used.at[cand].add(dev_k * cm)
-        # bounced placements lose ALL slots (their fall-through scores
-        # were solved against a stale snapshot and were never charged)
-        score = jnp.where(cm, score, NEG_INF)
-        status = jnp.where(commit, STATUS_COMMITTED,
-                           jnp.where(ok | unfin, STATUS_RETRY,
-                                     STATUS_FAILED))
-        packed = jnp.concatenate(
-            [choice.astype(jnp.float32), score,
-             status.astype(jnp.float32)[:, None]], axis=-1)
-        return (used, dev_used), packed
-
-    res_per_p = jnp.take_along_axis(
-        stacked["ask_res"],
-        stacked["p_ask"][:, :, None].astype(jnp.int32), axis=1)  # [B,K,R]
-    dev_per_p = jnp.take_along_axis(
-        stacked["dev_ask"],
-        stacked["p_ask"][:, :, None].astype(jnp.int32), axis=1)  # [B,K,D]
-    (used_f, dev_used_f), out = jax.lax.scan(
-        apply_batch, (used0, dev_used0),
-        (res.choice, res.choice_ok, res.score, res.unfinished,
-         res_per_p, dev_per_p, n_places))
-    return used_f, dev_used_f, out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("has_spread", "group_count_hint",
-                                    "max_waves", "wave_mode",
                                     "has_distinct", "has_devices",
                                     "stack_commit", "compact",
                                     "pallas_mode", "shortlist_c",
@@ -409,18 +330,17 @@ def _lane_stream_kernel(avail, reserved, valid, node_dc, attr_rank,
     `_stream_kernel` but L batches per scan step, each step `vmap`ing
     the solve over its L lanes against the CARRIED usage snapshot and
     then revalidating all L lanes' slot-0 commits in one in-kernel pass
-    — `_parallel_kernel.apply_batch`'s cumulative same-node credit
-    generalized from within-batch to cross-lane placement order (lane-
-    major: lane l's placement k revalidates at rank l*K + k).  Serial
-    depth drops from B to B/L; placements a sibling lane beat to a node
-    bounce to STATUS_RETRY with every score slot nulled — exactly the
-    `_parallel_kernel` contract, so the retry stream clears them.
+    — a cumulative same-node credit in cross-lane placement order
+    (lane-major: lane l's placement k revalidates at rank l*K + k).
+    Serial depth drops from B to B/L; placements a sibling lane beat to
+    a node bounce to STATUS_RETRY with every score slot nulled, so the
+    retry stream clears them.
 
-    Unlike `_parallel_kernel`, the lanes keep the caller's shortlist:
-    `lane_axis` makes the carried/full wave cond lane-UNIFORM (a psum
-    over the vmap axis is unbatched, so the cond stays a real branch —
-    see kernel.py), fixing the PR 4 cond→select overhead that forced
-    `shortlist_c=-1` and the pinned full-rescore on vmapped lanes.
+    The lanes keep the caller's shortlist: `lane_axis` makes the
+    carried/full wave cond lane-UNIFORM (a psum over the vmap axis is
+    unbatched, so the cond stays a real branch — see kernel.py); a
+    plain vmap would turn the cond into a select that runs both
+    branches on every lane.
 
     B must be a multiple of `lanes` (the host pads with n_place=0 rows).
     Preemption streams stay on the serial kernel: cross-lane
@@ -1483,38 +1403,6 @@ class ResidentSolver:
                         "across them")
                 seen |= keys
 
-    def solve_parallel(self, batches: Sequence[PackedBatch]
-                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                  np.ndarray]:
-        """Optimistic-parallel variant of solve_stream: all B batches
-        solve concurrently against the CURRENT usage snapshot (each with
-        a distinct tie-break seed), then a serial on-device revalidation
-        pass commits them in order and bounces placements that no longer
-        fit — the reference's worker/plan-applier split, fused into one
-        device call.  Bounced placements come back STATUS_RETRY with all
-        score slots nulled; the caller resubmits them in a later stream.
-        Higher throughput than solve_stream, weaker in-batch visibility
-        (batches don't see each other's scoring state at all, only the
-        revalidation)."""
-        self._check_stream_jobs(batches)
-        self._check_batch_axis(batches)
-        stacked = self._stack_args(batches)
-        n_places = np.asarray([pb.n_place for pb in batches], np.int32)
-        seeds = np.arange(1, len(batches) + 1, dtype=np.int32)
-        self._used, self._dev_used, out = _parallel_kernel(
-            self._dev_node["avail"], self._dev_node["reserved"],
-            self._dev_node["valid"], self._dev_node["node_dc"],
-            self._dev_node["attr_rank"], self._dev_node["dev_cap"],
-            self._used, self._dev_used, stacked, n_places, seeds,
-            has_spread=self._has_spread(batches),
-            group_count_hint=self._group_count_hint(batches),
-            max_waves=self.max_waves,
-            has_distinct=self._has_distinct(batches),
-            has_devices=self._has_devices(batches))  # wave_mode: the parallel
-        # kernel's vmap over sibling batches always wants "while" (its
-        # default) — a cond would run every budget wave for every lane
-        return self._unpack(out)
-
     def _staged_stack(self, name: str, mats) -> np.ndarray:
         """Pow2-bucketed preallocated staging for the fused path's
         B>1 stacked ask planes (ISSUE 20 satellite): `np.stack`
@@ -1550,8 +1438,7 @@ class ResidentSolver:
         streams over a fixed node/ask universe must not grow this —
         every new entry is a silent recompile eating the PR 1/2 wins."""
         return sum(fn._cache_size() for fn in
-                   (_stream_kernel, _parallel_kernel,
-                    _lane_stream_kernel))
+                   (_stream_kernel, _lane_stream_kernel))
 
     def usage(self) -> Tuple[np.ndarray, np.ndarray]:
         """Fetch the carried device usage (one sync — call sparingly)."""

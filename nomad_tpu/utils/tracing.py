@@ -350,8 +350,12 @@ class FlightRecorder:
         now = _time.monotonic()
         sp = self.stage(trace_id, name, **attrs)
         if sp is not NULL_SPAN:
+            # the row ends at the stamp the sample is cut at: a second
+            # read of the clock, after `stage` took its lock, made the
+            # row longer than its own sample
             sp.t_start = min(t_start, now)
-            sp.end()
+            sp.t_end = now
+            self._record(sp)
         global_metrics.add_sample(key or "span." + name,
                                   max(now - t_start, 0.0))
 

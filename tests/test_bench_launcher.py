@@ -58,7 +58,7 @@ def test_parent_never_initialises_a_jax_backend(tmp_path):
         assert not xla_bridge.backends_are_initialized(), \\
             "bench.py's parent initialised a JAX backend"
         assert calls.count("--one") == len(bench.CONFIGS)
-        assert "--analysis" in calls and "--open-loop" in calls
+        assert "--analysis" in calls and "--overcommit" in calls
         detail = json.load(open({str(tmp_path / "BENCH_DETAIL.json")!r}))
         assert "skipped" not in json.dumps(detail)
         print("PARENT-OFF-JAX")

@@ -3,8 +3,8 @@ recover the serial scan bit-for-bit at L=1, reach the same terminal
 placements as the serialized scan after the retry drain at L>1, and
 never lose a bounced placement — a bounce is STATUS_RETRY, never a
 drop.  Plus the host-side machinery: conflict-aware chunk formation
-(form_lanes), the adaptive lane-width controller, the B>1 stream-stack
-cache, and the coordinator's lane_former hook."""
+(form_lanes), the adaptive lane-width controller and the B>1
+stream-stack cache."""
 import copy
 import os
 
@@ -13,8 +13,7 @@ import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.chaos.invariants import InvariantHarness
-from nomad_tpu.scheduler.fleet import (LaneWidthController,
-                                       SolveCoordinator, form_lanes)
+from nomad_tpu.scheduler.fleet import LaneWidthController, form_lanes
 from nomad_tpu.solver.resident import ResidentSolver
 from nomad_tpu.solver.solve import _run_kernel, solve_trace_attrs
 from nomad_tpu.solver.tensorize import PlacementAsk
@@ -350,33 +349,3 @@ def test_lane_counters_feed_solve_trace_attrs():
     _solve(rs, [batches[0]])
     assert rs.lane_counters() is None
     assert "lanes" not in solve_trace_attrs(pb, res)
-
-
-def test_coordinator_lane_former_reorders_drain_round():
-    """The drain leader must pass each fused round's combined member
-    list through lane_former at the controller's width before
-    dispatch."""
-    calls = {}
-
-    def former(members, width):
-        calls["width"] = width
-        calls["n"] = len(members)
-        return list(reversed(members))
-
-    got = []
-
-    def solve_fn(_server, _worker, combined):
-        got.extend(combined)
-
-    ctrl = LaneWidthController(max_width=8, start=4)
-    coord = SolveCoordinator(None, max_fused=16, solve_fn=solve_fn,
-                             lane_former=former, lane_controller=ctrl)
-    coord.pause()
-    subs = [coord.submit_nowait(f"w{i}", [(f"ev{i}", f"tok{i}")])
-            for i in range(3)]
-    coord.resume()
-    for s in subs:
-        assert s.done.wait(10.0)
-        assert s.error is None
-    assert calls == {"width": 4, "n": 3}
-    assert got == [("ev2", "tok2"), ("ev1", "tok1"), ("ev0", "tok0")]

@@ -229,6 +229,28 @@ def test_fused_round_spans_and_one_replay_span_per_replay(monkeypatch):
         assert solve[0]["attrs"]["fused"] is True
 
 
+def test_a_wait_is_one_stamp_for_its_row_and_its_sample(monkeypatch):
+    """`waited` cuts the row and the sample at ONE read of the clock:
+    however long the recorder takes to open the row (a lock, an id; a
+    preempted thread under load), the row is no longer than its own
+    sample."""
+    real = global_tracer.stage
+
+    def slow_stage(*a, **kw):
+        time.sleep(0.005)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(global_tracer, "stage", slow_stage)
+    before = _samples()
+    global_tracer.waited("test.wait", time.monotonic() - 0.010,
+                         "trace-of-a-wait")
+    row, = global_tracer.get("trace-of-a-wait")
+    count, total = _grew(before, _samples(), "span.test.wait")
+    assert count == 1 and row["name"] == "test.wait"
+    assert row["dur_s"] >= 0.010
+    assert total == pytest.approx(row["dur_s"], abs=2e-6)   # 6 digits
+
+
 def test_recorder_off_still_writes_the_samples(monkeypatch):
     monkeypatch.setattr(global_tracer, "enabled", False)
     server = _server()

@@ -131,33 +131,6 @@ def test_solve_stream_carries_usage_and_matches_sequential():
     assert ok[:2, :4, 0].all()          # first two batches place fully
 
 
-def test_solve_parallel_never_overcommits_and_marks_bounces_retryable():
-    """Optimistic batches collide on a tight cluster: the revalidation
-    pass must keep total committed usage within capacity and mark every
-    bounced placement STATUS_RETRY (2), never STATUS_FAILED (0)."""
-    nodes = make_nodes(4)
-    for nd in nodes:
-        nd.node_resources.cpu = 2000
-        nd.node_resources.memory_mb = 8192
-    rs = ResidentSolver(nodes, [make_ask(count=4)], gp=2, kp=8)
-    # 4 batches x 4 placements x 900cpu = 14400 asked vs 8000 capacity
-    batches = [rs.pack_batch([make_ask(count=4, cpu=900)])
-               for _ in range(4)]
-    choice, ok, score, status = rs.solve_parallel(batches)
-    committed = int((status[:, :4] == 1).sum())
-    assert committed <= 8000 // 900
-    used, _ = rs.usage()
-    assert (used[:4, 0] <= 2000).all(), "node capacity must hold"
-    assert used[:, 0].sum() == pytest.approx(900 * committed)
-    # everything not committed was solve-time-ok (capacity existed in
-    # the shared snapshot) so it must be retryable, not failed
-    rest = status[:, :4][status[:, :4] != 1]
-    assert (rest == 2).all()
-    # bounced placements expose no stale fall-through candidates
-    bounced = (status[:, :4] == 2)
-    assert not ok[..., :4, :][bounced].any()
-
-
 def test_solve_stream_capacity_exhaustion_fails_late_batches():
     nodes = make_nodes(4)
     for nd in nodes:

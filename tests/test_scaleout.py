@@ -304,11 +304,13 @@ def test_submitter_waits_out_a_slow_round_and_abandons_only_orphans(
     release = threading.Event()
     solved = []
 
-    def slow_solve(_server, _worker, combined):
+    def slow_dispatch(_server, _worker, combined):
         release.wait(10.0)
-        solved.append(len(combined))
+        return combined
 
-    coord = SolveCoordinator(None, max_fused=1, solve_fn=slow_solve)
+    coord = SolveCoordinator(
+        None, max_fused=1, dispatch_fn=slow_dispatch,
+        finish_fn=lambda _server, _worker, rnd: solved.append(len(rnd)))
     errors = []
 
     def submit(tag):
@@ -639,13 +641,45 @@ def test_scaleout_smoke_sharded_workers_coordinator():
 # ------------------------------------------------------------------
 # Pipelined coordinator (ISSUE 19): seeded parity + async fan-back
 # ------------------------------------------------------------------
-def _coordinator_run(n_jobs, n_workers, pipeline, seed):
-    """One seeded scenario through a SolveCoordinator: shuffle the
-    dequeued evals, deal them round-robin to `n_workers` submitters,
-    release them against a paused coordinator with `pipeline` on or
-    off.  max_fused=4 forces multiple rounds, so the pipelined drain
-    actually overlaps round b+1's reconcile with round b's solve.
-    Returns (placements, eval statuses) — the full observable state."""
+_MAX_FUSED = 4
+
+
+def _drive_coordinator(server, workers, shares):
+    """Release every share against a paused SolveCoordinator.
+    max_fused=4 forces multiple rounds, so the drain actually overlaps
+    round b+1's reconcile with round b's solve."""
+    coord = SolveCoordinator(server, max_fused=_MAX_FUSED)
+    coord.pause()
+    threads = [threading.Thread(target=coord.submit, args=(w, share))
+               for w, share in zip(workers, shares)]
+    for t in threads:
+        t.start()
+    assert wait_until(lambda: coord.pending() == len(threads),
+                      timeout=5.0)
+    coord.resume()
+    for t in threads:
+        t.join(timeout=60.0)
+        assert not t.is_alive()
+
+
+def _drive_serialized(server, workers, shares):
+    """The rounds the coordinator forms (whole submissions popped in
+    order until max_fused evals are reached), each run end to end by
+    `process_fleet` on the first submitter's worker: nothing in
+    flight."""
+    queue = list(shares)
+    while queue:
+        combined = []
+        while queue and len(combined) < _MAX_FUSED:
+            combined.extend(queue.pop(0))
+        process_fleet(server, workers[0], combined)
+
+
+def _coordinator_run(n_jobs, n_workers, seed, drive=_drive_coordinator):
+    """One seeded scenario: shuffle the dequeued evals, deal them
+    round-robin to `n_workers` submitters and let `drive` solve the
+    shares.  Returns (placements, eval statuses) — the full observable
+    state."""
     server = Server(num_workers=0)
     server.start()
     try:
@@ -655,23 +689,10 @@ def _coordinator_run(n_jobs, n_workers, pipeline, seed):
         batch = server.broker.dequeue_batch(["service"], n_jobs, 1.0)
         assert len(batch) == n_jobs
         random.Random(seed).shuffle(batch)
-        coord = SolveCoordinator(server, max_fused=4, pipeline=pipeline)
-        assert coord.pipeline is bool(pipeline)
-        coord.pause()
         workers = [Worker(server, ["service"], index=i)
                    for i in range(n_workers)]
         shares = [batch[k::n_workers] for k in range(n_workers)]
-        threads = [threading.Thread(target=coord.submit,
-                                    args=(workers[k], shares[k]))
-                   for k in range(n_workers) if shares[k]]
-        for t in threads:
-            t.start()
-        assert wait_until(lambda: coord.pending() == len(threads),
-                          timeout=5.0)
-        coord.resume()
-        for t in threads:
-            t.join(timeout=60.0)
-            assert not t.is_alive()
+        drive(server, workers, shares)
         assert server.broker.stats()["total_unacked"] == 0
         statuses = {j.id: server.store.evals_by_job("default", j.id)[0]
                     .status for j in jobs}
@@ -685,9 +706,10 @@ def _coordinator_run(n_jobs, n_workers, pipeline, seed):
 def test_pipelined_coordinator_matches_serialized(n_workers, pallas,
                                                   monkeypatch):
     """ISSUE 19 property: the async double-buffered drain must place
-    EXACTLY what the PR-17 serialized drain places — same placements,
-    same eval statuses — across worker counts and with the pallas
-    scoring kernel forced on (interpreted on CPU) or off.  Round b+1
+    EXACTLY what the same rounds place run serially, one `process_fleet`
+    after another — same placements, same eval statuses — across
+    worker counts and with the pallas scoring kernel forced on
+    (interpreted on CPU) or off.  Round b+1
     reconciles against a snapshot that excludes round b's uncommitted
     plans; with dc-pinned jobs the solves are independent, so any
     divergence is a pipelining bug, not optimistic-concurrency slack."""
@@ -697,12 +719,72 @@ def test_pipelined_coordinator_matches_serialized(n_workers, pallas,
     PK.enabled.cache_clear()
     try:
         n_jobs, seed = 8, 1900 + n_workers
-        serialized = _coordinator_run(n_jobs, n_workers, False, seed)
-        pipelined = _coordinator_run(n_jobs, n_workers, True, seed)
+        serialized = _coordinator_run(n_jobs, n_workers, seed,
+                                      drive=_drive_serialized)
+        pipelined = _coordinator_run(n_jobs, n_workers, seed)
         assert pipelined == serialized
         assert all(len(v) == 2 for v in pipelined[0].values())
     finally:
         PK.enabled.cache_clear()
+
+
+def _recording_coordinator(calls, fail_on=()):
+    """A SolveCoordinator on recording fakes through its seam: one
+    eval a round, every half-round appended to `calls`."""
+    def dispatch(_server, _worker, batch):
+        tag = batch[0][0]
+        calls.append(("dispatch", tag))
+        if tag in fail_on:
+            raise RuntimeError(f"dispatch {tag}")
+        return tag
+
+    def finish(_server, _worker, rnd):
+        calls.append(("finish", rnd))
+
+    return SolveCoordinator(None, max_fused=1, dispatch_fn=dispatch,
+                            finish_fn=finish)
+
+
+def test_coordinator_is_wired_and_pipelined_whatever_the_environment(
+        monkeypatch):
+    """The switches are gone: a multi-worker Server fuses through a
+    coordinator, and the coordinator drains with one round in flight —
+    round b+1's dispatch before round b's finish — also where an
+    environment still carries the two variables that used to turn
+    either off."""
+    for gone in ("PIPELINE", "COORDINATOR"):
+        monkeypatch.setenv("NOMAD_TPU_" + gone, "0")
+    server = Server(num_workers=2)
+    assert isinstance(server.solve_coordinator, SolveCoordinator)
+    assert Server(num_workers=1).solve_coordinator is None
+
+    calls = []
+    coord = _recording_coordinator(calls)
+    coord.pause()
+    subs = [coord.submit_nowait(f"w{tag}", [(tag, "tok")])
+            for tag in ("a", "b")]
+    coord.resume()
+    assert all(s.done.is_set() and s.error is None for s in subs)
+    assert calls == [("dispatch", "a"), ("dispatch", "b"),
+                     ("finish", "a"), ("finish", "b")]
+
+
+def test_failed_dispatch_releases_its_round_and_the_one_in_flight_finishes():
+    """A dispatch half that raises hands the error to every submitter
+    of ITS round, at once; the round already in flight is still
+    finished and released clean, and the drain goes on to the next."""
+    calls = []
+    coord = _recording_coordinator(calls, fail_on=("b",))
+    coord.pause()
+    subs = {tag: coord.submit_nowait(f"w{tag}", [(tag, "tok")])
+            for tag in ("a", "b", "c")}
+    coord.resume()
+    assert all(s.done.is_set() for s in subs.values())
+    assert subs["a"].error is None and subs["c"].error is None
+    assert isinstance(subs["b"].error, RuntimeError)
+    assert calls == [("dispatch", "a"), ("dispatch", "b"),
+                     ("finish", "a"), ("dispatch", "c"), ("finish", "c")]
+    assert coord.pending() == 0
 
 
 def test_async_fanback_conservation_storm():

@@ -275,7 +275,7 @@ def test_two_node_cluster_role_gated_raft(pki):
     import time as _time
 
     from nomad_tpu.rpc.endpoints import serve_cluster
-    servers, _rpcs, _addrs = serve_cluster(
+    servers, server_rpcs, _addrs = serve_cluster(
         n=2, num_workers=0,
         tls_server=tlsutil.server_context(pki["server.global.nomad"]),
         tls_client=tlsutil.client_context(pki["server.global.nomad"]),
@@ -290,4 +290,6 @@ def test_two_node_cluster_role_gated_raft(pki):
             "role-gated raft failed to elect"
     finally:
         for s in servers:
-            s.shutdown()
+            s.stop()
+        for r in server_rpcs:
+            r.rpc.stop()
