@@ -108,19 +108,19 @@ class StateFSM:
             from_wire(DesiredTransition, p["transition"]))
 
     def _ap_plan_result(self, index, p):
-        result = from_wire(PlanResult, p["result"])
-        job = from_wire(Job, p["job"]) if p.get("job") is not None else None
-        self.store.upsert_plan_results(index, result, job)
+        # the entry carries its job once; the store hands that one
+        # object to every placement that came without a job of its own
+        # (an entry from before ISSUE 33 has one on each, and keeps it)
+        self.store.upsert_plan_results(
+            index, from_wire(PlanResult, p["result"]),
+            from_wire(Job, p.get("job")))
 
     def _ap_plan_results_batch(self, index, p):
         # group commit (ISSUE 17): K plan results in one log entry, in
         # submission order, all under the shared commit index — the same
         # store state K consecutive plan_result entries would produce
         for item in p["items"]:
-            result = from_wire(PlanResult, item["result"])
-            job = from_wire(Job, item["job"]) \
-                if item.get("job") is not None else None
-            self.store.upsert_plan_results(index, result, job)
+            self._ap_plan_result(index, item)
 
     def _ap_job_stability(self, index, p):
         self.store.update_job_stability(index, p["namespace"],

@@ -12,6 +12,7 @@ workers, heartbeater, watchers and plan applier
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time as _time
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -32,6 +33,7 @@ from ..structs import (ALLOC_CLIENT_FAILED, CORE_JOB_PRIORITY,
                        SCHEDULERS, Allocation, Evaluation, Job, Node, Plan,
                        PlanResult)
 from ..utils.ids import generate_uuid
+from ..utils.metrics import global_metrics
 from ..utils.timetable import TimeTable
 from .blocked_evals import BlockedEvals
 from .eval_broker import EvalBroker
@@ -40,6 +42,44 @@ from .periodic import PeriodicDispatcher
 from .plan_apply import PlanApplier
 from .plan_queue import PlanQueue
 from .worker import Worker
+
+
+_ALLOC_FIELDS = tuple(f.name for f in dataclasses.fields(Allocation))
+
+
+def _plan_entry(plan: Plan, result: PlanResult) -> dict:
+    """The payload of one plan's `plan_result` entry (one item of a
+    `plan_results_batch`): the plan's job ONCE, beside the result.  A
+    placement whose `job` IS the plan's job goes out with `"job": null`
+    and the job is never walked for it; `StateStore.upsert_plan_results`
+    hands the entry's one decoded job to every placement that came
+    without (reference: Plan.AppendAlloc strips the job,
+    UpsertPlanResults puts it back).  An alloc that carries some other
+    job object keeps it on the wire.  Nothing in memory is mutated: the
+    scheduler, the overlay and the worker's refresh still read
+    `a.job` off these allocs."""
+    job = plan.job
+    # Job trees walked for this entry (counter plan.jobs_encoded)
+    walked = 0 if job is None else 1
+    for allocs in (*result.node_update.values(),
+                   *result.node_preemptions.values()):
+        walked += sum(a.job is not None for a in allocs)
+    node_allocation = {}
+    for node_id, allocs in result.node_allocation.items():
+        rows = node_allocation[node_id] = []
+        for a in allocs:
+            if a.job is job:
+                rows.append({name: None if name == "job"
+                             else to_wire(getattr(a, name))
+                             for name in _ALLOC_FIELDS})
+            else:
+                walked += a.job is not None
+                rows.append(to_wire(a))
+    wire = to_wire(dataclasses.replace(result, node_allocation={}))
+    wire["node_allocation"] = node_allocation
+    global_metrics.incr_counter("plan.jobs_encoded", walked)
+    return {"result": wire,
+            "job": to_wire(job) if job is not None else None}
 
 
 class JobValidationError(ValueError):
@@ -1149,9 +1189,7 @@ class Server:
         }
 
     def _apply_plan(self, plan: Plan, result: PlanResult) -> int:
-        index = self._propose("plan_result", {
-            "result": to_wire(result),
-            "job": to_wire(plan.job) if plan.job is not None else None})
+        index = self._propose("plan_result", _plan_entry(plan, result))
         self._claim_csi_for_placements(plan, result)
         return index
 
@@ -1160,9 +1198,8 @@ class Server:
         (index, finish_fn) — finish_fn blocks until the entry is
         applied and then claims CSI volumes.  The applier pipelines
         plan N+1's evaluation under plan N's consensus round trip."""
-        index, wait = self.raft.propose_async("plan_result", {
-            "result": to_wire(result),
-            "job": to_wire(plan.job) if plan.job is not None else None})
+        index, wait = self.raft.propose_async(
+            "plan_result", _plan_entry(plan, result))
 
         def finish(timeout: float = 10.0) -> int:
             ix = wait(timeout)
@@ -1178,10 +1215,7 @@ class Server:
         the shared commit index, which is the same store state K chained
         single applies would produce."""
         index, wait = self.raft.propose_async("plan_results_batch", {
-            "items": [{
-                "result": to_wire(result),
-                "job": to_wire(plan.job) if plan.job is not None else None,
-            } for plan, result in items]})
+            "items": [_plan_entry(plan, result) for plan, result in items]})
 
         def finish(timeout: float = 10.0) -> int:
             ix = wait(timeout)

@@ -136,9 +136,9 @@ def layers(cluster):
     return layers
 
 
-#: ISSUE 31's three metrics: the layer each names, an `Observed` that
-#: holds what it reads and what it then says, and which key a program
-#: from before the change lacks
+#: ISSUE 31's three metrics and ISSUE 33's one: the layer each names,
+#: an `Observed` that holds what it reads and what it then says, and
+#: which key a program from before the change lacks
 STORE_METRICS = {
     "plan_snapshot_ms": (
         "Plan apply", {"samples": {"span.plan.snapshot": (0.9, 300)}},
@@ -150,6 +150,11 @@ STORE_METRICS = {
     "gc_full_collections": (
         "Interpreter", {"samples": {"span.gc.full": (2.4, 3)}},
         3.0, "samples"),
+    # ISSUE 33's: Job trees the plan proposer walked, an eval
+    "jobs_encoded_per_eval": (
+        "Plan apply", {"counters": {"plan.jobs_encoded": 336.0},
+                       "harness": {"evals_completed": 320}},
+        1.05, "counters"),
 }
 
 
