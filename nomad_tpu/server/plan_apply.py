@@ -40,6 +40,10 @@ from .plan_queue import PendingPlan, PlanQueue
 ApplyFn = Callable[[Plan, PlanResult], int]
 
 
+#: allocs_fit's reasons on the network dimension (structs/funcs.py)
+_NETWORK_REASONS = ("reserved port collision", "bandwidth exceeded")
+
+
 def evaluate_node_plan(snapshot, plan: Plan, node_id: str
                        ) -> Tuple[bool, str]:
     """Can this node accommodate the plan's allocations for it?
@@ -150,13 +154,18 @@ def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
     partial = False
     node_ids = list(plan.node_allocation)
     if len(node_ids) >= _POOL_MIN_NODES:
-        oks = list(_verify_pool().map(
-            lambda nid: evaluate_node_plan(snapshot, plan, nid)[0],
+        verdicts = list(_verify_pool().map(
+            lambda nid: evaluate_node_plan(snapshot, plan, nid),
             node_ids))
     else:
-        oks = [evaluate_node_plan(snapshot, plan, nid)[0]
-               for nid in node_ids]
-    for node_id, ok in zip(node_ids, oks):
+        verdicts = [evaluate_node_plan(snapshot, plan, nid)
+                    for nid in node_ids]
+    # nodes allocs_fit refused on the network dimension: written for
+    # every plan, so the key is there where it reads 0
+    from ..utils.metrics import global_metrics as _m
+    _m.incr_counter("plan.port_refused", sum(
+        1 for _ok, why in verdicts if why in _NETWORK_REASONS))
+    for node_id, (ok, _why) in zip(node_ids, verdicts):
         if ok:
             result.node_allocation[node_id] = plan.node_allocation[node_id]
             if node_id in plan.node_preemptions:

@@ -1,9 +1,14 @@
 """Solver orchestration: pack -> device solve -> unpack into placements.
 
-The discrete leftovers the tensor solve can't express (exact port picking,
-device instance IDs — SURVEY §7.3) are fixed up host-side here, walking the
-kernel's top-K candidates per placement so a port/instance conflict falls
-through to the next-best node instead of failing the eval.
+The discrete leftovers the tensor solve can't express (which dynamic
+ports, the per-address collision check, device instance IDs — SURVEY
+§7.3) are fixed up host-side here, walking the kernel's top-K candidates
+per placement so a port/instance conflict falls through to the next-best
+node instead of failing the eval.  A static port a group reserves is a
+counted column of the wave (tensorize.py, "Counted columns"), so the
+candidates already have it free; a placement whose candidates the
+network assignment all refused while the wave saw more placeable nodes
+is retried, not failed, and names its `network:` dimension.
 """
 from __future__ import annotations
 
@@ -24,7 +29,7 @@ from .kernel import TOP_K, solve_kernel
 from .tensorize import (NUM_R, ClusterDelta, PackedBatch, PlacementAsk,
                         Tensorizer, alloc_device_usage,
                         alloc_usage_vector, apply_node_delta_host,
-                        evict_width,
+                        evict_width, static_port_columns,
                         R_CPU, R_DISK, R_MEM, R_NET)
 
 _DIM_NAMES = {R_CPU: "cpu", R_MEM: "memory", R_DISK: "disk", R_NET: "network"}
@@ -388,6 +393,33 @@ class _DeviceTally:
                                   for got in task)
 
 
+class _PortTally:
+    """What the network asks of one solve cost its host fixup, summed
+    over the placements by `Solver._host_commit` as `_DeviceTally` sums
+    the device asks; a solve whose groups ask for no network leaves it
+    at zero and nothing is written."""
+
+    __slots__ = ("commits", "seconds", "assigned", "refused", "reason")
+
+    def __init__(self):
+        self.commits = 0        # _host_commit calls whose group asks
+        self.seconds = 0.0      # ... and the time of their network part
+        self.assigned = 0       # ports handed out, static and dynamic
+        self.refused = 0        # candidate nodes assign_network refused
+        self.reason = ""        # ... and why the last of them
+
+    def add(self, offers: Optional[list], reason: str,
+            seconds: float) -> None:
+        self.commits += 1
+        self.seconds += seconds
+        if offers is None:
+            self.refused += 1
+            self.reason = reason
+        else:
+            self.assigned += sum(len(o.reserved_ports)
+                                 + len(o.dynamic_ports) for o in offers)
+
+
 class PendingSolve:
     """An in-flight fused solve: packed and dispatched to the device,
     fetch + host fixup deferred.  `wait()` is the ONLY blocking step —
@@ -742,6 +774,7 @@ class Solver:
         net_cache: Dict[int, NetworkIndex] = {}
         dev_cache: Dict[int, DeviceAccounter] = {}
         dev_tally = _DeviceTally()
+        port_tally = _PortTally()
         host_used = pb.used0.copy()
         chosen_by_ask: Dict[int, set] = {}
         # distinct_property charges shared batch-wide by (scope, target) key
@@ -794,13 +827,14 @@ class Solver:
                 placed = self._evict_commit(
                     int(choice[p, 0]), g, ask, pb, sol_nodes,
                     allocs_by_node, evict[p], host_used,
-                    float(score[p, 0]), m, dev_tally)
+                    float(score[p, 0]), m, dev_tally, port_tally)
                 if placed is not None:
                     by_p[p] = placed
                     continue
                 # discrete fixup failed (ports, stale victim view):
                 # fall through as a normal failure — the scheduler's
                 # host-side preemption walk remains the safety net
+            net_refused: Dict[str, int] = {}
             for k in range(TOP_K):
                 if not choice_ok[p, k]:
                     break
@@ -814,10 +848,14 @@ class Solver:
                 prop_vals = self._property_fit(node, ask, prop_used)
                 if prop_vals is None:
                     continue
+                refused0 = port_tally.refused
                 resources = self._host_commit(node, ni, ask, net_cache,
                                               dev_cache, allocs_by_node,
-                                              dev_tally)
+                                              dev_tally, port_tally)
                 if resources is None:
+                    if port_tally.refused > refused0:
+                        why = "network: " + port_tally.reason
+                        net_refused[why] = net_refused.get(why, 0) + 1
                     continue
                 host_used[ni] += ask_vec
                 if gid >= 0:
@@ -834,17 +872,33 @@ class Solver:
                                    resources=resources)
                 break
             if placed is None:
-                if unfinished[p]:
+                retryable = bool(unfinished[p])
+                if retryable:
                     # the wave budget ran out before this placement was
                     # decided; the scheduler's retry loop re-solves it
                     reason = "solve wave budget exhausted (retryable)"
+                elif net_refused:
+                    # the network assignment refused the candidates it
+                    # was offered (rank.go: such a node is exhausted and
+                    # the iterator goes on).  Where the wave saw more
+                    # placeable nodes than were walked, the next solve,
+                    # which starts from this plan's commits, offers
+                    # them: retry, as for a placement left undecided
+                    n = sum(net_refused.values())
+                    m.nodes_exhausted += n
+                    for why, cnt in net_refused.items():
+                        m.dimension_exhausted[why] = \
+                            m.dimension_exhausted.get(why, 0) + cnt
+                    reason = max(net_refused, key=net_refused.get)
+                    retryable = int(n_feasible[p]) \
+                        - int(n_exhausted[p]) > n
                 elif n_feasible[p] > 0:
                     reason = "resources exhausted"
                 else:
                     reason = "no feasible nodes"
                 placed = Placement(ask_index=g, node=None, score=0.0,
                                    metrics=m, failed_reason=reason,
-                                   retryable=bool(unfinished[p]))
+                                   retryable=retryable)
             by_p[p] = placed
         # emit in ask order regardless of replay order: the scheduler
         # maps placements back to its per-ask missing queues by
@@ -860,6 +914,14 @@ class Solver:
             if spans == "solve":
                 # no metric reads the fused round's
                 _tr.summed("solve.devices", dev_tally.seconds)
+        if port_tally.commits:
+            # and the network part, the same way
+            _m.incr_counter("solver.ports.assigned", port_tally.assigned)
+            _m.incr_counter("solver.ports.refused", port_tally.refused)
+            _m.incr_counter("solver.ports.static_columns",
+                            static_port_columns(pb))
+            if spans == "solve":
+                _tr.summed("solve.ports", port_tally.seconds)
 
         # class eligibility for blocked-eval optimization
         class_elig: List[Dict[str, bool]] = []
@@ -883,7 +945,8 @@ class Solver:
                       pb: PackedBatch, sol_nodes, allocs_by_node,
                       ev_row: np.ndarray, host_used: np.ndarray,
                       score: float, m: AllocMetric,
-                      dev_tally: _DeviceTally) -> Optional[Placement]:
+                      dev_tally: _DeviceTally,
+                      port_tally: _PortTally) -> Optional[Placement]:
         """Host fixup for a kernel-committed (place, evict) pair: map
         the victim-slot mask back to alloc ids through the packed
         `ev_ids` rows, re-check capacity net of the freed usage, and
@@ -916,7 +979,8 @@ class Solver:
             return None
         remaining = [a for a in proposed if a.id not in vset]
         resources = self._host_commit(node, ni, ask, {}, {},
-                                      {node.id: remaining}, dev_tally)
+                                      {node.id: remaining}, dev_tally,
+                                      port_tally)
         if resources is None:
             return None
         host_used[ni] += ask_vec - freed
@@ -931,7 +995,8 @@ class Solver:
                      net_cache: Dict[int, NetworkIndex],
                      dev_cache: Dict[int, DeviceAccounter],
                      allocs_by_node,
-                     dev_tally: Optional[_DeviceTally] = None
+                     dev_tally: Optional[_DeviceTally] = None,
+                     port_tally: Optional[_PortTally] = None
                      ) -> Optional[AllocatedResources]:
         """Build AllocatedResources with real ports + device instance ids.
 
@@ -941,16 +1006,19 @@ class Solver:
         Returns None if the discrete assignment fails on this node.
         The node's DeviceAccounter is built, and `dev_tally` (a
         solve's; the schedulers' own walks keep none) written, only
-        where a task of the group asks for a device.
+        where a task of the group asks for a device; `port_tally` only
+        where the group asks for a network.
         """
-        idx = net_cache.get(node_ix)
+        t0 = _t.perf_counter()
+        idx, net_offers, why = Solver._network_offers(
+            node, node_ix, ask, net_cache, allocs_by_node)
+        if port_tally is not None and (net_offers or idx is None):
+            port_tally.add(
+                None if idx is None else
+                [o for got in net_offers.values() for o in got],
+                why, _t.perf_counter() - t0)
         if idx is None:
-            idx = NetworkIndex()
-            idx.set_node(node)
-            if allocs_by_node is not None:
-                idx.add_allocs(allocs_by_node.get(node.id, ()))
-            net_cache[node_ix] = idx
-        idx = idx.clone()
+            return None
 
         acct, dev_offers = None, {}
         if any(t.resources.devices for t in ask.tg.tasks):
@@ -968,27 +1036,47 @@ class Solver:
         for t in ask.tg.tasks:
             tr = AllocatedTaskResources(cpu=t.resources.cpu,
                                         memory_mb=t.resources.memory_mb)
-            for ask_net in t.resources.networks:
-                offer, _err = idx.assign_network(ask_net)
-                if offer is None:
-                    return None
-                idx.add_reserved(offer)
-                tr.networks.append(offer)
+            tr.networks.extend(net_offers.get(t.name, ()))
             tr.devices.extend(dev_offers.get(t.name, ()))
             out.tasks[t.name] = tr
-        shared_nets = []
-        for ask_net in ask.tg.networks:
-            offer, _err = idx.assign_network(ask_net)
-            if offer is None:
-                return None
-            idx.add_reserved(offer)
-            shared_nets.append(offer)
         out.shared = AllocatedSharedResources(
-            disk_mb=ask.tg.ephemeral_disk.size_mb, networks=shared_nets)
+            disk_mb=ask.tg.ephemeral_disk.size_mb,
+            networks=net_offers.get(None, []))
         net_cache[node_ix] = idx
         if acct is not None:
             dev_cache[node_ix] = acct
         return out
+
+    @staticmethod
+    def _network_offers(node: Node, node_ix: int, ask: PlacementAsk,
+                        net_cache: Dict[int, NetworkIndex],
+                        allocs_by_node):
+        """The network part of `_host_commit`: a clone of the node's
+        NetworkIndex (built from the node and its allocs at its first
+        touch in a solve) with an offer for every network ask of the
+        group reserved on it, those offers by task name (None: the
+        group's own), and "".  (None, {}, why) where the node cannot
+        serve an ask: the wave counted bandwidth and static ports, the
+        values are settled here."""
+        idx = net_cache.get(node_ix)
+        if idx is None:
+            idx = NetworkIndex()
+            idx.set_node(node)
+            if allocs_by_node is not None:
+                idx.add_allocs(allocs_by_node.get(node.id, ()))
+            net_cache[node_ix] = idx
+        idx = idx.clone()
+        offers: Dict[Optional[str], list] = {}
+        for name, asks in [(t.name, t.resources.networks)
+                           for t in ask.tg.tasks] \
+                + [(None, ask.tg.networks)]:
+            for ask_net in asks:
+                offer, why = idx.assign_network(ask_net)
+                if offer is None:
+                    return None, {}, why
+                idx.add_reserved(offer)
+                offers.setdefault(name, []).append(offer)
+        return idx, offers, ""
 
     @staticmethod
     def _device_offers(node: Node, node_ix: int, ask: PlacementAsk,

@@ -11,6 +11,18 @@ per-ask boolean `host_ok` mask.
 
 Resource dims (R=4): cpu MHz, memory MB, disk MB, network mbits.
 
+Counted columns (D): the `dev_cap` / `dev_used0` / `dev_ask` planes hold
+one column per key of the batch's registry (`dev_pattern_ids`): a device
+pattern (vendor, type, model) counts healthy instances, and a STATIC
+PORT a group of the batch reserves (`port_key`) is a column of capacity
+1 per address of a node, used where a live alloc or the node's own
+reserved ports hold the value, asked 1 by each reservation.  So the wave
+rules out the nodes that hold the port and its conflict sort keeps two
+placements of one batch off the same one; WHICH dynamic ports an alloc
+gets, and the exact per-address collision check, stay with the host
+fixup (`NetworkIndex`).  A batch without a device or a static port adds
+no column.
+
 Boolean plane dtype contract: the eligibility masks packed here
 (`valid`, `dc_ok`, `host_ok`, `penalty`) stay dense bool on the host —
 the interning/memoization layer mutates and compares them row-wise.
@@ -163,10 +175,62 @@ def alloc_usage_vector(alloc) -> np.ndarray:
     return v
 
 
+#: first part of a static port's registry key; no device's vendor
+_PORT = "\x00port"
+
+
+def port_key(port: int) -> Tuple[str, str, str]:
+    """Registry key (`dev_pattern_ids`) of a static port's column."""
+    return (_PORT, "", str(int(port)))
+
+
+def _networks(resources, shared) -> list:
+    return [n for t in resources for n in t.networks] + list(shared)
+
+
+def group_column_asks(tg: TaskGroup) -> Dict[Tuple[str, str, str], float]:
+    """What one instance of the group asks of the counted columns:
+    registry key -> count (device instances; 1 for each reservation of a
+    static port)."""
+    out: Dict[Tuple[str, str, str], float] = {}
+    for t in tg.tasks:
+        for d in t.resources.devices:
+            key = d.id_tuple()
+            out[key] = out.get(key, 0.0) + d.count
+    for n in _networks((t.resources for t in tg.tasks), tg.networks):
+        for p in n.reserved_ports:
+            key = port_key(p.value)
+            out[key] = out.get(key, 0.0) + 1.0
+    return out
+
+
+def node_column_caps(dev_pattern_ids, D: int, node: Node) -> np.ndarray:
+    """[D] capacity row of one node: healthy instances per device
+    pattern; per static port the node's addresses, 0 where the node's
+    own reserved ports hold the value."""
+    from ..structs.resources import device_pattern_matches
+    row = np.zeros(D, np.float32)
+    for dev in node.node_resources.devices:
+        healthy = sum(1 for inst in dev.instances if inst.healthy)
+        for key, dix in dev_pattern_ids.items():
+            if device_pattern_matches(key, dev.id_tuple()):
+                row[dix] += healthy
+    ports = [(int(key[2]), dix) for key, dix in dev_pattern_ids.items()
+             if key[0] == _PORT]
+    if ports:
+        addresses = len({n.ip for n in node.node_resources.networks
+                         if n.device})
+        reserved = set(node.reserved_resources.parsed_ports())
+        for port, dix in ports:
+            row[dix] = 0 if port in reserved else addresses
+    return row
+
+
 def alloc_device_usage(dev_pattern_ids, D: int, alloc
                        ) -> Optional[np.ndarray]:
-    """[D] device-instance usage row for one alloc against a template's
-    interned device patterns, or None when it uses none of them."""
+    """[D] usage row of one alloc against a template's counted columns
+    (device instances per pattern; 1 per held port whose value is a
+    static port's column), or None when it uses none of them."""
     ar = getattr(alloc, "allocated_resources", None)
     if not dev_pattern_ids or ar is None:
         return None
@@ -180,7 +244,22 @@ def alloc_device_usage(dev_pattern_ids, D: int, alloc
                     if row is None:
                         row = np.zeros(D, np.float32)
                     row[dix] += len(ad.device_ids)
+    for n in _networks(ar.tasks.values(), ar.shared.networks):
+        for p in n.reserved_ports + n.dynamic_ports:
+            dix = dev_pattern_ids.get(port_key(p.value)) if p.value \
+                else None
+            if dix is not None:
+                if row is None:
+                    row = np.zeros(D, np.float32)
+                row[dix] += 1
     return row
+
+
+def static_port_columns(pb) -> int:
+    """Counted columns of a packed batch that are static ports some ask
+    of the batch reserves."""
+    return sum(1 for key, dix in pb.dev_pattern_ids.items()
+               if key[0] == _PORT and pb.dev_ask[:, dix].any())
 
 
 def _pad_pow2(n: int, floor: int = 8) -> int:
@@ -972,46 +1051,31 @@ class Tensorizer:
                     if r >= 0:
                         sp_used0[g, s, r] = cnt
 
-        # ---- devices ----
-        dev_patterns: List[Tuple[str, str, str]] = []
+        # ---- counted columns: device patterns and static ports ----
         dev_pattern_ix: Dict[Tuple[str, str, str], int] = {}
-        for ask in asks:
-            for t in ask.tg.tasks:
-                for d in t.resources.devices:
-                    key = d.id_tuple()
-                    if key not in dev_pattern_ix:
-                        dev_pattern_ix[key] = len(dev_patterns)
-                        dev_patterns.append(key)
-        D = _pad_pow2(max(len(dev_patterns), 1), floor=1)
+        col_asks = [group_column_asks(ask.tg) for ask in asks]
+        for cols in col_asks:
+            for key in cols:
+                dev_pattern_ix.setdefault(key, len(dev_pattern_ix))
+        D = _pad_pow2(max(len(dev_pattern_ix), 1), floor=1)
         dev_cap = np.zeros((Np, D), np.float32)
         dev_used0 = np.zeros((Np, D), np.float32)
         dev_ask = np.zeros((Gp, D), np.float32)
-        if dev_patterns:
-            from ..structs.resources import device_pattern_matches
+        if dev_pattern_ix:
             for i, n in enumerate(nodes):
-                for dev in n.node_resources.devices:
-                    healthy = sum(1 for inst in dev.instances if inst.healthy)
-                    for key, dix in dev_pattern_ix.items():
-                        if device_pattern_matches(key, dev.id_tuple()):
-                            dev_cap[i, dix] += healthy
-            if allocs_by_node:
-                for nid, allocs in allocs_by_node.items():
-                    i = node_index.get(nid)
-                    if i is None:
-                        continue
-                    for a in allocs:
-                        if a.terminal_status():
-                            continue
-                        for tr in a.allocated_resources.tasks.values():
-                            for ad in tr.devices:
-                                for key, dix in dev_pattern_ix.items():
-                                    if device_pattern_matches(
-                                            key, (ad.vendor, ad.type, ad.name)):
-                                        dev_used0[i, dix] += len(ad.device_ids)
-            for g, ask in enumerate(asks):
-                for t in ask.tg.tasks:
-                    for d in t.resources.devices:
-                        dev_ask[g, dev_pattern_ix[d.id_tuple()]] += d.count
+                dev_cap[i] = node_column_caps(dev_pattern_ix, D, n)
+            for nid, allocs in (allocs_by_node or {}).items():
+                i = node_index.get(nid)
+                if i is None:
+                    continue
+                for a in allocs:
+                    drow = None if a.terminal_status() else \
+                        alloc_device_usage(dev_pattern_ix, D, a)
+                    if drow is not None:
+                        dev_used0[i] += drow
+            for g, cols in enumerate(col_asks):
+                for key, count in cols.items():
+                    dev_ask[g, dev_pattern_ix[key]] += count
 
         # ---- placement schedule ----
         p_ask_list: List[int] = []
@@ -1060,7 +1124,7 @@ class Tensorizer:
             constraint_labels=constraint_labels,
             class_ids=dict(class_interner.items()),
             dc_ids=dict(dc_interner.items()),
-            dev_pattern_ids=dict(dev_pattern_ix),
+            dev_pattern_ids=dev_pattern_ix,
             ask_prio=ask_prio, ev_prio=ev_prio, ev_res=ev_res,
             ev_ids=ev_ids, ev_lists=ev_lists,
         )
@@ -1147,12 +1211,8 @@ class Tensorizer:
                     return None                 # unseen attr value
                 attr_rank[m, col] = r
             if template.dev_pattern_ids:
-                from ..structs.resources import device_pattern_matches
-                for dev in n.node_resources.devices:
-                    healthy = sum(1 for i in dev.instances if i.healthy)
-                    for key, dix in template.dev_pattern_ids.items():
-                        if device_pattern_matches(key, dev.id_tuple()):
-                            dev_cap[m, dix] += healthy
+                dev_cap[m] = node_column_caps(template.dev_pattern_ids,
+                                              D, n)
 
         # ---- removes: valid=False tombstones keeping current rows ----
         for k, nid in enumerate(delta.remove_node_ids):
@@ -1293,6 +1353,7 @@ class Tensorizer:
         add("n")
         for n in tg.networks:
             add(n.mbits)
+            sig.extend(p.value for p in n.reserved_ports)
         for t in tg.tasks:
             add("t"); add(t.driver)
             r = t.resources
@@ -1309,6 +1370,7 @@ class Tensorizer:
             add("tn")
             for n in r.networks:
                 add(n.mbits)
+                sig.extend(p.value for p in n.reserved_ports)
         return tuple(sig)
 
     def repack_asks(self, nodes: Sequence[Node], asks: Sequence[PlacementAsk],
@@ -1516,12 +1578,11 @@ class Tensorizer:
                     if 0 < sum_desired < total_count:
                         row["sp_implicit"][si] = total_count - sum_desired
 
-            for t in ask.tg.tasks:
-                for d in t.resources.devices:
-                    dix = template.dev_pattern_ids.get(d.id_tuple())
-                    if dix is None:
-                        return FALLBACK
-                    row["dev_ask"][dix] += d.count
+            for key, count in group_column_asks(ask.tg).items():
+                dix = template.dev_pattern_ids.get(key)
+                if dix is None:
+                    return FALLBACK
+                row["dev_ask"][dix] += count
             return row
 
         # one cached spec row per distinct ask shape; per-eval state is

@@ -2,8 +2,14 @@
 
 Reference: nomad/structs/network.go `NetworkIndex` :43 — used by the
 bin-pack ranker to offer networks and by the plan applier to re-verify.
-Port picking is inherently discrete/host-side (SURVEY §7.3); the TPU solve
-models bandwidth only and the applier does port fixup with this class.
+Port picking is inherently discrete/host-side (SURVEY §7.3).  The TPU
+solve models bandwidth (the fourth resource column) and each STATIC port
+a group of the batch reserves (a counted column of capacity 1 per
+address, solver/tensorize.py "Counted columns"), so the wave rules out
+the nodes that hold it; which dynamic ports an alloc gets and the exact
+per-address collision check are settled with this class in the solve's
+host fixup (`Solver._host_commit`), and the plan applier re-verifies
+with it (`allocs_fit`).
 """
 from __future__ import annotations
 

@@ -176,3 +176,80 @@ def test_the_store_metrics_read_what_is_there_and_nothing_otherwise(
     if absent_before:
         fields = dict(fields, **{absent_before: {}})
         assert layers.read_metric(name, layers.Observed(**fields)) is None
+
+
+def test_the_ports_deployment_and_its_cell_are_there(cluster):
+    assert "c5-ports-10k" in CONFIGS
+    cell = by_name("workloads", "c5-ports-10k.closed1")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("c5-ports-10k", "closed1", 1)
+    for metric in ("placements_per_s", "reg_to_visible_p50_ms", "setup_s"):
+        assert reports(cell["name"], metric)
+    assert not reports(cell["name"], "reg_to_visible_p95_ms")
+    cfg = cluster.load_config("c5-ports-10k")
+    c2 = cluster.load_config("c2-binpack-10k")
+    assert cfg["rules"] == ["ports"] and cfg["reduced"] == []
+    assert cfg["cluster"] == c2["cluster"]
+    assert {k: v for k, v in cfg["resident"].items() if k != "ports"} \
+        == c2["resident"]
+    assert cfg["ceiling"] == c2["ceiling"]
+    assert cluster.job_count(cfg) == 64
+    assert [g["count"] for g in cluster.job_groups(cfg)] == [4, 20, 20, 20]
+    assert len(cfg["guarantees"]) == len(c2["guarantees"]) + 3
+    limits, controls = cfg["correct"]["limits"], cfg["correct"]["controls"]
+    for number in ("port_collisions", "ports_unmet",
+                   "bandwidth_overcommitted_nodes"):
+        assert limits[number]["limit"] == 0
+    assert set(c2["correct"]["limits"]) <= set(limits)
+    assert controls == c2["correct"]["controls"] + ["ports_unaccounted"]
+    # the ceiling never asks for more nodes with 8080 free than start so
+    r = cfg["resident"]["ports"]
+    free = cfg["cluster"]["nodes"] * (r["static_every"] - 1) \
+        // r["static_every"]
+    assert cluster.ceiling_jobs(cfg) * 4 + 233 <= free
+    # what the program adds for it is read by a metric the cell lists,
+    # and the three device_* metrics are c4's alone
+    for name in ("port_assign_ms", "port_refused_per_solve",
+                 "ports_per_solve", "plan_port_refused_per_eval",
+                 "plan_evaluate_ms", "wave_loop_roofline",
+                 "waves_per_solve", "fixup_ms", "raft_apply_ms"):
+        assert cell["name"] in by_name("per_layer", name)["workloads"]
+    for name in ("device_assign_ms", "device_refused_per_solve",
+                 "device_instances_per_solve"):
+        assert cell["name"] not in by_name("per_layer", name)["workloads"]
+
+
+#: ISSUE 34's four metrics: the layer each names, an `Observed` that
+#: holds what it reads, and what it then says
+PORT_METRICS = {
+    "port_assign_ms": (
+        "Host fixup", {"samples": {"span.solve.ports": (3.0, 500)},
+                       "counters": {"solver.solve.tpu": 500.0}}, 6.0),
+    "port_refused_per_solve": (
+        "Host fixup", {"counters": {"solver.ports.refused": 0.0,
+                                    "solver.solve.tpu": 500.0}}, 0.0),
+    "ports_per_solve": (
+        "Host fixup", {"counters": {"solver.ports.assigned": 54_000.0,
+                                    "solver.solve.tpu": 500.0}}, 108.0),
+    "plan_port_refused_per_eval": (
+        "Plan apply", {"counters": {"plan.port_refused": 5.0},
+                       "harness": {"evals_completed": 500}}, 0.01),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PORT_METRICS))
+def test_the_port_metrics_read_what_is_there_and_nothing_otherwise(
+        layers, name):
+    layer, fields, reads = PORT_METRICS[name]
+    spec = layers.load_metric(name)
+    entry = by_name("per_layer", name)
+    assert spec["layer"] == entry["layer"] == layer
+    assert entry["workloads"] == ["c5-ports-10k.closed1"]
+    assert entry["moves"] == "placements_per_s"
+    assert layers.read_metric(name, layers.Observed(**fields)) \
+        == pytest.approx(reads)
+    # a program without the counter or the sample (the parent): nothing
+    assert layers.read_metric(name, layers.Observed()) is None
+    bare = {k: v for k, v in fields.items() if k == "harness"}
+    bare["counters"] = {"solver.solve.tpu": 500.0}
+    assert layers.read_metric(name, layers.Observed(**bare)) is None

@@ -149,6 +149,10 @@ def test_the_cell_through_server_against_the_reference(
         seen["rows"], seen["plain"] = real_rows(cfg, snapshot, plain), plain
         return seen["rows"]
     monkeypatch.setattr(check, "rows_from_snapshot", rows_from_snapshot)
+    # `run.run` reads `off_device_solves` from the process's counters as
+    # they stand, and an earlier test file of this worker may have left
+    # a degraded solve or a watchdog failover in them
+    global_metrics.reset()
     m0 = device_metrics()
     assert run.run(run.parse_args([
         "--workload", CELL, "--seed", str(seed), "--seconds", "0.5",

@@ -163,6 +163,13 @@ def test_single_server_restart_restores_state(tmp_path):
     s.register_job(job)
     node = mock.node()
     s.register_node(node)
+    # let the job's eval land before the stop: a stop that catches its
+    # plan in flight fails the eval ("plan submission failed") and
+    # nothing re-enqueues it after the restart, so whether this test
+    # passed hung on how fast the first solve was (it failed whenever
+    # the compile cache was warm, at the parent commit too)
+    assert wait_until(lambda: len(
+        s.store.allocs_by_job(job.namespace, job.id)) == 2, timeout=120)
     s.stop()
     # read the head only after stop(): the background worker may commit
     # plans between register_node and shutdown. A propose already past
@@ -334,14 +341,27 @@ def test_lagging_follower_catches_up_via_snapshot(tmp_path):
     try:
         assert wait_until(lambda: any(n.is_leader() for n in nodes[:2]),
                           timeout=10)
-        leader = next(n for n in nodes[:2] if n.is_leader())
+        from nomad_tpu.raft.node import NotLeaderError
+        from nomad_tpu.utils.codec import to_wire
+
+        def propose(entry):
+            # on a loaded machine the two live members may hold another
+            # election (timeouts of 0.10 to 0.25 s): ask whoever leads
+            for _ in range(200):
+                leader = next((n for n in nodes[:2] if n.is_leader()), None)
+                if leader is not None:
+                    try:
+                        leader.propose("node_upsert", entry)
+                        return leader
+                    except NotLeaderError:
+                        pass
+                wait_until(lambda: any(n.is_leader() for n in nodes[:2]),
+                           timeout=10)
+            raise AssertionError("no leader took the entry")
+
         # push enough entries to trigger compaction while s2 is dark
         for i in range(100):
-            mn = mock.node()
-            leader.propose("node_upsert",
-                           {"node": __import__(
-                               "nomad_tpu.utils.codec",
-                               fromlist=["to_wire"]).to_wire(mn)})
+            leader = propose({"node": to_wire(mock.node())})
         assert leader.log.offset > 0, "log must have compacted"
         nodes[2].start()
         assert wait_until(
