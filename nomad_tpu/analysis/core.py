@@ -187,8 +187,8 @@ class AnalysisConfig:
     # here; dynamically-built names are cardinality hazards (OBS802,
     # warn) that carry a baseline justification naming the bound.
     obs_metric_prefixes: Tuple[str, ...] = (
-        "broker", "coordinator", "health", "mesh", "metrics", "plan",
-        "rpc", "scheduler", "serving", "slo", "solver", "state",
+        "broker", "codec", "coordinator", "health", "mesh", "metrics",
+        "plan", "rpc", "scheduler", "serving", "slo", "solver", "state",
         "telemetry", "watchdog", "worker",
     )
     # the sinks themselves (name arrives as a parameter there; the

@@ -136,9 +136,9 @@ def layers(cluster):
     return layers
 
 
-#: ISSUE 31's three metrics and ISSUE 33's one: the layer each names,
-#: an `Observed` that holds what it reads and what it then says, and
-#: which key a program from before the change lacks
+#: ISSUE 31's three metrics, ISSUE 33's one and ISSUE 35's two: the
+#: layer each names, an `Observed` that holds what it reads and what it
+#: then says, and which key a program from before the change lacks
 STORE_METRICS = {
     "plan_snapshot_ms": (
         "Plan apply", {"samples": {"span.plan.snapshot": (0.9, 300)}},
@@ -155,6 +155,15 @@ STORE_METRICS = {
         "Plan apply", {"counters": {"plan.jobs_encoded": 336.0},
                        "harness": {"evals_completed": 320}},
         1.05, "counters"),
+    # ISSUE 35's: what the codec compiled in the window (a window that
+    # compiled nothing reads 0, not nothing), what it could not dispatch
+    "codec_classes_compiled": (
+        "Plan apply", {"counters": {"codec.classes_compiled": 0.0}},
+        0.0, "counters"),
+    "codec_fallbacks_per_eval": (
+        "Plan apply", {"counters": {"codec.fallback": 80.0},
+                       "harness": {"evals_completed": 320}},
+        0.25, "counters"),
 }
 
 

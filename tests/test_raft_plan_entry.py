@@ -264,6 +264,50 @@ def test_alloc_with_another_job_object_keeps_it_on_the_wire_and_in_store():
     assert st.alloc_by_id(allocs[0].id).job.meta == job.meta
 
 
+@pytest.mark.parametrize("shape", sorted(_JOBS))
+def test_entry_is_byte_for_byte_what_the_reflective_codec_wrote(
+        shape, monkeypatch):
+    """ISSUE 35 compiled the codec; the entry on the wire is the one
+    commit c8d58ca wrote for the same plan (the golden string is built
+    by that commit's `to_wire`, kept in `test_codec.py` as the plain
+    reference), and a log that holds old-form and new entries written
+    by either codec replays, through either decoder, to equal stores."""
+    from nomad_tpu.raft import fsm as fsm_module
+    from nomad_tpu.server import server as server_module
+    from test_codec import ref_from_wire, ref_to_wire
+
+    def dumps(payload):
+        return json.dumps(payload, separators=(",", ":"))
+
+    nodes = [mock.node() for _ in range(8)]
+    first, second = _c3_job(n_groups=1), _JOBS[shape]()
+    plans = [_plan_of(j, _placements(j, nodes, 16)) for j in (first, second)]
+
+    def log():
+        # to_wire is read off the module at each call, so the patch
+        # below writes the same log through the reference
+        return (_base_entries(nodes, [first, second])
+                + [("plan_result", _old_entry(*plans[0])),
+                   ("plan_result", _plan_entry(*plans[1])),
+                   ("plan_results_batch",
+                    {"items": [_plan_entry(*plans[0])]})])
+
+    compiled = log()
+    with monkeypatch.context() as m:
+        m.setattr(server_module, "to_wire", ref_to_wire)
+        m.setitem(globals(), "to_wire", ref_to_wire)
+        golden = log()
+    assert [dumps(p) for _, p in compiled] == [dumps(p) for _, p in golden]
+    assert len(dumps(compiled[-2][1])) > 10_000
+
+    by_compiled = _tables(_replay(compiled))
+    monkeypatch.setattr(fsm_module, "from_wire", ref_from_wire)
+    by_reference = _tables(_replay(golden))
+    assert by_compiled == by_reference
+    assert len(by_compiled["tables"]["allocs"]) \
+        == 16 + 16 * len(second.task_groups)
+
+
 def _single_voter(data_dir):
     fsm = StateFSM(StateStore())
     node = RaftNode(RaftConfig(node_id="s1", peers=[], data_dir=data_dir,
