@@ -298,6 +298,7 @@ class StateFSM:
             st._t["_allocs_by_node"] = by_node
             st._t["_allocs_by_job"] = by_job
             st._disown_indexes_locked()
+            st._drop_ready_view_locked()
             st._ix = dict(snap.get("table_indexes", {}))
             st.index = snap.get("latest_index", 0)
             st._watch.notify_all()

@@ -243,15 +243,11 @@ def fleet_begin(server, worker, batch: List[Tuple[Evaluation, str]]
         snapshot = server.store.snapshot()
         rnd.snapshot = snapshot
 
-        # one shared world for the whole batch — including the node-id map
-        # and dc counts every member's prepare pass reads (the per-eval
-        # rebuild of node_by_id over a 2k-node list was pure burn)
-        nodes = [n for n in snapshot.nodes() if n.ready()]
+        # one shared world for the whole batch — the ready nodes of every
+        # dc (each member's ask carries its dc mask), their id map and dc
+        # counts, from the snapshot's shared view (read-only)
+        nodes, by_dc, node_by_id = snapshot.ready_node_view(["*"])
         rnd.nodes = nodes
-        node_by_id = {n.id: n for n in nodes}
-        by_dc: Dict[str, int] = {}
-        for n in nodes:
-            by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
         rnd.by_dc = by_dc
         allocs_by_node: Dict[str, List[Allocation]] = {}
         for n in nodes:

@@ -326,13 +326,15 @@ class GenericScheduler:
         """Pre-solve work: eager destructive stops, sticky placements and
         per-tg ask assembly. Returns (nodes, by_dc, allocs_by_node, asks,
         ask_missing), or None when nothing remains for the solver.
-        The fleet path passes shared nodes/allocs_by_node/node_by_id so
-        evals in one batch see the same world (and skip rebuilding the
-        O(cluster) id map once per member)."""
+        The ready nodes, their DC counts and id map come from the
+        snapshot's shared view (read-only); the fleet path passes the
+        round's nodes/by_dc/node_by_id and allocs_by_node so evals in
+        one batch see the same world."""
         if self.job is None:
             return None
         if nodes is None:
-            nodes, by_dc = snapshot.ready_nodes_in_dcs(self.job.datacenters)
+            nodes, by_dc, node_by_id = snapshot.ready_node_view(
+                self.job.datacenters)
         if not nodes:
             for m in missing:
                 self._record_failure(m, None)
@@ -366,8 +368,6 @@ class GenericScheduler:
 
         # sticky-disk placements prefer their previous node (reference:
         # generic_sched.go:628 findPreferredNode)
-        if node_by_id is None:
-            node_by_id = {n.id: n for n in nodes}
         batch_missing: List[_Missing] = []
         sticky_done: List[Tuple[_Missing, object, object]] = []
         for m in missing:

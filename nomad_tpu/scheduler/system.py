@@ -178,13 +178,9 @@ class SystemScheduler:
                               (self.job.task_groups if self.job else [])}
         self.plan = ev.make_plan(self.job)
 
-        if self.job is not None and self.job.datacenters:
-            nodes, by_dc = snapshot.ready_nodes_in_dcs(self.job.datacenters)
-        else:
-            nodes = [n for n in snapshot.nodes() if n.ready()]
-            by_dc = {}
-            for n in nodes:
-                by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
+        nodes, by_dc = snapshot.ready_nodes_in_dcs(
+            self.job.datacenters
+            if self.job is not None and self.job.datacenters else ["*"])
 
         allocs = snapshot.allocs_by_job(ev.namespace, ev.job_id)
         tainted = tainted_nodes(snapshot, allocs)

@@ -15,7 +15,8 @@ queries (reference: rpc.go blocking-query min-index machinery).
 from __future__ import annotations
 
 import threading
-from typing import Dict, Iterable, List, Optional, Tuple
+from types import MappingProxyType
+from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
 from ..structs import (ALLOC_CLIENT_LOST, ALLOC_DESIRED_STOP, Allocation,
                        Deployment, Evaluation, Job, JOB_STATUS_DEAD,
@@ -80,6 +81,26 @@ class SchedulerConfiguration:
         self.modify_index = 0
 
 
+#: the ready nodes of a datacenter set in table order, their count per
+#: datacenter, and the same nodes by id
+ReadyNodes = Tuple[Tuple[Node, ...], Mapping[str, int], Mapping[str, Node]]
+
+
+def _walk_ready_nodes(table: Dict[str, Node], dcs) -> ReadyNodes:
+    """Reference: scheduler/util.go:233 readyNodesInDCs."""
+    _m.incr_counter("state.ready_view.built")
+    out, by_dc = [], {}
+    for n in table.values():
+        if not n.ready():
+            continue
+        if n.datacenter not in dcs and "*" not in dcs:
+            continue
+        out.append(n)
+        by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
+    return (tuple(out), MappingProxyType(by_dc),
+            MappingProxyType({n.id: n for n in out}))
+
+
 class StateSnapshot:
     """Immutable point-in-time view handed to schedulers.
 
@@ -88,10 +109,17 @@ class StateSnapshot:
     """
 
     def __init__(self, tables: Dict[str, dict], indexes: Dict[str, int],
-                 index: int):
+                 index: int,
+                 ready: Optional[Dict[frozenset, ReadyNodes]] = None):
         self._t = tables
         self._ix = dict(indexes)
         self.index = index
+        #: the ready-node view: datacenter set -> its ready nodes, walked
+        #: at the set's first read.  The store starts a new one at every
+        #: write to the nodes table and hands the current one to each
+        #: snapshot, so it is only filled from a table in the state it
+        #: was made for, and evals between two node writes share a walk
+        self._ready = ready if ready is not None else {}
 
     # -- nodes --
     def node_by_id(self, node_id: str) -> Optional[Node]:
@@ -101,18 +129,23 @@ class StateSnapshot:
         return self._t["nodes"].values()
 
     def ready_nodes_in_dcs(self, datacenters: List[str]
-                           ) -> Tuple[List[Node], Dict[str, int]]:
-        """Reference: scheduler/util.go:233 readyNodesInDCs."""
-        dcs = set(datacenters)
-        out, by_dc = [], {}
-        for n in self._t["nodes"].values():
-            if not n.ready():
-                continue
-            if n.datacenter not in dcs and "*" not in dcs:
-                continue
-            out.append(n)
-            by_dc[n.datacenter] = by_dc.get(n.datacenter, 0) + 1
-        return out, by_dc
+                           ) -> Tuple[Tuple[Node, ...], Mapping[str, int]]:
+        nodes, by_dc, _ = self.ready_node_view(datacenters)
+        return nodes, by_dc
+
+    def ready_node_view(self, datacenters: List[str]) -> ReadyNodes:
+        """`ready_nodes_in_dcs` and the id map of its nodes, walked once
+        per state of the nodes table and datacenter set ("*" is every
+        datacenter); shared by every reader of that state, hence a tuple
+        and read-only mappings."""
+        dcs = frozenset(datacenters)
+        ready = self._ready.get(dcs)
+        if ready is None:
+            # readers that miss at once each walk the same table to an
+            # equal answer; all of them return the first one stored
+            ready = self._ready.setdefault(
+                dcs, _walk_ready_nodes(self._t["nodes"], dcs))
+        return ready
 
     # -- csi volumes --
     def csi_volume_by_id(self, namespace: str, vol_id: str):
@@ -280,7 +313,12 @@ class StateStore(StateSnapshot):
     def ready_nodes_in_dcs(self, datacenters: List[str]
                            ) -> Tuple[List[Node], Dict[str, int]]:
         with self._lock:
-            return super().ready_nodes_in_dcs(datacenters)
+            nodes, by_dc = super().ready_nodes_in_dcs(datacenters)
+            return list(nodes), dict(by_dc)
+
+    def ready_node_view(self, datacenters: List[str]) -> ReadyNodes:
+        with self._lock:
+            return super().ready_node_view(datacenters)
 
     def jobs(self) -> List[Job]:
         with self._lock:
@@ -335,13 +373,19 @@ class StateStore(StateSnapshot):
         with self._lock:
             copied = {name: dict(table) for name, table in self._t.items()}
             self._disown_indexes_locked()
-            return StateSnapshot(copied, self._ix, self.index)
+            return StateSnapshot(copied, self._ix, self.index, self._ready)
 
     def _disown_indexes_locked(self) -> None:
         """Every id set of the alloc indexes may be shared from here on
         (a snapshot took them, or a restore installed new tables)."""
         for owned in self._owned.values():
             owned.clear()
+
+    def _drop_ready_view_locked(self) -> None:
+        """The nodes table changed (every node write bumps it; a restore
+        replaces it): snapshots taken before keep the view they hold,
+        later ones share a new one."""
+        self._ready = {}
 
     def _own_ids_locked(self, name: str, key) -> set:
         """The id set under `key` of alloc index `name`, safe to mutate:
@@ -389,6 +433,8 @@ class StateStore(StateSnapshot):
             return self.index
 
     def _bump_locked(self, table: str, index: int) -> None:
+        if table == "nodes":
+            self._drop_ready_view_locked()
         self.index = max(self.index, index)
         self._ix[table] = max(self._ix.get(table, 0), index)
         self._watch.notify_all()

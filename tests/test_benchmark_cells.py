@@ -187,6 +187,24 @@ def test_the_store_metrics_read_what_is_there_and_nothing_otherwise(
         assert layers.read_metric(name, layers.Observed(**fields)) is None
 
 
+@pytest.mark.parametrize("walks, reads", [(0.0, 0.0), (32.0, 0.1)])
+def test_the_ready_view_metric_reads_the_counter_and_nothing_otherwise(
+        layers, walks, reads):
+    name = "ready_view_walks_per_eval"
+    spec = layers.load_metric(name)
+    entry = by_name("per_layer", name)
+    # PERF.md's State store layer, first named in the table here
+    assert spec["layer"] == entry["layer"] == "State store"
+    assert entry["workloads"] == CELLS and entry["better"] == "lower"
+    evals = {"evals_completed": 320}
+    assert layers.read_metric(name, layers.Observed(
+        counters={"state.ready_view.built": walks}, harness=evals)) \
+        == pytest.approx(reads)
+    # a program without the counter: nothing, no raise
+    assert layers.read_metric(name, layers.Observed(harness=evals)) is None
+    assert layers.read_metric(name, layers.Observed()) is None
+
+
 def test_the_ports_deployment_and_its_cell_are_there(cluster):
     assert "c5-ports-10k" in CONFIGS
     cell = by_name("workloads", "c5-ports-10k.closed1")
