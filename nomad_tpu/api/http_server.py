@@ -814,21 +814,6 @@ class HTTPAgentServer:
         return 200, {"stats": global_tracer.stats(),
                      "traces": global_tracer.traces(limit)}, None
 
-    def trace_corpus(self, q, body):
-        """/v1/trace/corpus — the recorded per-eval placement corpus
-        (ROADMAP item 1's training substrate).  GET returns the rows;
-        POST with {"path": ...} exports them as JSONL to that path on
-        the agent host and returns the row count."""
-        from ..utils.tracing import global_tracer
-        if body is not None and isinstance(body, dict) \
-                and body.get("path"):
-            try:
-                n = global_tracer.write_corpus(body["path"])
-            except OSError as e:
-                raise HTTPError(400, f"cannot write corpus: {e}")
-            return 200, {"path": body["path"], "rows": n}, None
-        return 200, {"rows": global_tracer.corpus_rows()}, None
-
     def agent_events(self, q, body):
         """/v1/agent/events — the mesh event log (elastic grow/shrink/
         move/fail/recover transitions with measured bytes/durations).
@@ -1884,10 +1869,6 @@ def _build_routes(s: HTTPAgentServer):
         (R(r"^/v1/status/peers$"), {"GET": s.status_peers}),
         (R(r"^/v1/metrics$"), {"GET": s.metrics}),
         (R(r"^/v1/traces$"), {"GET": s.traces_list}),
-        # literal /v1/trace/corpus must outrank the :id capture
-        (R(r"^/v1/trace/corpus$"), {"GET": s.trace_corpus,
-                                    "POST": s.trace_corpus,
-                                    "PUT": s.trace_corpus}),
         (R(r"^/v1/trace/([^/]+)$"), {"GET": s.trace_get}),
         (R(r"^/v1/agent/events$"), {"GET": s.agent_events}),
         (R(r"^/v1/telemetry/health$"), {"GET": s.telemetry_health}),

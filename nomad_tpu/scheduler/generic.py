@@ -481,9 +481,7 @@ class GenericScheduler:
         retract eager stops for failed destructive replacements. `out`
         placements must use ask indexes local to `ask_missing`.
         `span`: the eval's open solve trace span — ended here with the
-        device counters (out.trace) and the per-placement corpus rows
-        (chosen node + candidate score window + features, the learned-
-        scorer training substrate)."""
+        device counters (out.trace)."""
         # map solver placements (contiguous per ask) back to missing
         from .preemption import preemption_enabled
         preempt_ok = preemption_enabled(
@@ -492,18 +490,10 @@ class GenericScheduler:
         queues = [list(ms) for ms in ask_missing]
         cursor = [0] * len(queues)
         failed: set = set()
-        # the per-placement corpus rows exist solely for the trace span:
-        # skip building the nested dicts entirely when the span is not
-        # sampled (the fused hot path at trace sample < 1) — at batch
-        # 128 the row churn was a measurable slice of plan build
-        want_rows = span is not None and span is not NULL_SPAN
-        place_rows: List[dict] = []
         for placement in out.placements:
             g = placement.ask_index
             m = queues[g][cursor[g]]
             cursor[g] += 1
-            if want_rows:
-                place_rows.append(_placement_row(m, placement))
             if placement.node is None:
                 if placement.retryable:
                     # undecided, not failed: no preemption, no blocked
@@ -533,9 +523,9 @@ class GenericScheduler:
             for elig in out.class_eligibility:
                 self._class_eligibility.update(elig)
         self._stop_destructive_for_failed(missing, failed)
-        if want_rows:
+        if span is not None and span is not NULL_SPAN:
             span.set(**(getattr(out, "trace", None) or {}))
-            span.end(placements=place_rows)
+            span.end()
 
     def _stop_destructive_for_failed(self, missing: List[_Missing],
                                      failed: set) -> None:
@@ -828,37 +818,6 @@ class GenericScheduler:
         if self.deployment is not None and status == EVAL_STATUS_COMPLETE:
             ev.deployment_id = self.deployment.id
         self.planner.update_eval(ev)
-
-
-def _placement_row(m: _Missing, placement) -> dict:
-    """One trace-corpus row per placement decision: the chosen (group,
-    node, score) plus the candidate score window and the per-eval
-    feasibility features — failed placements ride along with node_id ""
-    and the failure cause (negative training examples)."""
-    metrics = placement.metrics
-    row = {
-        "group": m.tg.name,
-        "node_id": placement.node.id if placement.node is not None
-        else "",
-        "score": round(float(placement.score), 6),
-        "candidates": [
-            {"node_id": c.get("node_id", ""),
-             "score": round(float(c.get("normalized_score", 0.0)), 6)}
-            for c in (metrics.score_meta or [])]
-        if metrics is not None else [],
-        "features": {
-            "nodes_evaluated": metrics.nodes_evaluated,
-            "nodes_filtered": metrics.nodes_filtered,
-            "nodes_exhausted": metrics.nodes_exhausted,
-            "dimension_exhausted": dict(metrics.dimension_exhausted),
-            "constraint_filtered": dict(metrics.constraint_filtered),
-        } if metrics is not None else {},
-    }
-    if placement.evicted:
-        row["evicted"] = list(placement.evicted)
-    if placement.failed_reason:
-        row["failed_reason"] = placement.failed_reason
-    return row
 
 
 def _update_reschedule_tracker(alloc: Allocation, prev: Allocation,

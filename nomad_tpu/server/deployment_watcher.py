@@ -32,6 +32,7 @@ from ..structs import (DEPLOYMENT_DESC_FAILED_ALLOCS,
                        EVAL_STATUS_PENDING, EVAL_TRIGGER_DEPLOYMENT_WATCHER,
                        EVAL_TRIGGER_ROLLING_UPDATE, Deployment,
                        DeploymentStatusUpdate, Evaluation)
+from ..utils.tracing import global_tracer as _tr
 
 _log = logging.getLogger(__name__)
 
@@ -85,15 +86,16 @@ class DeploymentWatcher:
             with self._cv:
                 if not self._enabled:
                     return
-            try:
-                for dep in list(store.deployments()):
-                    if dep.active():
-                        self._check(dep)
-                    else:
-                        with self._cv:
-                            self._state.pop(dep.id, None)
-            except Exception:
-                _log.exception("deployment watcher pass failed")
+            with _tr.layer("deployment_watcher.pass", key="bg.pass"):
+                try:
+                    for dep in list(store.deployments()):
+                        if dep.active():
+                            self._check(dep)
+                        else:
+                            with self._cv:
+                                self._state.pop(dep.id, None)
+                except Exception:
+                    _log.exception("deployment watcher pass failed")
             # block until new writes (health updates bump the store) or a
             # short tick for deadline checks
             store.wait_for_change(store.latest_index(),

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Tuple
 from ..structs import (ALLOC_CLIENT_PENDING, ALLOC_CLIENT_RUNNING,
                        EVAL_STATUS_PENDING, EVAL_TRIGGER_NODE_DRAIN,
                        Allocation, Evaluation, Node)
+from ..utils.tracing import global_tracer as _tr
 
 _log = logging.getLogger(__name__)
 
@@ -58,12 +59,15 @@ class NodeDrainer:
             with self._cv:
                 if not self._enabled:
                     return
-            try:
-                for node in list(store.nodes()):
-                    if node.drain_strategy is not None:
-                        self._drain_node(node)
-            except Exception:
-                _log.exception("drainer pass failed")
+            # one span a pass: a background loop's share of the host
+            # (sample `bg.pass`, summed over every such loop)
+            with _tr.layer("drainer.pass", key="bg.pass"):
+                try:
+                    for node in list(store.nodes()):
+                        if node.drain_strategy is not None:
+                            self._drain_node(node)
+                except Exception:
+                    _log.exception("drainer pass failed")
             store.wait_for_change(store.latest_index(),
                                   self.poll_interval_s * 4)
 

@@ -785,8 +785,9 @@ class EvalBroker:
     def _run_delayed_watcher(self) -> None:
         while not self._stop_delay.is_set():
             wait = 0.1
-            for s in self._shards:
-                wait = min(wait, s.pop_due_delayed())
+            with _tr.layer("broker.delay_pass", key="bg.pass"):
+                for s in self._shards:
+                    wait = min(wait, s.pop_due_delayed())
             self._stop_delay.wait(max(wait, 0.01))
 
     # --------------------------------------------------------------- stats

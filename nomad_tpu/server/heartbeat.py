@@ -34,6 +34,8 @@ import threading
 import time as _time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..utils.tracing import global_tracer as _tr
+
 _log = logging.getLogger(__name__)
 
 
@@ -169,23 +171,30 @@ class NodeHeartbeater:
 
     def _watch(self) -> None:
         while True:
+            with _tr.layer("heartbeat.sweep", key="bg.pass"):
+                with self._cv:
+                    if not self._enabled:
+                        return
+                    expired = self._pop_expired_locked(_time.monotonic())
+                for nid in expired:
+                    # the callback races node deletion (reap_nodes); an
+                    # exception here must not kill the watcher and
+                    # silently disable failure detection for the whole
+                    # cluster
+                    try:
+                        self._on_expire(nid)
+                    except Exception:
+                        _log.exception(
+                            "heartbeat expiry callback failed for node %s",
+                            nid)
+            if expired:
+                continue
             with self._cv:
+                # the next deadline, read under the lock a reset takes
                 if not self._enabled:
                     return
-                now = _time.monotonic()
-                expired = self._pop_expired_locked(now)
-                if not expired:
-                    wait = 0.5
-                    if self._heap:
-                        wait = min(wait, max(self._heap[0][0] - now, 0.001))
-                    self._cv.wait(wait)
-                    continue
-            for nid in expired:
-                # the callback races node deletion (reap_nodes); an exception
-                # here must not kill the watcher and silently disable failure
-                # detection for the whole cluster
-                try:
-                    self._on_expire(nid)
-                except Exception:
-                    _log.exception(
-                        "heartbeat expiry callback failed for node %s", nid)
+                wait = 0.5
+                if self._heap:
+                    wait = min(wait, max(
+                        self._heap[0][0] - _time.monotonic(), 0.001))
+                self._cv.wait(wait)

@@ -21,6 +21,7 @@ _log = logging.getLogger(__name__)
 
 from ..structs import Job
 from ..utils.cron import Cron, CronParseError
+from ..utils.tracing import global_tracer as _tr
 
 PERIODIC_LAUNCH_SUFFIX = "/periodic-"
 
@@ -162,7 +163,8 @@ class PeriodicDispatcher:
                         wait = min(wait, max(self._heap[0][0] - now, 0.01))
                     self._cv.wait(wait)
                     continue
-            self._launch(launch_job, launch_time)
+            with _tr.layer("periodic.launch", key="bg.pass"):
+                self._launch(launch_job, launch_time)
 
     def _launch(self, job: Job, launch: float) -> Optional[Job]:
         if job.periodic and job.periodic.prohibit_overlap:

@@ -348,7 +348,8 @@ class PlanApplier:
             with _tr.layer("plan.raft_apply", plan.eval_id):
                 index = self.apply_fn(plan, result)
             result.alloc_index = index
-            self._account_and_respond(p, plan, result)
+            with _tr.layer("plan.respond", plan.eval_id):
+                self._account_and_respond(p, plan, result)
         return None
 
     def _finalize(self, out: _Outstanding):
@@ -356,24 +357,27 @@ class PlanApplier:
         exactly once, never raising: every failure path error-responds
         instead (PlanFuture.respond is first-wins, so a partial
         _account_and_respond that already delivered the result cannot
-        be overwritten by the trailing error)."""
+        be overwritten by the trailing error).  One layer span,
+        `plan.respond`, of the plan's eval (of none for a group)."""
         from ..utils.tracing import global_tracer as _tr
-        try:
-            # the wait for the dispatched entry: over before it starts
-            # on a single-voter raft, which applies inside the dispatch
-            # (`plan.raft_apply` is the layer's work)
-            with _tr.layer("plan.commit_wait", key="plan.apply"):
-                index = out.finish(10.0)
-        except Exception as e:
-            for pending, _plan, _result in out.items:
-                pending.future.respond(None, f"plan apply error: {e}")
-            return None
-        for pending, plan, result in out.items:
-            result.alloc_index = index
+        eval_id = out.items[0][1].eval_id if len(out.items) == 1 else ""
+        with _tr.layer("plan.respond", eval_id):
             try:
-                self._account_and_respond(pending, plan, result)
+                # the wait for the dispatched entry: over before it
+                # starts on a single-voter raft, which applies inside the
+                # dispatch (`plan.raft_apply` is the layer's work)
+                with _tr.layer("plan.commit_wait", key="plan.apply"):
+                    index = out.finish(10.0)
             except Exception as e:
-                pending.future.respond(None, f"plan apply error: {e}")
+                for pending, _plan, _result in out.items:
+                    pending.future.respond(None, f"plan apply error: {e}")
+                return None
+            for pending, plan, result in out.items:
+                result.alloc_index = index
+                try:
+                    self._account_and_respond(pending, plan, result)
+                except Exception as e:
+                    pending.future.respond(None, f"plan apply error: {e}")
         return None
 
     def _account_and_respond(self, pending, plan: Plan,

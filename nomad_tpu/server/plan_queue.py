@@ -19,6 +19,9 @@ class PlanFuture:
         self._event = threading.Event()
         self._result: Optional[PlanResult] = None
         self._err: Optional[str] = None
+        #: respond stamp (`time.monotonic`): the worker records
+        #: `plan.resume_wait` from it, the hand-off back to its thread
+        self.t_responded: Optional[float] = None
 
     def respond(self, result: Optional[PlanResult],
                 err: Optional[str]) -> None:
@@ -29,6 +32,7 @@ class PlanFuture:
             return
         self._result = result
         self._err = err
+        self.t_responded = time.monotonic()
         self._event.set()
 
     def wait(self, timeout: float = 30.0

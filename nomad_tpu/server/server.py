@@ -292,22 +292,25 @@ class Server:
         (reference: leader.go:625 reapDupBlockedEvaluations)."""
         import copy
         from ..structs import EVAL_STATUS_CANCELLED
+        from ..utils.tracing import global_tracer as _tr
         ticks = 0
         while not self._stop_reapers.is_set():
             ticks += 1
             if ticks % 10 == 0:
-                self._autopilot_reconcile()
+                with _tr.layer("server.autopilot", key="bg.pass"):
+                    self._autopilot_reconcile()
             dups = self.blocked_evals.get_duplicates(timeout=0.2)
             if not dups:
                 continue
-            cancelled = []
-            for ev in dups:
-                e2 = copy.copy(ev)
-                e2.status = EVAL_STATUS_CANCELLED
-                e2.status_description = \
-                    "cancelled due to duplicate blocked evaluation"
-                cancelled.append(e2)
-            self.upsert_evals(cancelled)
+            with _tr.layer("server.dup_reap", key="bg.pass"):
+                cancelled = []
+                for ev in dups:
+                    e2 = copy.copy(ev)
+                    e2.status = EVAL_STATUS_CANCELLED
+                    e2.status_description = \
+                        "cancelled due to duplicate blocked evaluation"
+                    cancelled.append(e2)
+                self.upsert_evals(cancelled)
 
     def _restore_evals(self) -> None:
         """Re-enqueue non-terminal evals from state (leader.go:245).
@@ -336,10 +339,12 @@ class Server:
         from ..scheduler.core import (CORE_JOB_DEPLOYMENT_GC,
                                       CORE_JOB_EVAL_GC, CORE_JOB_JOB_GC,
                                       CORE_JOB_NODE_GC)
+        from ..utils.tracing import global_tracer as _tr
         while not self._stop_reapers.wait(self.gc_interval_s):
-            for kind in (CORE_JOB_EVAL_GC, CORE_JOB_NODE_GC,
-                         CORE_JOB_JOB_GC, CORE_JOB_DEPLOYMENT_GC):
-                self.broker.enqueue(self._core_job_eval(kind))
+            with _tr.layer("server.gc_timer", key="bg.pass"):
+                for kind in (CORE_JOB_EVAL_GC, CORE_JOB_NODE_GC,
+                             CORE_JOB_JOB_GC, CORE_JOB_DEPLOYMENT_GC):
+                    self.broker.enqueue(self._core_job_eval(kind))
 
     #: server-side broker-gauge export beat (seconds)
     METRICS_EXPORT_INTERVAL_S = 1.0
@@ -355,7 +360,7 @@ class Server:
         while not self._stop_reapers.wait(self.METRICS_EXPORT_INTERVAL_S):
             # one span a beat: what this thread takes from the workers
             # (it walks the broker, and every fifth beat the node planes)
-            with _tr.layer("telemetry.tick"):
+            with _tr.layer("telemetry.tick", key="bg.pass"):
                 self.broker.export_metrics()
                 beats += 1
                 try:

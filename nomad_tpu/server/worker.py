@@ -278,6 +278,10 @@ class Worker(threading.Thread):
                 return None, None
             with _tr.layer("plan.result_wait"):
                 result, err = pending.future.wait(30.0)
+                if pending.future.t_responded is not None:
+                    # the applier's respond -> this thread running again
+                    _tr.waited("plan.resume_wait",
+                               pending.future.t_responded, plan.eval_id)
             if err is not None or result is None:
                 sp.set(outcome=f"error: {err}" if err else "no result")
                 return None, None

@@ -147,8 +147,14 @@ class MetricsRegistry:
 
     def add_sample(self, key: str, value_s: float) -> None:
         with self._lock:
-            if self._admit_locked(key, self._samples):
-                self._samples.setdefault(key, _Summary()).add(value_s)
+            summary = self._samples.get(key)
+            if summary is None:
+                # a summary and its reservoir only for a new key: this
+                # runs twice at the end of every layer span
+                if not self._admit_locked(key, self._samples):
+                    return
+                summary = self._samples[key] = _Summary()
+            summary.add(value_s)
 
     def observe_hist(self, key: str, value: float,
                      buckets=None) -> None:

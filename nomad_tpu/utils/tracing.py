@@ -5,11 +5,7 @@ A lightweight span layer threaded through the full eval lifecycle
 plan submit/apply), so ONE trace id — the eval id — yields the complete
 timeline with queue-age, batch-size and shed/nack causality attached,
 and the device-side wave/byte counters land on the solve span instead
-of dying in bench-only JSON.  This is the training substrate ROADMAP
-item 1 (the learned placement scorer) declares: every solve span
-carries per-(group, node) candidate scores and the chosen placements,
-exportable as a JSONL corpus (`FlightRecorder.corpus_rows` /
-`write_corpus`, served at /v1/trace/corpus).
+of dying in bench-only JSON.
 
 Design constraints (ISSUE 10):
 
@@ -36,6 +32,27 @@ whatever the recorder's sampling.  `waited()` is the same for a wait
 whose start was stamped on another thread, and `watch_gc()` marks the
 collector's pauses (`gc.pause`), which otherwise sit unseen inside
 whatever span was open.
+
+Where a thread's time goes when it is not running.  A layer span
+also reads `time.thread_time()` at both ends: the sample `cpu.<name>`
+(`cpu.<key>` where a `key` is given) holds the block's thread-CPU
+seconds, children included, as `span.<name>` holds its wall, and the
+recorder row carries it as `cpu_s`.  Each span hands its wall and CPU
+to its parent on the thread's layer stack, so a span's own time is its
+time less its children's; the own wall less the own CPU of every
+working span (one whose last name part does not say `wait`) adds to the
+counter `thread.offcpu_s`: seconds a thread was inside a span and not
+running.  Around a thread's outermost layer span the recorder reads the
+thread's `run_delay` (`/proc/thread-self/schedstat`, one descriptor a
+thread) and adds the difference to `thread.runq_s`: of the off-CPU
+time, what the thread spent runnable and waiting for a core; the rest
+it spent blocked, on the interpreter lock or on a lock of the program.
+Where that file cannot be read, no `thread.runq_s` is written.
+
+Completed rows park in a spill queue that a drainer thread moves into
+the ring and the sink every half second, or at once when the queue
+passes half its bound; every reader drains it first, so a span's end
+wakes no second thread.
 
 Knobs (env):
   NOMAD_TPU_TRACE        "0" disables recording (default on)
@@ -70,6 +87,26 @@ DEFAULT_MESH_EVENTS = 4096
 #: drainer thread does the ring insert + JSONL sink write, so the solve
 #: hot path never takes the recorder's main lock
 DEFAULT_TRACE_SPILL = 8192
+#: a thread's own scheduler statistics: nanoseconds on a CPU, nanoseconds
+#: runnable and waiting for one (`run_delay`), time slices
+SCHEDSTAT = "/proc/thread-self/schedstat"
+
+
+class _Descriptor:
+    """A file descriptor that closes with its last reference: one a
+    thread, held in the recorder's thread-local storage, so it goes
+    when the thread does."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self, fd: int):
+        self.fd = fd
+
+    def __del__(self) -> None:
+        try:
+            os.close(self.fd)
+        except OSError:
+            pass
 
 
 def _env_on(name: str, default: bool = True) -> bool:
@@ -85,7 +122,7 @@ class Span:
     abandoned mid-flight leaves no partial row."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name",
-                 "t_start", "t_end", "attrs", "_rec")
+                 "t_start", "t_end", "cpu_s", "attrs", "_rec")
 
     def __init__(self, rec: Optional["FlightRecorder"], trace_id: str,
                  name: str, parent_id: str, attrs: Dict):
@@ -96,6 +133,8 @@ class Span:
         self.name = name
         self.t_start = _time.monotonic()
         self.t_end = 0.0
+        #: thread-CPU seconds of a layer span; None where not measured
+        self.cpu_s: Optional[float] = None
         self.attrs = dict(attrs)
 
     def set(self, **attrs) -> "Span":
@@ -146,10 +185,12 @@ NULL_SPAN = _NullSpan()
 
 class LayerSpan:
     """One layer boundary, opened by `FlightRecorder.layer` (see there).
-    After the `with` block `dur_s` holds the wall the block took."""
+    After the `with` block `dur_s` holds the wall the block took and
+    `cpu_s` the thread-CPU seconds."""
 
-    __slots__ = ("_rec", "name", "trace_id", "_key", "_attrs", "_span",
-                 "_ann", "_t0", "dur_s")
+    __slots__ = ("_rec", "name", "trace_id", "_key", "_cpu_key", "_attrs",
+                 "_span", "_ann", "_t0", "_c0", "_run_delay0",
+                 "_child_s", "_child_cpu_s", "dur_s", "cpu_s")
 
     def __init__(self, rec: "FlightRecorder", name: str, trace_id: str,
                  key: Optional[str], attrs: Dict):
@@ -157,10 +198,14 @@ class LayerSpan:
         self.name = name
         self.trace_id = trace_id
         self._key = key or "span." + name
+        self._cpu_key = "cpu." + (key or name)
         self._attrs = attrs
         self._span = NULL_SPAN
         self._ann = None
-        self.dur_s = 0.0
+        self._run_delay0: Optional[float] = None
+        # wall and CPU of the children that ended inside this span
+        self._child_s = self._child_cpu_s = 0.0
+        self.dur_s = self.cpu_s = 0.0
 
     @property
     def span_id(self) -> str:
@@ -183,6 +228,8 @@ class LayerSpan:
         else:
             self._span = rec.stage(self.trace_id, self.name,
                                    **self._attrs)
+        if outer is None:
+            self._run_delay0 = rec._run_delay()
         stack.append(self)
         # only where jax is already loaded: client and CLI processes
         # must not import it for the sake of a span
@@ -192,17 +239,37 @@ class LayerSpan:
                                              eval=self.trace_id)
             self._ann.__enter__()
         self._t0 = _time.monotonic()
+        self._c0 = _time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self.cpu_s = _time.thread_time() - self._c0
         self.dur_s = _time.monotonic() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        self._rec._layer_stack().pop()
+        rec = self._rec
+        stack = rec._layer_stack()
+        stack.pop()
+        if stack:
+            stack[-1]._child_s += self.dur_s
+            stack[-1]._child_cpu_s += self.cpu_s
+        if self._span is not NULL_SPAN:
+            self._span.cpu_s = self.cpu_s
         self._span.__exit__(exc_type, exc, tb)
         global_metrics.add_sample(self._key, self.dur_s)
-        if self._rec._gc_done:
-            self._rec._drain_gc()
+        global_metrics.add_sample(self._cpu_key, self.cpu_s)
+        if "wait" not in self.name.rpartition(".")[2]:
+            off_s = (self.dur_s - self._child_s) \
+                - (self.cpu_s - self._child_cpu_s)
+            if off_s > 0.0:
+                global_metrics.incr_counter("thread.offcpu_s", off_s)
+        if self._run_delay0 is not None:
+            run_delay = rec._run_delay()
+            if run_delay is not None:
+                global_metrics.incr_counter(
+                    "thread.runq_s", run_delay - self._run_delay0)
+        if rec._gc_done:
+            rec._drain_gc()
 
 
 
@@ -269,8 +336,11 @@ class FlightRecorder:
         self._tail_lock = threading.Lock()
         self._spill_event = threading.Event()
         self._drainer: Optional[threading.Thread] = None
-        # per-thread stack of the open layer spans (`layer`)
+        # per-thread stack of the open layer spans (`layer`), and the
+        # thread's schedstat descriptor (`_run_delay`)
         self._layers = threading.local()
+        #: cleared for good at the first schedstat that cannot be read
+        self._schedstat = True
         # collector pauses (`watch_gc`): the running one's profiler
         # event and start, and the finished ones not yet sampled
         self._gc_watched = False
@@ -327,6 +397,28 @@ class FlightRecorder:
             stack = self._layers.stack = []
             return stack
 
+    def _run_delay(self) -> Optional[float]:
+        """Seconds this thread has spent runnable and waiting for a core
+        since it started (schedstat's second field), read through the
+        thread's one descriptor; None, from then on for every thread,
+        where the file cannot be opened or read."""
+        if not self._schedstat:
+            return None
+        try:
+            fd = self._layers.schedstat
+        except AttributeError:
+            try:
+                fd = self._layers.schedstat = _Descriptor(
+                    os.open(SCHEDSTAT, os.O_RDONLY))
+            except OSError:
+                self._schedstat = False
+                return None
+        try:
+            return int(os.pread(fd.fd, 64, 0).split()[1]) * 1e-9
+        except (OSError, IndexError, ValueError):
+            self._schedstat = False
+            return None
+
     def layer(self, name: str, trace_id: str = "",
               key: Optional[str] = None, **attrs) -> "LayerSpan":
         """Mark one layer boundary: `with tracer.layer(name, eval_id):`
@@ -338,7 +430,8 @@ class FlightRecorder:
         older sample name (`worker.submit_plan`) where one is read.
         An empty `trace_id` takes the enclosing layer span's; with
         none to take, or the recorder off or not sampling the trace,
-        no row is written and the other two sinks still are."""
+        no row is written and the other two sinks still are, as are
+        the CPU readings of the module docstring."""
         return LayerSpan(self, name, trace_id, key, attrs)
 
     def waited(self, name: str, t_start: float, trace_id: str = "",
@@ -428,6 +521,7 @@ class FlightRecorder:
             "parent_id": sp.parent_id, "name": sp.name,
             "t_start": sp.t_start, "t_end": sp.t_end,
             "dur_s": round(sp.t_end - sp.t_start, 9),
+            "cpu_s": sp.cpu_s,
             "t_wall": round(self._anchor_wall
                             + (sp.t_start - self._anchor_mono), 6),
             "attrs": sp.attrs,
@@ -446,7 +540,11 @@ class FlightRecorder:
                     target=self._drain_loop, daemon=True,
                     name="trace-drain")
                 self._drainer.start()
-        self._spill_event.set()
+            # the drainer's own tick empties the queue; a row wakes it
+            # only once the queue is half full
+            wake = len(self._spill) > self.spill_limit // 2
+        if wake:
+            self._spill_event.set()
 
     def flush(self) -> None:
         """Synchronously drain the spill queue into the ring + sink —
@@ -554,55 +652,6 @@ class FlightRecorder:
                 self._spill_dropped = 0
             self._traces.clear()
             self._dropped = 0
-
-    # ------------------------------------------------------------- corpus
-    def corpus_rows(self) -> List[dict]:
-        """The learned-scorer training substrate (ROADMAP item 1): one
-        row per recorded placement decision, flattened from the solve
-        spans — per-eval features, the candidate (group, node) score
-        window, the chosen placement.  Failed placements ride along
-        with node_id "" (negative examples are training signal too)."""
-        self._drain_pending()
-        with self._lock:
-            traces = [(tid, list(spans))
-                      for tid, spans in self._traces.items()]
-        rows: List[dict] = []
-        for tid, spans in traces:
-            queue_age = batch_size = None
-            for s in spans:
-                if s["name"] == "broker.dequeue":
-                    queue_age = s["attrs"].get("queue_age_s")
-                elif s["name"] == "worker.batch":
-                    batch_size = s["attrs"].get("batch_size")
-            for s in spans:
-                if s["name"] != "solve":
-                    continue
-                a = s["attrs"]
-                for p in a.get("placements", ()):
-                    rows.append({
-                        "eval_id": tid,
-                        "job_id": a.get("job_id", ""),
-                        "group": p.get("group", ""),
-                        "node_id": p.get("node_id", ""),
-                        "score": p.get("score", 0.0),
-                        "candidates": p.get("candidates", []),
-                        "features": p.get("features", {}),
-                        "evicted": p.get("evicted", []),
-                        "queue_age_s": queue_age,
-                        "batch_size": batch_size,
-                        "fused": a.get("fused", False),
-                        "solve_wall_s": s["dur_s"],
-                        "t_wall": s["t_wall"],
-                    })
-        return rows
-
-    def write_corpus(self, path: str) -> int:
-        """Write the corpus as JSONL; returns the row count."""
-        rows = self.corpus_rows()
-        with open(path, "w") as f:
-            for r in rows:
-                f.write(json.dumps(r, sort_keys=True) + "\n")
-        return len(rows)
 
 
 class MeshEventLog:

@@ -280,3 +280,55 @@ def test_the_port_metrics_read_what_is_there_and_nothing_otherwise(
     bare = {k: v for k, v in fields.items() if k == "harness"}
     bare["counters"] = {"solver.solve.tpu": 500.0}
     assert layers.read_metric(name, layers.Observed(**bare)) is None
+
+
+#: the five metrics of where a thread's time goes when it is not running:
+#: the layer each names, whether lower is better, an `Observed` that
+#: holds what it reads, and what it then says
+EVALS = {"evals_completed": 400}
+OFFCPU_METRICS = {
+    "plan_resume_wait_ms": (
+        "Plan apply", "lower",
+        {"samples": {"span.plan.resume_wait": (0.6, 400)}}, 1.5),
+    "submit_handoff_share": (
+        "Plan apply", "lower",
+        {"samples": {"span.plan.respond": (0.9, 400),
+                     "span.plan.resume_wait": (0.6, 400),
+                     "worker.submit_plan": (12.0, 400)}}, 12.5),
+    "working_offcpu_ms_per_eval": (
+        "Interpreter", "lower",
+        {"counters": {"thread.offcpu_s": 2.0}, "harness": EVALS}, 5.0),
+    "background_ms_per_eval": (
+        "Interpreter", "lower",
+        {"samples": {"bg.pass": (1.2, 2000)}, "harness": EVALS}, 3.0),
+    "fixup_oncpu_share": (
+        "Host fixup", "higher",
+        {"samples": {"cpu.solve.fixup": (4.5, 400),
+                     "span.solve.fixup": (6.0, 400)}}, 75.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(OFFCPU_METRICS))
+def test_the_offcpu_metrics_read_their_counter_or_sample_and_nothing_otherwise(
+        layers, name):
+    layer, better, fields, reads = OFFCPU_METRICS[name]
+    spec = layers.load_metric(name)
+    entry = by_name("per_layer", name)
+    assert spec["layer"] == entry["layer"] == layer
+    # a layer the table already had, under the same letters
+    assert layer in {m["layer"] for m in BENCH["per_layer"]
+                     if m["name"] not in OFFCPU_METRICS}
+    assert entry["workloads"] == CELLS and entry["better"] == better
+    assert entry["moves"] == "placements_per_s"
+    assert entry["source"] == "program_counter"
+    assert layers.read_metric(name, layers.Observed(**fields)) \
+        == pytest.approx(reads)
+    # a program without the counter or the sample (the parent): nothing,
+    # no raise
+    assert layers.read_metric(name, layers.Observed()) is None
+    assert layers.read_metric(name, layers.Observed(harness=EVALS)) is None
+    # where the span's own sample is there and the new one is not
+    older = {"samples": {"worker.submit_plan": (12.0, 400),
+                         "span.solve.fixup": (6.0, 400)},
+             "harness": EVALS}
+    assert layers.read_metric(name, layers.Observed(**older)) is None

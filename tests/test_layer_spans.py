@@ -43,6 +43,9 @@ SINGLE_PATH = {
     "sched.plan_build": (INVOKE, None),
     "plan.submit": (INVOKE, "worker.submit_plan"),
     "plan.result_wait": ("plan.submit", None),
+    # a wait recorded by `waited`: the applier's respond -> the worker
+    # running again, a stage of the trace
+    "plan.resume_wait": (None, None),
     "plan.refresh": ("plan.submit", None),
     "eval.update": (INVOKE, None),
     # the applier's thread
@@ -51,14 +54,15 @@ SINGLE_PATH = {
     "plan.evaluate": (None, "plan.evaluate"),
     "plan.raft_apply": (None, None),
     "fsm.apply": ("plan.raft_apply", None),
+    "plan.respond": (None, None),
+    "plan.commit_wait": ("plan.respond", "plan.apply"),
 }
 #: sample key -> its span: written with no recorder row, because no
 #: eval id is known where they run (`broker.wait` is no span at all:
 #: the age the broker computes at dequeue)
 SAMPLES_ONLY = {"span.job.register": "job.register",
                 "broker.wait": "broker.wait",
-                "span.worker.dequeue_wait": "worker.dequeue_wait",
-                "plan.apply": "plan.commit_wait"}
+                "span.worker.dequeue_wait": "worker.dequeue_wait"}
 FLEET = ["fleet.reconcile", "fleet.pack", "fleet.dispatch", "fleet.fetch",
          "fleet.fixup", "fleet.d2h", "fleet.class_elig", "fleet.plan_build",
          "fleet.apply"]
@@ -302,14 +306,15 @@ def test_spans_are_host_events_of_a_profiler_trace(tmp_path):
     applier_line, = of["nomad.plan.evaluate"]
     assert worker_line != applier_line
     for name, (parent, _key_) in SINGLE_PATH.items():
-        if name == "plan.queue_wait":       # a wait: no profiler event
+        if name in ("plan.queue_wait", "plan.resume_wait"):
+            # a wait recorded by `waited`: no profiler event
             assert "nomad." + name not in of
             continue
         on = of["nomad." + name]
         if parent in (INVOKE, "solve.fixup") or name == INVOKE:
             assert on == {worker_line}, name
         elif name in ("plan.snapshot", "plan.evaluate", "plan.raft_apply",
-                      "fsm.apply"):
+                      "fsm.apply", "plan.respond", "plan.commit_wait"):
             assert on == {applier_line}, name
     # nesting on the worker's line: children inside the parent's event
     evs = lines[worker_line]
