@@ -19,6 +19,7 @@ from ..state.store import JobSummary, SchedulerConfiguration, StateStore
 from ..structs import (Allocation, DeploymentStatusUpdate,
                        DesiredTransition, Deployment, Evaluation, Job, Node,
                        PlanResult)
+from ..utils import collector
 from ..utils.codec import from_wire, to_wire
 from ..utils.tracing import global_tracer
 
@@ -302,6 +303,9 @@ class StateFSM:
             st._ix = dict(snap.get("table_indexes", {}))
             st.index = snap.get("latest_index", 0)
             st._watch.notify_all()
+        # the old store went whole: its cycles frozen by a full collection
+        # are the collector's again
+        collector.thaw()
 
     @staticmethod
     def _key(table: str, k):

@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..utils import collector
 from ..utils.tracing import global_tracer
 from .fsm import NOOP, StateFSM
 from .log import LogEntry, RaftLog
@@ -597,6 +598,9 @@ class RaftNode:
                 os.replace(tmp, self._snap_path)
             self.log.compact_to(self.snapshot_index)
             self._save_meta_locked()
+            # the entries cut went with their payloads: what of them a full
+            # collection froze is the collector's again
+            collector.thaw()
 
     def _read_snapshot(self) -> bytes:
         if self._snap_path and os.path.exists(self._snap_path):

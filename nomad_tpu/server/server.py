@@ -18,6 +18,7 @@ import time as _time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 from ..raft import NotLeaderError, RaftConfig, RaftNode, StateFSM
+from ..utils import collector
 from ..utils.codec import to_wire
 
 from ..state.store import StateStore
@@ -198,6 +199,9 @@ class Server:
         leader services follow leadership transitions."""
         from ..utils.tracing import global_tracer
         global_tracer.watch_gc()
+        # the collector's freeze policy (`utils/collector.py`), held from
+        # start() to stop()
+        collector.install(self)
         if self._multi:
             self.raft.start()
         else:
@@ -271,6 +275,7 @@ class Server:
             if w.is_alive():
                 w.join(timeout=5.0)
         self.raft.stop()
+        collector.release(self)
 
     def _revoke_leadership(self) -> None:
         self.heartbeater.set_enabled(False)

@@ -342,7 +342,8 @@ class FlightRecorder:
         #: cleared for good at the first schedstat that cannot be read
         self._schedstat = True
         # collector pauses (`watch_gc`): the running one's profiler
-        # event and start, and the finished ones not yet sampled
+        # event and start, and the finished ones not yet sampled (with
+        # the counts of `count_from_gc`)
         self._gc_watched = False
         self._gc_open = None
         self._gc_done: deque = deque(maxlen=DEFAULT_TRACE_SPILL)
@@ -505,15 +506,24 @@ class FlightRecorder:
                 ann.__exit__(None, None, None)
             self._gc_done.append((generation, _time.monotonic() - t0))
 
+    def count_from_gc(self, key: str, value: float) -> None:
+        """Add `value` to the counter `key` from a `gc.callbacks` hook,
+        which may run under any lock: parked without one, and written
+        with the pause samples at the next layer span's end."""
+        self._gc_done.append((key, value))
+
     def _drain_gc(self) -> None:
         while True:
             try:
-                generation, dur_s = self._gc_done.popleft()
+                what, value = self._gc_done.popleft()
             except IndexError:
                 return
-            global_metrics.add_sample("span.gc.pause", dur_s)
-            if generation == 2:
-                global_metrics.add_sample("span.gc.full", dur_s)
+            if isinstance(what, str):           # `count_from_gc`'s key
+                global_metrics.incr_counter(what, value)
+                continue
+            global_metrics.add_sample("span.gc.pause", value)
+            if what == 2:
+                global_metrics.add_sample("span.gc.full", value)
 
     def _record(self, sp: Span) -> None:
         row = {
