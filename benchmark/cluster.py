@@ -256,7 +256,9 @@ def _group(base, name, count, cpu, mem, disk):
 
 
 def build_job(cfg: dict, job_id: str, shape=None):
-    """`bench.make_job` from the configuration's job template."""
+    """`bench.make_job` from the configuration's job template; once its
+    groups are built, each rule's `build_job(job, cfg)` may put its
+    job-level part (a constraint over all of the job's groups) on it."""
     from nomad_tpu import mock
     from nomad_tpu.structs import Affinity, Constraint, Spread
     j = cfg["job"]
@@ -280,6 +282,8 @@ def build_job(cfg: dict, job_id: str, shape=None):
         for build in build_group:
             build(tg, g, cfg)
         job.task_groups.append(tg)
+    for build in hooks(cfg, "build_job"):
+        build(job, cfg)
     return job
 
 

@@ -19,10 +19,12 @@ placement seeing every earlier one: the straightforward implementation.
 Its `controls` are the lower-precision and guarantee-breaking variants
 that `correct` has to tell apart from a sound run (see check.py).
 
-What a node holds beside cpu, memory and disk (device instances, ports)
-is a rule's: a file `rules/<name>.py` that the configuration names under
-`rules` (README.md, "A rule").  The mask and the placer ask each loaded
-rule; a configuration that names none reads as it always did.
+What a node holds beside cpu, memory and disk (device instances, ports),
+and a limit a job's allocs share (distinct hosts, distinct property
+values), is a rule's: a file `rules/<name>.py` that the configuration
+names under `rules` (README.md, "A rule").  The mask and the placer ask
+each loaded rule; a configuration that names none reads as it always
+did.
 """
 from __future__ import annotations
 
@@ -155,7 +157,9 @@ class Placer:
     copy) and `begin_job(placer)` (what this job sees); `fits(placer,
     group)` says on which nodes one more alloc of the group finds what
     the rule accounts for, and `commit(placer, ni, group)` debits it and
-    returns what the alloc then holds, for the reference's rows.
+    returns what the alloc then holds, for the reference's rows.  Each
+    rule's whole `fits` is ANDed in again after every placement, so a
+    limit that many nodes share (so many allocs a rack) closes them all.
     """
 
     def __init__(self, cfg: dict, plain: cluster.PlainNodes,
@@ -205,8 +209,8 @@ class Placer:
         the score it was chosen by, what the rules say the alloc holds)
         of every alloc placed (shorter than the group's count where the
         reference finds no room).  Every step scores every node; only
-        the chosen node's terms are recomputed between steps, which
-        changes no value."""
+        the chosen node's terms and every rule's fits are recomputed
+        between steps, which changes no value."""
         seen = self.used if self._round_base is None \
             else self._round_base.copy()
         self._call("begin_job")
@@ -244,9 +248,9 @@ class Placer:
                 if seen is not self.used:
                     self.used[ni] = (self.used[ni] + ask).astype(self.dtype)
                 after[ni] = (seen[ni] + ask).astype(self.dtype)
-                fits[ni] = self.feasible[ni] and bool(
-                    (after[ni] <= self.cap[ni]).all()) and all(
-                    rule_fits(self, g)[ni] for rule_fits in self._fits)
+                fits[ni] &= bool((after[ni] <= self.cap[ni]).all())
+                for rule_fits in self._fits:
+                    fits &= rule_fits(self, g)
                 binpack[ni] = binpack_score(after[ni], self.cap[ni])
                 on_node[ni] += 1
                 for (inv, _nv, _w), cnt in zip(self.spreads, per_value):

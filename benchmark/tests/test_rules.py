@@ -8,6 +8,9 @@ import.
 `compare` gave at the parent commit (664e26a, before any rule could be
 loaded) on c2 and c3 at 1,024 nodes; `digests` below computed them there
 (this file imported over the parent's modules) and computes them here.
+The same for c4 (`rules_golden.json`) and c5 (`rules_ports_golden.json`)
+at commit 96e65fe, before a rule could share its fit between nodes, with
+the rules' fields of the rows beside them.
 """
 import ast
 import glob
@@ -50,6 +53,11 @@ def sha(*parts) -> str:
     return h.hexdigest()[:16]
 
 
+#: the columns of `check.reference_rows` that are no rule's
+ROW_KEYS = {"job_id", "group", "node", "res", "create_index", "score",
+            "job_index"}
+
+
 def digests(name, seed, mask_of) -> dict:
     """`mask_of(cfg, plain)` is `reference.constraint_mask` under the
     signature of the commit that runs this."""
@@ -75,6 +83,9 @@ def digests(name, seed, mask_of) -> dict:
             rows["job_id"], rows["group"], rows["node"].tobytes(),
             rows["res"].tobytes(), rows["create_index"].tobytes(),
             rows["score"].tobytes(), rows["job_index"])
+        held = sorted(set(rows) - ROW_KEYS)         # the rules' fields
+        if held:
+            out[f"held.{label}"] = sha({k: rows[k] for k in held})
         out[f"compare.{label}"] = {
             k: repr(v) for k, v in sorted(check.compare(
                 cfg, plain, rows, sent, None, 0).items())}
@@ -89,6 +100,20 @@ def test_without_rules_everything_reads_as_at_the_parent(name, seed):
     with open(GOLDEN, encoding="utf-8") as f:
         want = json.load(f)[f"{name}/{seed}"]
     assert cluster.rules_of(cluster.load_config(name)) == []
+    assert digests(name, seed, reference.constraint_mask) == want
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("name, golden", [
+    ("c4-devices-10k", GOLDEN),
+    ("c5-ports-10k", os.path.join(HERE, "rules_ports_golden.json"))])
+def test_rule_configurations_read_as_at_the_parent(name, golden, seed):
+    """c4's and c5's digests, the rules' fields of the rows among them,
+    computed at commit 96e65fe, when the placer re-read only the chosen
+    node's fit after a placement: re-reading every node's changes none."""
+    with open(golden, encoding="utf-8") as f:
+        want = json.load(f)[f"{name}/{seed}"]
+    assert cluster.rules_of(cluster.load_config(name))
     assert digests(name, seed, reference.constraint_mask) == want
 
 
@@ -412,8 +437,10 @@ def all_imports(path):
 def test_rule_files_import_numpy_and_the_standard_library_at_the_top():
     import sys
     paths = sorted(glob.glob(os.path.join(cluster.HERE, "rules", "*.py")))
-    assert [os.path.basename(p) for p in paths] == ["devices.py"]
+    assert {"devices.py", "ports.py", "distinct.py"} <= \
+        {os.path.basename(p) for p in paths}
     for path in paths:
+        assert module_level_imports(path), path
         for name in module_level_imports(path):
             top = name.split(".")[0]
             assert top == "numpy" or top == "__future__" \

@@ -1,10 +1,11 @@
 """The ports rule (`benchmark/rules/ports.py`) and the configuration that
 names it, `c5-ports-10k`, at 1,024 nodes: against its control and
-`isolated_round`, a golden of its numbers (`rules_ports_golden.json`, a
-new file), the share test (the resident ports `resident_alloc` lays out
-on the wire are the plain side's columns), the template's shapes, the
-planes, and the rule's probe of the program.  `test_rules.py`'s test of
-what a rule file may import covers the file by its glob.
+`isolated_round`, a golden of its numbers (`rules_ports_golden.json`,
+beside `test_rules.py`'s digests of c5), the share test (the resident
+ports `resident_alloc` lays out on the wire are the plain side's
+columns), the template's shapes, the planes, and the rule's probe of the
+program.  `test_rules.py`'s test of what a rule file may import covers
+the file by its glob.
 """
 import json
 import os
@@ -223,17 +224,3 @@ def test_the_probe_passes_here_and_names_a_program_blind_to_the_port(
     with pytest.raises(RuntimeError, match="static port in the wave"):
         rule.require_static_ports_in_the_wave()
 
-
-def test_the_rule_file_imports_numpy_and_the_standard_library_at_the_top():
-    """`test_rules.py` asserts this of `rules/*.py` and, first, that the
-    directory holds `devices.py` alone; that listing is its own to edit
-    (a benchmark PR's), so the check of this file is made here."""
-    import sys
-    from test_rules import module_level_imports
-    path = os.path.join(cluster.HERE, "rules", "ports.py")
-    names = list(module_level_imports(path))
-    assert names
-    for name in names:
-        top = name.split(".")[0]
-        assert top == "numpy" or top == "__future__" \
-            or top in sys.stdlib_module_names, name
