@@ -169,7 +169,8 @@ def host_solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
                       static_cache=None, has_preempt=False,
                       ev_res=None, ev_prio=None,
                       ask_prio=None, learned=None,
-                      region_bias=None) -> SolveResult:
+                      region_bias=None, pl_slot=None, pl_col=None,
+                      pl_left=None) -> SolveResult:
     """Numpy port of kernel.solve_kernel — see that docstring for the
     wave semantics.  Every formula, window size, and tie-break matches;
     tests/test_host_solver.py asserts bitwise-equal placements.
@@ -241,6 +242,37 @@ def host_solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
             region_bias=region_bias)
         return _score_spec.evaluate_wave(_NP_OPS, ctx)
 
+    # ---------- distinct_property slots (kernel.py twin) ----------
+    has_property = pl_slot is not None
+    if has_property:
+        pl_slot = np.asarray(pl_slot, np.int64)
+        pl_left = np.asarray(pl_left, f32)
+        PS, VL = pl_left.shape
+        J = pl_slot.shape[1]
+        pl_vnode = np.asarray(attr_rank, np.int64)[
+            :, np.asarray(pl_col, np.int64)].T             # [PS, Np]
+        pl_member = np.zeros((Gp, PS), bool)
+        for j in range(J):
+            np.logical_or.at(pl_member, (gs, np.maximum(pl_slot[:, j], 0)),
+                             pl_slot[:, j] >= 0)
+        pl_missing = (pl_member[:, :, None]
+                      & (pl_vnode < 0)[None, :, :]).any(axis=1)
+
+        def pl_charged(mask, nodes):
+            sl = pl_slot[g_idx]
+            slc = np.maximum(sl, 0)
+            v = pl_vnode[slc, nodes[:, None]]
+            ok = mask[:, None] & (sl >= 0) & (v >= 0)
+            out = np.zeros((PS, VL), f32)
+            np.add.at(out, (slc, np.maximum(v, 0)), ok.astype(f32))
+            return out
+
+        def pl_blocked(left):
+            full_n = np.take_along_axis(left <= 0, np.maximum(pl_vnode, 0),
+                                        axis=1) & (pl_vnode >= 0)
+            return (pl_member[:, :, None] & full_n[None, :, :]
+                    ).any(axis=1) | pl_missing
+
     # ---------- in-kernel preemption planes (kernel.py twin) ----------
     if has_preempt:
         EVW = ev_prio.shape[1]
@@ -283,6 +315,9 @@ def host_solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
         hit = hit > 0
         blocked = (hit[np.maximum(distinct, 0)]
                    & (distinct >= 0)[:, None])
+        if has_property:
+            pl_left_w = pl_left - pl_charged(committed, chosen)
+            blocked = blocked | pl_blocked(pl_left_w)
 
         score, placeable, feas_b, fit, fit_dims, dev_fit = group_scores(
             used, dev_used, coll, sp_used, blocked)
@@ -387,6 +422,26 @@ def host_solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
         dg_key = cand * np.int64(Gp) + np.maximum(dg, 0)
         dg_ok = prior_rank(dg_key, dg >= 0) == 0
 
+        # distinct_property: earlier candidates on each (slot, value)
+        # of a candidate's group, counted in candidate order
+        pl_ok = np.ones(K, bool)
+        if has_property:
+            seen: dict = {}
+            for p in range(K):
+                if not cand_ok[p]:
+                    continue
+                mine = []
+                for s_ in pl_slot[g_idx[p]]:
+                    if s_ < 0:
+                        continue
+                    v_ = int(pl_vnode[s_, cand[p]])
+                    key_ = (int(s_), max(v_, 0))
+                    if v_ < 0 or seen.get(key_, 0) >= pl_left_w[key_]:
+                        pl_ok[p] = False
+                    mine.append(key_)
+                for key_ in mine:
+                    seen[key_] = seen.get(key_, 0) + 1
+
         sp_ok = np.ones(K, bool)
         for s in (range(S) if has_spread else range(0)):
             cols = sp_col[g_idx, s]
@@ -428,7 +483,7 @@ def host_solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
             sp_ok &= ~has_s | (gv_rank
                                < quota[g_idx, np.minimum(vsc, V - 1)])
 
-        commit = cand_ok & fits & dev_fits & dg_ok & sp_ok
+        commit = cand_ok & fits & dev_fits & dg_ok & sp_ok & pl_ok
         cm = commit[:, None]
 
         np.add.at(used, cand, ask_res[g_idx] * cm)

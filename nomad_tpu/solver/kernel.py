@@ -19,9 +19,11 @@ placement per step, every wave
      (rank.go:462) makes them do one step at a time,
   3. commits every assignment that survives cross-group conflict checks:
      cumulative capacity on shared nodes (segment-sum by node),
-     first-per-(node, distinct-group) for distinct_hosts, and a spread
+     first-per-(node, distinct-group) for distinct_hosts, a spread
      quota per (group, value) so targeted/even spread cannot be
-     overfilled inside a single wave (spread.go semantics),
+     overfilled inside a single wave (spread.go semantics), and for a
+     distinct_property limit no more commits per (slot, value) than
+     the value has left (propertyset.go, made hard),
   4. placements that lose a conflict simply retry next wave against
      refreshed usage.
 
@@ -314,7 +316,11 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
                  ask_prio=None, mesh_hosts=0, mesh_nt=0, tile_np=0,
                  node_gid=None, owner_map=None, slot_map=None,
                  learned=None, mesh_regions=0,
-                 region_bias=None, lane_axis=None) -> SolveResult:
+                 region_bias=None, lane_axis=None,
+                 pl_slot=None, pl_col=None, pl_left=None) -> SolveResult:
+    # pl_slot / pl_col / pl_left: the batch's distinct_property slots
+    # (tensorize "Property limits"); None leaves the program as it is
+    # without them
     # has_distinct / has_devices: trace-time guarantees from the packer
     # that NO ask in this batch uses distinct_hosts / requests devices —
     # the per-wave conflict sort, blocking scatter, and device-fit
@@ -612,7 +618,8 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
     # hand-written shortlist twin and pallas tiles don't implement
     # them, so both stay disabled while either plane is active (see
     # score_spec.TERMS backends tuples)
-    C = (0 if (has_distinct or learned is not None
+    has_property = pl_slot is not None
+    C = (0 if (has_distinct or has_property or learned is not None
                or region_bias is not None)
          else resolve_shortlist_c(Np, TKl, shortlist_c))
     use_sl = C > 0
@@ -627,8 +634,12 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
     # twin of scheduler/preemption.victim_distance), ranks nodes by the
     # post-eviction bin-pack score, and commits (place, evict) pairs
     # through the same conflict/commit machinery as normal placements.
+    if has_property and in_mesh:
+        raise ValueError(
+            "property limits are counted on one device only; the mesh "
+            "solve leaves them to the host fixup")
     if has_preempt:
-        if has_distinct:
+        if has_distinct or has_property:
             raise ValueError(
                 "has_preempt does not compose with distinct_hosts "
                 "batches (cross-group blocking is invisible to the "
@@ -678,6 +689,44 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
 
     aff_score = jax.vmap(per_ask_aff)(gs) + a_host
     pen_score, pen_counts = _score_spec.static_terms(_JAX_OPS, penalty)
+
+    # ---------- distinct_property slots (wave-invariant part) ----------
+    # each slot's value rank per node (a one-hot matmul, exact, as the
+    # spread lookup below), the node-by-value one-hot the per-wave
+    # "value has nothing left" test reads, which slots each group is
+    # charged under, and the nodes that lack a slot's attribute
+    if has_property:
+        PS, VL = pl_left.shape
+        J = pl_slot.shape[1]
+        pl_sel = (pl_col[:, None] == jnp.arange(attr_rank.shape[1])[None, :]
+                  ).astype(jnp.float32)                    # [PS, A]
+        pl_vnode = exact_dot(pl_sel, attr_rank.T.astype(jnp.float32)
+                             ).astype(jnp.int32)           # [PS, Np]
+        pl_oh = (pl_vnode[:, None, :] == jnp.arange(VL)[None, :, None]
+                 ).astype(jnp.float32)                     # [PS, VL, Np]
+        pl_member = ((pl_slot[:, :, None] == jnp.arange(PS)[None, None, :])
+                     & (pl_slot >= 0)[:, :, None]).any(axis=1
+                                                       ).astype(jnp.float32)
+        pl_missing = exact_dot(pl_member,
+                               (pl_vnode < 0).astype(jnp.float32)) > 0.5
+
+        def pl_charged(mask, nodes):
+            """[PS, VL] commits of the placements in `mask` on `nodes`,
+            charged to every slot of their group."""
+            sl = pl_slot[p_ask]                            # [K, J]
+            slc = jnp.maximum(sl, 0)
+            v = pl_vnode[slc, nodes[:, None]]
+            ok = mask[:, None] & (sl >= 0) & (v >= 0)
+            return jnp.zeros((PS, VL), jnp.float32).at[
+                slc, jnp.maximum(v, 0)].add(ok.astype(jnp.float32))
+
+        def pl_blocked(left):
+            """[Gp, Np]: nodes whose value has nothing left in one of the
+            group's slots, or that lack a slot's attribute."""
+            full_v = (left <= 0).astype(jnp.float32)       # [PS, VL]
+            full_n = jax.vmap(exact_dot)(full_v, pl_oh)    # [PS, Np]
+            return (exact_dot(pl_member, (full_n > 0.5).astype(
+                jnp.float32)) > 0.5) | pl_missing
 
     # ---------- hoisted spread lookups (wave-invariant) ----------
     # The per-(group, node) spread value and desired-count are functions
@@ -978,6 +1027,12 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
         active = ~done & (ks < n_place)
         g_idx = p_ask
         used_pre, dev_used_pre = used, dev_used
+        if has_property:
+            # what each (slot, value) has left, rebuilt from the
+            # committed outputs as coll and blocked are
+            committed_p = done & out_ok[:, 0]
+            pl_left_w = pl_left - pl_charged(
+                committed_p, jnp.where(committed_p, out_idx[:, 0], 0))
 
         Vs = Vs_i
 
@@ -1010,6 +1065,8 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
                     & (distinct >= 0)[:, None]
             else:
                 blocked = jnp.zeros((Gp, Np), bool)
+            if has_property:
+                blocked = blocked | pl_blocked(pl_left_w)
 
             pk = None
             if use_pk:
@@ -1038,8 +1095,8 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
                     spread_pack = None
                 pk = _pk.fused_wave(
                     mode=pallas_mode, feas=pk_feas,
-                    blocked=(pack_groups_i32(blocked) if has_distinct
-                             else None),
+                    blocked=(pack_groups_i32(blocked)
+                             if has_distinct or has_property else None),
                     aff=aff_score, pen=pk_pen, jitter=jitter, coll=coll,
                     used_t=used.T, avail_t=pk_avail_t,
                     reserved_t=pk_reserved_t,
@@ -1422,6 +1479,33 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
         else:
             dg_ok = jnp.ones(K, bool)
 
+        # distinct_property: a commit only while this wave's earlier
+        # candidates on each of its (slot, value) pairs leave room in
+        # what the value has left (a candidate counts in every slot of
+        # its group: one sort over the K x J pairs, ranked by candidate)
+        if has_property:
+            sl_c = pl_slot[g_idx]                          # [K, J]
+            slc_c = jnp.maximum(sl_c, 0)
+            v_c = pl_vnode[slc_c, cand[:, None]]
+            memb = cand_ok[:, None] & (sl_c >= 0)
+            L = K * J
+            keyf = jnp.where(memb, slc_c * jnp.int32(VL)
+                             + jnp.maximum(v_c, 0),
+                             jnp.int32(0x7FFFFFF0)).reshape(L)
+            posf = jnp.broadcast_to(ks[:, None], (K, J)).reshape(L)
+            ixf = jnp.arange(L, dtype=jnp.int32)
+            s_key, _s_pos, s_ix = lax.sort((keyf, posf, ixf), num_keys=2)
+            is_start = jnp.concatenate(
+                [jnp.ones(1, bool), s_key[1:] != s_key[:-1]])
+            seg0 = lax.cummax(jnp.where(is_start, ixf, 0))
+            pl_rank = jnp.zeros(L, jnp.int32).at[s_ix].set(
+                ixf - seg0).reshape(K, J)
+            left_c = pl_left_w[slc_c, jnp.maximum(v_c, 0)]
+            pl_ok = ((sl_c < 0) | ((v_c >= 0) & (
+                pl_rank.astype(jnp.float32) < left_c))).all(axis=1)
+        else:
+            pl_ok = jnp.ones(K, bool)
+
         # spread quota: cap same-wave commits per (group, slot, value) so
         # a wave cannot blow far past a spread target the serial
         # reference would have steered away from; targeted spreads stop
@@ -1473,7 +1557,7 @@ def solve_kernel(avail, reserved, used0, valid, node_dc, attr_rank,
             gv_rank = prior_rank(gv_key, has_s).astype(jnp.float32)
             sp_ok &= ~has_s | (gv_rank < quota[g_idx, vsc])
 
-        commit = cand_ok & fits & dev_fits & dg_ok & sp_ok
+        commit = cand_ok & fits & dev_fits & dg_ok & sp_ok & pl_ok
         cm = commit[:, None]
 
         # -- apply all of this wave's commits at once (coll/blocked are

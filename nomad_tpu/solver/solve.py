@@ -29,7 +29,8 @@ from .kernel import TOP_K, solve_kernel
 from .tensorize import (NUM_R, ClusterDelta, PackedBatch, PlacementAsk,
                         Tensorizer, alloc_device_usage,
                         alloc_usage_vector, apply_node_delta_host,
-                        evict_width, static_port_columns,
+                        evict_width, property_target,
+                        static_port_columns,
                         R_CPU, R_DISK, R_MEM, R_NET)
 
 _DIM_NAMES = {R_CPU: "cpu", R_MEM: "memory", R_DISK: "disk", R_NET: "network"}
@@ -611,8 +612,6 @@ class Solver:
         bit-identical no matter how many plan solves interleave.
         Returns (pb, nodes) so callers never re-read self._world (a
         concurrent rebuild could swap the node list under them)."""
-        if any(a.property_limits for a in asks):
-            return None          # host-side walk the resident path skips
         with self._world_lock:
             if self._world is None:
                 if overlay_only:
@@ -629,7 +628,8 @@ class Solver:
             kp = max(self._pad(sum(max(a.count, 1) for a in asks)), 1)
             pb = self._tensorizer.repack_asks(
                 world.nodes, asks, world.template, gp=gp, kp=kp,
-                drv_cache=world.drv_cache, row_cache=world.row_cache)
+                drv_cache=world.drv_cache, row_cache=world.row_cache,
+                property_slots=True)
             if pb is None:
                 if overlay_only:
                     return None
@@ -639,7 +639,8 @@ class Solver:
                 world.rebuild(snapshot)
                 pb = self._tensorizer.repack_asks(
                     world.nodes, asks, world.template, gp=gp, kp=kp,
-                    drv_cache=world.drv_cache, row_cache=world.row_cache)
+                    drv_cache=world.drv_cache, row_cache=world.row_cache,
+                    property_slots=True)
                 if pb is None:
                     return None
             return (_overlay_usage(world, pb, proposed_delta),
@@ -695,12 +696,18 @@ class Solver:
         with _tr.layer(spans + ".pack") as pack:
             pb = None
             sol_nodes = nodes
+            passed_over = 0
             if snapshot is not None and self.resident_active(snapshot):
                 packed = self._resident_pack(
                     snapshot, asks, proposed_delta,
                     overlay_only=_overlay_only)
                 if packed is not None:
                     pb, sol_nodes = packed
+                else:
+                    passed_over = 1
+            # every solve writes it: a window whose solves all took the
+            # resident world reads 0, not nothing
+            _m.incr_counter("solver.resident.passed_over", passed_over)
             used_resident = pb is not None
             if pb is None:
                 with self._world_lock:
@@ -779,6 +786,7 @@ class Solver:
         chosen_by_ask: Dict[int, set] = {}
         # distinct_property charges shared batch-wide by (scope, target) key
         prop_used: Dict[tuple, Dict[str, int]] = {}
+        prop_refused = 0
 
         # Replay commits in KERNEL WAVE order when the preemption pass
         # ran: evictions make in-batch usage non-monotone, so an
@@ -847,6 +855,7 @@ class Solver:
                     continue
                 prop_vals = self._property_fit(node, ask, prop_used)
                 if prop_vals is None:
+                    prop_refused += 1
                     continue
                 refused0 = port_tally.refused
                 resources = self._host_commit(node, ni, ask, net_cache,
@@ -922,6 +931,11 @@ class Solver:
                             static_port_columns(pb))
             if spans == "solve":
                 _tr.summed("solve.ports", port_tally.seconds)
+        if pb.pl_slot is not None:
+            # the wave's property slots, once a solve: what it counted,
+            # and what the fixup's re-check still refused (expected 0)
+            _m.incr_counter("solver.property.columns", pb.pl_values)
+            _m.incr_counter("solver.property.refused", prop_refused)
 
         # class eligibility for blocked-eval optimization
         class_elig: List[Dict[str, bool]] = []
@@ -1109,7 +1123,9 @@ class Solver:
     @staticmethod
     def _property_fit(node: Node, ask: PlacementAsk,
                       used: Dict[tuple, Dict[str, int]]):
-        """Check distinct_property limits against existing + in-batch counts.
+        """Check distinct_property limits against existing + in-batch counts:
+        the re-check of what the wave counted (tensorize "Property
+        limits"), so it refuses nothing where the wave chose the node.
         Limits are keyed (scope, attr target); charges under one key are
         shared across all asks carrying it (job-level scope spans the whole
         batch). Returns the (key, value) pairs to charge on acceptance, or
@@ -1119,8 +1135,7 @@ class Solver:
         from ..structs import resolve_node_target
         out = []
         for key, (limit, existing) in ask.property_limits.items():
-            target = key[1] if isinstance(key, tuple) else key
-            val, ok = resolve_node_target(node, target)
+            val, ok = resolve_node_target(node, property_target(key))
             if not ok:
                 # nodes missing the property are infeasible for
                 # distinct_property (reference: propertyset.go:240)
@@ -1220,7 +1235,8 @@ def solve_trace_attrs(pb: PackedBatch, res,
     TK = window_tk(Gp, K, Np)
     mode = ("off" if backend == "host"
             else _pk.resolve_mode(Np, Gp, TK, V, has_spread))
-    C = (0 if bool((_np.asarray(pb.distinct) >= 0).any())
+    C = (0 if (bool((_np.asarray(pb.distinct) >= 0).any())
+               or pb.pl_slot is not None)
          else resolve_shortlist_c(Np, TK, 0))
     b1, brw, _passes = model_wave_bytes(Np, Gp, K, S, R, has_spread,
                                         mode, TK, C)
@@ -1259,8 +1275,12 @@ def _run_kernel(pb: PackedBatch, host_mode: str = "auto",
     # no distinct_hosts groups — cross-group blocking is invisible to
     # the eviction pass, so those batches keep the host-side walk.
     # Host twin and device kernel get the SAME decision (bit-identity).
+    # the batch's distinct_property slots (tensorize "Property limits");
+    # like distinct_hosts they keep the host-side preemption walk
+    pl_kw = ({} if pb.pl_slot is None else
+             dict(pl_slot=pb.pl_slot, pl_col=pb.pl_col, pl_left=pb.pl_left))
     ev_kw = {}
-    if (preempt and pb.ev_prio is not None
+    if (preempt and pb.ev_prio is not None and not pl_kw
             and not bool((_np.asarray(pb.distinct) >= 0).any())):
         ev_kw = dict(has_preempt=True, ev_res=pb.ev_res,
                      ev_prio=pb.ev_prio, ask_prio=pb.ask_prio)
@@ -1276,11 +1296,12 @@ def _run_kernel(pb: PackedBatch, host_mode: str = "auto",
                 pb.avail.shape[0], pb.n_asks, pb.n_place):
             return host_solve_kernel(*_kernel_args(pb),
                                      has_spread=has_spread,
-                                     max_waves=max_waves, **ev_kw)
+                                     max_waves=max_waves, **ev_kw,
+                                     **pl_kw)
     # "auto" resolves to the pallas fused wave on TPU backends (or when
     # NOMAD_TPU_PALLAS forces it) and to the unfused kernel otherwise —
     # placement-identical either way (tests/test_pallas_kernel.py)
-    host_ev_kw = dict(ev_kw)
+    host_ev_kw = dict(ev_kw, **pl_kw)
     if ev_kw:
         # the eviction pass statically asserts no distinct batches;
         # the check above established it for this batch
@@ -1301,10 +1322,11 @@ def _run_kernel(pb: PackedBatch, host_mode: str = "auto",
         # argument crosses on every call, one already on the device is
         # a jax array and is not counted
         _m.incr_counter("solver.h2d_bytes", float(sum(
-            a.nbytes for a in args if isinstance(a, _np.ndarray))))
+            a.nbytes for a in args + tuple(pl_kw.values())
+            if isinstance(a, _np.ndarray))))
         res = solve_kernel(*args, has_spread=has_spread,
                            pallas_mode=pallas, max_waves=max_waves,
-                           lane_axis=None, **ev_kw)
+                           lane_axis=None, **ev_kw, **pl_kw)
         # materialize under the watchdog deadline: an async dispatch
         # that only wedges at a later fetch would escape it
         if materialize or global_watchdog.enabled:

@@ -23,6 +23,16 @@ gets, and the exact per-address collision check, stay with the host
 fixup (`NetworkIndex`).  A batch without a device or a static port adds
 no column.
 
+Property limits (`pl_*`): a `distinct_property` limit of the batch's
+asks is a SLOT, one per (scope key, attribute) of `property_limits` (a
+job-scope key is one slot for all of the job's groups), with the
+allocs each value of the attribute may still take (`pl_left`: the limit
+less what the job already holds there) and the groups charged under it
+(`pl_slot`).  The wave admits a commit only while its wave's commits on
+a (slot, value) stay within what is left, charges it across waves, and
+drops a node whose value has nothing left, or that lacks the attribute,
+out of the group's feasibility.  A batch without a limit packs no slot.
+
 Boolean plane dtype contract: the eligibility masks packed here
 (`valid`, `dc_ok`, `host_ok`, `penalty`) stay dense bool on the host —
 the interning/memoization layer mutates and compares them row-wise.
@@ -137,8 +147,9 @@ class PlacementAsk:
     # ^ attr target -> value -> existing count (propertyset seed)
     property_limits: Dict[str, Tuple[int, Dict[str, int]]] = field(
         default_factory=dict)
-    # ^ distinct_property: attr target -> (limit, existing count by value);
-    #   enforced host-side across in-batch placements (solve.py)
+    # ^ distinct_property: (scope key, attr target) -> (limit, existing
+    #   count by value); counted in the wave (`pl_*`, "Property limits")
+    #   and re-checked by the host fixup (solve.py _property_fit)
 
 
 def group_resource_vector(tg: TaskGroup) -> np.ndarray:
@@ -262,6 +273,57 @@ def static_port_columns(pb) -> int:
                if key[0] == _PORT and pb.dev_ask[:, dix].any())
 
 
+def property_target(key) -> str:
+    """The attribute target of a `property_limits` key: (scope, target),
+    or the bare target."""
+    return key[1] if isinstance(key, tuple) else key
+
+
+def property_planes(asks: Sequence[PlacementAsk], gp: int,
+                    attr_ix: Dict[str, int],
+                    rank_columns: Sequence[RankColumn]):
+    """(pl_slot, pl_col, pl_left, pl_values) of a batch's
+    distinct_property limits, or None where an attribute has no column
+    in `attr_ix`.  A slot per (scope key, attribute); the slot, group
+    and value axes are padded to powers of two (8, 2 and 8 at least), so
+    a deployment's jobs of one to four groups share one shape."""
+    slot_ix: Dict[object, int] = {}
+    slots: List[Tuple[int, int, Dict[str, int]]] = []
+    per_group: List[List[int]] = []
+    for ask in asks:
+        mine = []
+        for key, (limit, existing) in ask.property_limits.items():
+            s = slot_ix.get(key)
+            if s is None:
+                col = attr_ix.get(property_target(key))
+                if col is None:
+                    return None
+                s = slot_ix[key] = len(slots)
+                slots.append((col, int(limit), existing))
+            mine.append(s)
+        per_group.append(mine)
+    J = _pad_pow2(max(len(m) for m in per_group), floor=2)
+    PS = _pad_pow2(len(slots), floor=8)
+    VL = _pad_pow2(max(rank_columns[col].n_values
+                       for col, _l, _e in slots), floor=8)
+    pl_slot = np.full((gp, J), -1, np.int32)
+    for g, mine in enumerate(per_group):
+        pl_slot[g, :len(mine)] = mine
+    pl_col = np.zeros(PS, np.int32)
+    pl_left = np.zeros((PS, VL), np.float32)
+    values = 0
+    for s, (col, limit, existing) in enumerate(slots):
+        rc = rank_columns[col]
+        pl_col[s] = col
+        pl_left[s] = limit
+        for val, cnt in existing.items():
+            r = rc.rank(val)
+            if r >= 0:
+                pl_left[s, r] -= cnt
+        values += rc.n_values
+    return pl_slot, pl_col, pl_left, values
+
+
 def _pad_pow2(n: int, floor: int = 8) -> int:
     p = floor
     while p < n:
@@ -367,6 +429,15 @@ class PackedBatch:
     ev_ids: Optional[List[List[str]]] = None  # [Np][E] alloc ids
     ev_lists: Optional[List[list]] = None   # per-node candidate lists
     #   (template-only; _evict_candidates order, feeds delta recompute)
+    # distinct_property limits counted in the wave (module docstring,
+    # "Property limits"); None where no ask carries one
+    pl_slot: Optional[np.ndarray] = None    # [Gp, J] i32 slots a group
+    #   is charged under (-1 = empty)
+    pl_col: Optional[np.ndarray] = None     # [PS] i32 attr column a slot
+    #   counts by
+    pl_left: Optional[np.ndarray] = None    # [PS, VL] f32 allocs each
+    #   (slot, value rank) may still take
+    pl_values: int = 0                      # real (slot, value) pairs
 
 
 @dataclass
@@ -874,6 +945,8 @@ class Tensorizer:
 
             for sp in list(ask.job.spreads) + list(ask.tg.spreads):
                 target_col(sp.attribute)
+            for key in ask.property_limits:
+                target_col(property_target(key))
 
         A = max(len(attr_targets), 1)
 
@@ -1106,6 +1179,11 @@ class Tensorizer:
                     ev_prio[i], ev_res[i], ev_ids[i] = _evict_row(
                         cands, E)
 
+        planes = (property_planes(asks, Gp, attr_target_ix, rank_columns)
+                  if any(a.property_limits for a in asks) else None)
+        pl_slot, pl_col, pl_left, pl_values = planes or (None, None,
+                                                         None, 0)
+
         return PackedBatch(
             node_ids=[n.id for n in nodes], n_real=N,
             avail=avail, reserved=reserved, used0=used0, valid=valid,
@@ -1127,6 +1205,8 @@ class Tensorizer:
             dev_pattern_ids=dev_pattern_ix,
             ask_prio=ask_prio, ev_prio=ev_prio, ev_res=ev_res,
             ev_ids=ev_ids, ev_lists=ev_lists,
+            pl_slot=pl_slot, pl_col=pl_col, pl_left=pl_left,
+            pl_values=pl_values,
         )
 
     def delta_pack(self, template: PackedBatch,
@@ -1377,10 +1457,16 @@ class Tensorizer:
                     template: PackedBatch,
                     gp: Optional[int] = None, kp: Optional[int] = None,
                     drv_cache: Optional[Dict[str, np.ndarray]] = None,
-                    row_cache: Optional[Dict] = None
+                    row_cache: Optional[Dict] = None,
+                    property_slots: bool = False
                     ) -> Optional[PackedBatch]:
         """Rebuild ONLY the ask-side tensors of `template`, reusing its
         node-side arrays and rank universes untouched.
+
+        `property_slots`: pack the asks' distinct_property limits as the
+        wave's slots (`property_planes`); a caller whose solve does not
+        read them (the stream solvers) leaves it off and gets None for a
+        batch that carries one.
 
         This is the resident-solve fast path (solver/resident.py): the node
         tensors stay on device across eval batches, so per batch we only
@@ -1409,9 +1495,8 @@ class Tensorizer:
         NDC = template.dc_ok.shape[1]
         if G > gp:
             return None
-        # distinct_property limits are enforced host-side by Solver.solve's
-        # _property_fit walk, which the resident path skips — fall back
-        if any(ask.property_limits for ask in asks):
+        has_limits = any(ask.property_limits for ask in asks)
+        if has_limits and not property_slots:
             return None
         rank_columns = template.rank_columns
         attr_ix = {t: i for i, t in enumerate(template.attr_targets)}
@@ -1709,6 +1794,14 @@ class Tensorizer:
         for g, ask in enumerate(asks):
             ask_prio[g] = int(getattr(ask.job, "priority", 0) or 0)
 
+        pl_slot = pl_col = pl_left = None
+        pl_values = 0
+        if has_limits:
+            planes = property_planes(asks, gp, attr_ix, rank_columns)
+            if planes is None:
+                return None
+            pl_slot, pl_col, pl_left, pl_values = planes
+
         return PackedBatch(
             node_ids=template.node_ids, n_real=template.n_real,
             avail=template.avail, reserved=template.reserved,
@@ -1736,6 +1829,8 @@ class Tensorizer:
             # (delta-maintained there; ev_lists stay template-owned)
             ev_prio=template.ev_prio, ev_res=template.ev_res,
             ev_ids=template.ev_ids,
+            pl_slot=pl_slot, pl_col=pl_col, pl_left=pl_left,
+            pl_values=pl_values,
         )
 
     def _class_masked(self, nodes: Sequence[Node], c: Constraint) -> np.ndarray:
